@@ -712,6 +712,30 @@ def _gather_free(run, args) -> bool:
     return gather_free(jax.jit(run).lower(*args).as_text())
 
 
+KERNEL_GRID_SCRIPT = r"""
+import json
+import jax
+from benchmarks.bench_mttkrp import bench_point
+
+d = jax.devices()
+out = {{"device": {{"platform": d[0].platform,
+                   "device_kind": d[0].device_kind,
+                   "device_count": len(d)}},
+       "points": [bench_point(n, r, z, repeats={repeats})
+                  for n, r, z in {grid!r}]}}
+print("RESULT_JSON:" + json.dumps(out, default=str))
+"""
+
+
+def bench_kernel_grid(grid, *, repeats: int = 3) -> dict:
+    """:func:`bench_point` over ``grid`` in a one-device child process;
+    returns ``{"device": {platform, device_kind, device_count},
+    "points": [...]}`` as reported by ``jax.devices()`` there."""
+    return run_subprocess_bench(
+        KERNEL_GRID_SCRIPT.format(grid=[tuple(g) for g in grid],
+                                  repeats=repeats), devices=1)
+
+
 def bench_point(nmodes: int, rank: int, nnz: int, *, repeats: int = 3,
                 seed: int = 0) -> dict:
     from repro.api import KernelConfig
@@ -866,22 +890,28 @@ def main() -> None:
                 for rank in (8, 32)
                 for nnz in (2048, 8192)]
 
-    points = []
+    # Every scenario that touches a device runs in its own child process,
+    # one after another, and this process stays off JAX until they are all
+    # done: a process that has touched JAX holds the chip, and a child that
+    # needs it then fails or hangs.
     with scenario("kernel_grid"):
-        for nmodes, rank, nnz in grid:
-            pt = bench_point(nmodes, rank, nnz, repeats=args.repeats)
-            f, b = pt["variants"]["fused"], pt["variants"]["blocked"]
-            s, h = pt["variants"]["sorted"], pt["ref_sorted_hint"]
-            print(f"nmodes={nmodes} R={rank} nnz={nnz}: "
-                  f"fused {f['time_s']*1e3:.2f}ms "
-                  f"(model {f['modelled_hbm_bytes']/1e6:.2f}MB) vs blocked "
-                  f"{b['time_s']*1e3:.2f}ms "
-                  f"(model {b['modelled_hbm_bytes']/1e6:.2f}MB); sorted "
-                  f"model {s['modelled_hbm_bytes']/1e6:.2f}MB "
-                  f"({s['modelled_flops']/1e6:.2f}MF vs fused "
-                  f"{f['modelled_flops']/1e6:.2f}MF); ref sorted-hint "
-                  f"{h['speedup']:.3f}x")
-            points.append(pt)
+        grid_res = bench_kernel_grid(grid, repeats=args.repeats)
+    device = grid_res["device"]
+    points = grid_res["points"]
+    print(f"device: {device['platform']} {device['device_kind']} "
+          f"x{device['device_count']}")
+    for pt in points:
+        f, b = pt["variants"]["fused"], pt["variants"]["blocked"]
+        s, h = pt["variants"]["sorted"], pt["ref_sorted_hint"]
+        print(f"nmodes={pt['nmodes']} R={pt['rank']} nnz={pt['nnz']}: "
+              f"fused {f['time_s']*1e3:.2f}ms "
+              f"(model {f['modelled_hbm_bytes']/1e6:.2f}MB) vs blocked "
+              f"{b['time_s']*1e3:.2f}ms "
+              f"(model {b['modelled_hbm_bytes']/1e6:.2f}MB); sorted "
+              f"model {s['modelled_hbm_bytes']/1e6:.2f}MB "
+              f"({s['modelled_flops']/1e6:.2f}MF vs fused "
+              f"{f['modelled_flops']/1e6:.2f}MF); ref sorted-hint "
+              f"{h['speedup']:.3f}x")
 
     skew = None
     if not args.skip_skew:
@@ -987,8 +1017,10 @@ def main() -> None:
     print(f"analysis findings: {len(afindings)}")
 
     save_result("BENCH_mttkrp", {
-        "backend": jax.default_backend(),
-        "interpret_mode": jax.default_backend() != "tpu",
+        "platform": device["platform"],
+        "device_kind": device["device_kind"],
+        "device_count": device["device_count"],
+        "interpret_mode": device["platform"] != "tpu",
         "analysis_findings": len(afindings),
         "notes": ("interpret-mode times are not hardware-meaningful; "
                   "modelled_hbm_bytes + modelled_flops + gather_free + the "

@@ -50,7 +50,9 @@ def run_subprocess_bench(script: str, *, devices: int = 8,
                          timeout: int = 3600) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["PYTHONPATH"] = os.path.join(HERE, "..", "src")
+    # src for repro, the repo root for scripts that import benchmarks.*
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(HERE, "..", "src"), os.path.join(HERE, "..")])
     proc = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=timeout)
     if proc.returncode != 0:

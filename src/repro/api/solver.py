@@ -33,9 +33,10 @@ from __future__ import annotations
 import itertools
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import comm, obs
 from repro.api.config import DecomposeConfig
@@ -243,14 +244,21 @@ class CPSolver:
         self.close()
 
     # -- state lifecycle ---------------------------------------------------
+    def _replicate(self, xs: list) -> list:
+        """Place state arrays replicated on the mesh — the sharding the
+        jitted updates return — so the first sweep calls the same compiled
+        updates as every later one."""
+        return jax.device_put(xs, NamedSharding(self.mesh, P()))
+
     def reset(self) -> None:
         """(Re)initialize factors from the config seed; sweep counter to 0."""
         rank = self.config.rank
-        factors = als_mod.init_factors(self.plan, rank,
-                                       seed=self.config.runtime.seed)
-        grams = [f.T @ f for f in factors]
-        self.state = als_mod.ALSState(factors=factors, lam=jnp.ones(rank),
-                                      grams=grams)
+        factors = self._replicate(als_mod.init_factors(
+            self.plan, rank, seed=self.config.runtime.seed))
+        grams = [als_mod.gram(f) for f in factors]
+        self.state = als_mod.ALSState(
+            factors=factors, lam=self._replicate([jnp.ones(rank)])[0],
+            grams=grams)
 
     def restore(self, step: int | None = None) -> bool:
         """Elastic resume: load the latest (or given) verified checkpoint and
@@ -288,10 +296,12 @@ class CPSolver:
         for w, fg in enumerate(factors):
             fp = np.zeros((self.plan.modes[w].padded_rows, rank), np.float32)
             fp[self.plan.global_to_padded[w]] = fg
-            padded.append(jnp.asarray(fp))
-        grams = [f.T @ f for f in padded]
+            padded.append(fp)
+        padded = self._replicate(padded)
+        grams = [als_mod.gram(f) for f in padded]
         self.state = als_mod.ALSState(
-            factors=padded, lam=jnp.asarray(np.asarray(lam, np.float32)),
+            factors=padded,
+            lam=self._replicate([np.asarray(lam, np.float32)])[0],
             grams=grams, sweep=sweep, fits=list(fits))
 
     def checkpoint(self) -> None:

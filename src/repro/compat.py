@@ -1,9 +1,8 @@
-"""Version-compatibility shims.
+"""The repo's conventions over a few jax APIs (jax >= 0.9).
 
-``shard_map`` moved from ``jax.experimental.shard_map`` (kwarg
-``check_rep``) to the ``jax`` top level (kwarg ``check_vma``) in newer
-releases; this container ships the experimental spelling. All repo code
-goes through :func:`shard_map` so either jax works.
+All repo code goes through these wrappers: :func:`shard_map` always runs
+with ``check_vma=False`` (the collectives here are written per device), and
+:func:`make_mesh` gives every axis the ``Auto`` type.
 """
 from __future__ import annotations
 
@@ -14,40 +13,22 @@ __all__ = ["shard_map", "axis_size", "cost_analysis", "make_mesh"]
 
 
 def make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    jax knows them (>= 0.5); plain ``make_mesh`` on earlier releases (this
-    container's 0.4.37 has neither ``jax.sharding.AxisType`` nor the
-    ``axis_types`` kwarg)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with every axis of type ``Auto``."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized to a flat dict — jax < 0.5
-    returned a one-element list of per-computation dicts."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        return cost[0] if cost else {}
-    return cost or {}
+    """``Compiled.cost_analysis()``, ``{}`` where the backend gives none."""
+    return compiled.cost_analysis() or {}
 
 
 def axis_size(axis_name: str) -> int:
-    """``lax.axis_size`` (jax >= 0.5) / ``lax.psum(1, name)`` (earlier) —
-    static mesh-axis size inside shard_map."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static mesh-axis size inside shard_map."""
+    return lax.axis_size(axis_name)
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
 
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -33,7 +33,7 @@ from repro.core import mttkrp as dmttkrp
 from repro.obs import trace as obs_trace
 from repro.core.partition import CPPlan
 
-__all__ = ["ALSState", "init_factors", "make_mode_update",
+__all__ = ["ALSState", "init_factors", "matmul", "gram", "make_mode_update",
            "make_sweep_updates", "als_sweep", "fit_from_stats",
            "unpad_factors", "StreamingModeUpdate",
            "make_streaming_mode_update", "make_streaming_sweep_updates",
@@ -63,11 +63,24 @@ def init_factors(plan: CPPlan, rank: int, seed: int = 0) -> list[jax.Array]:
     return out
 
 
+def matmul(a: jax.Array, b: jax.Array) -> jax.Array:
+    """f32 matmul for the ALS algebra (solve, Grams, fit). A TPU's default
+    f32 precision is one bf16 pass, whose rounding the Gram pseudo-inverse
+    amplifies into fit differences far above the EC's own; ``HIGHEST``
+    keeps f32 on every backend, at R×R and rows×R×R cost."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+def gram(f: jax.Array) -> jax.Array:
+    """``Fᵀ F`` in f32."""
+    return matmul(f.T, f)
+
+
 def _pinv_psd(v: jax.Array, rcond: float = 1e-8) -> jax.Array:
     """Pseudo-inverse of a symmetric PSD R×R matrix via eigh (stable, tiny)."""
     w, u = jnp.linalg.eigh(v)
     w_inv = jnp.where(w > rcond * jnp.max(jnp.abs(w)), 1.0 / w, 0.0)
-    return (u * w_inv[None, :]) @ u.T
+    return matmul(u * w_inv[None, :], u.T)
 
 
 def make_mode_update(plan: CPPlan, mode: int, mesh: Mesh, **mttkrp_kw) -> Callable:
@@ -91,11 +104,11 @@ def make_mode_update(plan: CPPlan, mode: int, mesh: Mesh, **mttkrp_kw) -> Callab
         v = functools.reduce(
             lambda a, b: a * b,
             [grams[w] for w in range(n) if w != mode])     # (R, R)
-        f_new = m @ _pinv_psd(v)
+        f_new = matmul(m, _pinv_psd(v))
         lam = jnp.linalg.norm(f_new, axis=0)
         lam = jnp.where(lam > 0, lam, 1.0)
         f_new = f_new / lam[None, :]
-        g_new = f_new.T @ f_new
+        g_new = gram(f_new)
         return f_new, g_new, m, lam
 
     donate = (0,) if jax.default_backend() != "cpu" else ()
@@ -175,11 +188,11 @@ def make_streaming_mode_update(plan: CPPlan, mode: int, mesh: Mesh, *,
         v = functools.reduce(
             lambda a, b: a * b,
             [grams[w] for w in range(n) if w != mode])     # (R, R)
-        f_new = m @ _pinv_psd(v)
+        f_new = matmul(m, _pinv_psd(v))
         lam = jnp.linalg.norm(f_new, axis=0)
         lam = jnp.where(lam > 0, lam, 1.0)
         f_new = f_new / lam[None, :]
-        g_new = f_new.T @ f_new
+        g_new = gram(f_new)
         return f_new, g_new, m, lam
 
     donate = jax.default_backend() != "cpu"
@@ -281,11 +294,13 @@ def als_traced_sweep(plan: CPPlan, mesh: Mesh, dev_arrays: Sequence,
                     sweep=state.sweep + 1, fits=state.fits + [fit])
 
 
+@jax.jit
 def fit_from_stats(norm_x: float, m_last, f_last, lam, grams) -> jax.Array:
-    """fit = 1 - ||X - X̂||_F / ||X||_F via the norm identity."""
+    """fit = 1 - ||X - X̂||_F / ||X||_F via the norm identity (one small
+    jitted program per sweep, not a dozen eager dispatches)."""
     inner = jnp.sum(jnp.sum(m_last * f_last, axis=0) * lam)
     gall = functools.reduce(lambda a, b: a * b, grams)
-    model_sq = lam @ gall @ lam
+    model_sq = matmul(lam, matmul(gall, lam))
     resid_sq = jnp.maximum(norm_x ** 2 - 2.0 * inner + model_sq, 0.0)
     return 1.0 - jnp.sqrt(resid_sq) / norm_x
 

@@ -569,15 +569,17 @@ def build_plan(
         replication = max(
             auto_replication(t.mode_histogram(d), num_devices)
             for d in range(n))
-    # pass 1: row layouts per mode (needed to translate input indices)
+    # pass 1: row layouts per mode (needed to translate input indices) —
+    # histogram-only, the same layouts partition_mode builds on
     g2ps: list[np.ndarray] = []
     metas = []
     for d in range(n):
-        _, g2p, p2g = partition_mode(
-            t, d, num_devices, strategy=strategy, replication=replication,
-            tile=tile, block_p=block_p, layout=layout, all_g2p=None)
-        g2ps.append(g2p)
-        metas.append(p2g)
+        lay = mode_layout(
+            t.mode_histogram(d), d, num_devices, strategy=strategy,
+            replication=replication, tile=tile, block_p=block_p,
+            layout=layout)
+        g2ps.append(lay.global_to_padded)
+        metas.append(lay.padded_to_global)
     # pass 2: build device arrays with translated indices
     parts = []
     for d in range(n):

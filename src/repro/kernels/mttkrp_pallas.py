@@ -12,14 +12,17 @@ Preprocessing (core/partition.py) guarantees:
 
 Grid = (num_blocks,). The output BlockSpec's index_map reads the
 scalar-prefetched ``block_to_tile`` array, so consecutive blocks hitting the
-same tile keep the accumulator resident in VMEM (Pallas revisiting); the tile
-is zero-initialised when the map changes. Per block the kernel computes
+same tile keep the accumulator resident in VMEM (Pallas revisiting); when
+the map changes the tile is loaded from the running output, which starts at
+zero. Per block the kernel computes
 
     E = val ⊙ A[i0,:] ⊙ B[i1,:] ⊙ ...      (P, R)   on the VPU
     out_tile += onehot(row_in_tile)ᵀ @ E    (TILE,R)  on the MXU
 
 which is the paper's EC with zero write conflicts — the same race-freedom
 the output-mode sharding buys across devices, pushed down to lane level.
+(The kernel folds ``val`` into the one-hot operand instead of E; operand
+layouts and the chunked launch follow ``tpu_layout``.)
 
 Input factor rows are gathered by XLA ahead of the kernel (``ops.py``),
 materializing (nnz, R) intermediates in HBM; ``mttkrp_fused.ec_fused`` is the
@@ -36,31 +39,39 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ec_blocked"]
+from repro.kernels import tpu_layout as tl
+
+__all__ = ["ec_blocked", "onehot_commit"]
 
 
-def _ec_kernel(nin: int, b2t, *refs):
-    # refs: vals_ref, seg_ref, rows_ref_0..rows_ref_{nin-1}, out_ref
-    vals_ref, seg_ref = refs[0], refs[1]
-    rows_refs = refs[2:2 + nin]
-    out_ref = refs[-1]
+def onehot_commit(vals_row, seg_row, e, tile: int) -> jax.Array:
+    """One block's contribution to its ``(tile, R)`` output tile:
+    ``(onehot(row_in_tile) * val) @ E`` on the MXU, with ``vals_row`` and
+    ``seg_row`` the block's ``(1, P)`` values and rows-in-tile and ``e`` its
+    ``(P, R)`` product of input factor rows."""
+    p = seg_row.shape[-1]
+    onehot = seg_row == jax.lax.broadcasted_iota(jnp.int32, (tile, p), 0)
+    lhs = jnp.where(onehot, vals_row.astype(jnp.float32), 0.0)
+    return jnp.dot(lhs, e, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def _ec_kernel(nin: int, base, b2t, vals_ref, seg_ref, *refs):
+    # refs: rows_ref_0..rows_ref_{nin-1}, acc_ref (aliased to out), out_ref
+    rows_refs = refs[:nin]
+    acc_ref, out_ref = refs[nin], refs[nin + 1]
     i = pl.program_id(0)
 
-    prev = b2t[jnp.maximum(i - 1, 0)]
-
-    @pl.when(jnp.logical_or(i == 0, prev != b2t[i]))
+    @pl.when(tl.first_visit(b2t, i))
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        out_ref[...] = acc_ref[...]
 
-    e = vals_ref[...].astype(jnp.float32)[:, None]
-    for rr in rows_refs:
+    e = rows_refs[0][...].astype(jnp.float32)
+    for rr in rows_refs[1:]:
         e = e * rr[...].astype(jnp.float32)
-    tile = out_ref.shape[0]
-    p = e.shape[0]
-    seg = seg_ref[...]
-    onehot = (seg[None, :] == jax.lax.broadcasted_iota(jnp.int32, (tile, p), 0))
-    out_ref[...] += jnp.dot(onehot.astype(jnp.float32), e,
-                            preferred_element_type=jnp.float32)
+    out_ref[...] += onehot_commit(tl.window_row(vals_ref, base, i),
+                                  tl.window_row(seg_ref, base, i), e,
+                                  out_ref.shape[0])
 
 
 def ec_blocked(
@@ -74,30 +85,34 @@ def ec_blocked(
     block_p: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """Blocked EC: returns (num_rows, R) f32."""
+    """Blocked EC: returns (num_rows, R) f32 (tiles no block visits are 0)."""
     nnz = values.shape[0]
     assert nnz % block_p == 0, (nnz, block_p)
     assert num_rows % tile == 0, (num_rows, tile)
     nblocks = nnz // block_p
     r = gathered_rows[0].shape[-1]
     nin = len(gathered_rows)
+    vals = values.reshape(nblocks, block_p)
+    seg = row_in_tile.astype(jnp.int32).reshape(nblocks, block_p)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((block_p,), lambda i, b2t: (i,)),
-            pl.BlockSpec((block_p,), lambda i, b2t: (i,)),
-        ] + [
-            pl.BlockSpec((block_p, r), lambda i, b2t: (i, 0))
-            for _ in range(nin)
-        ],
-        out_specs=pl.BlockSpec((tile, r), lambda i, b2t: (b2t[i], 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_ec_kernel, nin),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_rows, r), jnp.float32),
-        interpret=interpret,
-        name=f"amped_ec_nin{nin}",
-    )(block_to_tile, values, row_in_tile, *gathered_rows)
+    def launch(n, base, b2t, out):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n,),
+            in_specs=[tl.window_spec(nblocks, block_p)] * 2 + [
+                pl.BlockSpec((block_p, r), lambda i, base, b2t: (base[0] + i, 0))
+                for _ in range(nin)
+            ] + [pl.BlockSpec((tile, r), lambda i, base, b2t: (b2t[i], 0))],
+            out_specs=pl.BlockSpec((tile, r), lambda i, base, b2t: (b2t[i], 0)),
+        )
+        return pl.pallas_call(
+            functools.partial(_ec_kernel, nin),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((num_rows, r), jnp.float32),
+            input_output_aliases={4 + nin: 0},
+            interpret=interpret,
+            name=f"amped_ec_nin{nin}",
+        )(base, b2t, vals, seg, *gathered_rows, out)
+
+    return tl.chunked(launch, nblocks=nblocks, block_to_tile=block_to_tile,
+                      out=jnp.zeros((num_rows, r), jnp.float32))

@@ -10,27 +10,31 @@ copy (arXiv 2405.08470):
 
   * the device shard is row-sorted (``layout="sorted"`` in
     core/partition.py): each block's ``local_rows`` decompose into at most
-    ``tile + 1`` runs of equal output row, described by scalar-prefetched
-    per-block segment descriptors (``seg_starts``/``seg_rows``, see
-    ``core.partition.block_segment_descriptors``),
-  * factor rows stream exactly as in ``ec_fused`` — HBM-resident factors
-    (``pltpu.ANY``), lookahead index views, a rotating ring of
-    ``num_buffers`` VMEM slots filled by async row DMAs, one aggregated
-    semaphore wait per slot,
+    ``tile + 1`` runs of equal output row, described by per-block segment
+    descriptors (``seg_starts``/``seg_rows``, see
+    ``core.partition.block_segment_descriptors``) that the kernel DMAs into
+    SMEM one block at a time, together with the block's values,
+  * factor rows stream exactly as in ``ec_fused`` (``RowGather``) —
+    HBM-resident lane-padded factors, an HBM index slab staged per block
+    into SMEM, a rotating ring of ``num_buffers`` VMEM slots filled by async
+    row DMAs, one aggregated semaphore wait per slot,
   * each segment accumulates in a ``(1, R)`` register/VMEM accumulator and
     read-modify-writes its output row once — the row's current partial is
-    loaded, the segment's elementwise products are added in slot order, and
-    the row is stored back. No one-hot matmul, no per-block tile rewrite,
-    and the ``row_in_tile`` array is never shipped to the kernel at all.
+    loaded, the segment's elementwise products ``(val · A[i0]) · B[i1] ...``
+    are added in slot order, and the row is stored back. No one-hot matmul,
+    no per-block tile rewrite, and the ``row_in_tile`` array is never
+    shipped to the kernel at all.
 
 Accumulation order is *slot order*, exactly the order XLA's scatter-add
-(`segment_sum`) uses, so the result is bit-identical to ``ref`` — on both
-layouts (on the legacy blocked layout a pad run may revisit an earlier row,
-but pads contribute exact ``0.0`` adds in the same slot positions).
+(`segment_sum`) uses, and the elementwise product is formed in ``ref``'s
+order, so the result is bit-identical to ``ref`` — on both layouts (on the
+legacy blocked layout a pad run may revisit an earlier row, but pads
+contribute exact ``0.0`` adds in the same slot positions).
 
 Kernel contract (core/partition.py): fixed-size ``block_p`` blocks, every
 block updates rows inside one output tile, blocks of a tile consecutive,
 padding entries have ``values == 0`` and in-bounds index/row entries.
+Operand layouts and the chunked launch follow ``tpu_layout``.
 """
 from __future__ import annotations
 
@@ -42,92 +46,73 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import tpu_layout as tl
+from repro.kernels.mttkrp_fused import (RowGather, check_num_buffers,
+                                        gather_scratch)
+
 __all__ = ["ec_sorted"]
 
-MAX_NUM_BUFFERS = 4
 
+def _sorted_kernel(nin: int, n: int, nseg: int, base, b2t, vals_hbm,
+                   seg_hbm, idx_hbm, *refs):
+    """refs layout (after the scalar-prefetched ``base``/``b2t`` and the HBM
+    value slab, segment records and index slab):
 
-def _sorted_kernel(nin: int, num_buffers: int, nblocks: int, nseg: int,
-                   b2t, seg_starts, seg_rows, *refs):
-    """refs layout (after the scalar-prefetched descriptors):
-
-      vals_ref,
-      idx_ref_0 .. idx_ref_{L},      L+1 views of the index array; idx_ref_k
-                                     holds block min(i+k, nblocks-1)'s slice
-      fac_ref_0 .. fac_ref_{nin-1},  full factor matrices, HBM-resident
+      fac_ref_0 .. fac_ref_{nin-1},  lane-padded factors, HBM-resident
+      acc_ref,                       running output (aliased to out_ref)
       out_ref,
-      idx_smem, row_buf, row_sems, stage_sem
+      vals_smem, seg_smem, e_buf, idx_smem, row_buf, row_sems, stage_sem
     """
-    lookahead = num_buffers - 1
-    vals_ref = refs[0]
-    idx_refs = refs[1:1 + lookahead + 1]
-    fac_refs = refs[1 + lookahead + 1:1 + lookahead + 1 + nin]
-    out_ref = refs[1 + lookahead + 1 + nin]
-    idx_smem, row_buf, row_sems, stage_sem = refs[-4:]
-
+    acc_ref, out_ref = refs[nin], refs[nin + 1]
+    vals_smem, seg_smem, e_buf = refs[nin + 2:nin + 5]
+    gather = RowGather(idx_hbm, refs[:nin], *refs[nin + 5:])
     i = pl.program_id(0)
-    block_p = vals_ref.shape[0]
+    slot = gather.pipeline(base[0], i, n)
 
-    def start_rows(idx_ref, slot):
-        """Stage idx_ref (VMEM) into SMEM, then launch one row DMA per
-        (nonzero, input mode) into ``row_buf[slot]``."""
-        stage = pltpu.make_async_copy(idx_ref, idx_smem, stage_sem)
+    # This block's values and segment record, staged for scalar reads.
+    g = base[0] + i
+    srows = vals_smem.shape[0]
+    flat0 = g * gather.block_p
+    off = jax.lax.rem(flat0, tl.LANES)
+    for src, dst in ((vals_hbm.at[pl.ds(flat0 // tl.LANES, srows), :],
+                      vals_smem),
+                     (seg_hbm.at[pl.ds(g, 1), :], seg_smem)):
+        stage = pltpu.make_async_copy(src, dst, gather.stage_sem)
         stage.start()
         stage.wait()
 
-        def body(p, _):
-            for w in range(nin):
-                pltpu.make_async_copy(
-                    fac_refs[w].at[idx_smem[p, w]],
-                    row_buf.at[slot, w, p],
-                    row_sems.at[slot],
-                ).start()
-            return 0
-
-        jax.lax.fori_loop(0, block_p, body, 0)
-
-    @pl.when(i == 0)
-    def _prologue():
-        for k in range(lookahead):
-            if k < nblocks:
-                start_rows(idx_refs[k], k % num_buffers)
-
-    @pl.when(i + lookahead < nblocks)
-    def _prefetch():
-        start_rows(idx_refs[lookahead],
-                   jax.lax.rem(i + lookahead, num_buffers))
-
-    slot = jax.lax.rem(i, num_buffers)
-    pltpu.make_async_copy(row_buf.at[slot], row_buf.at[slot],
-                          row_sems.at[slot]).wait()
-
-    prev = b2t[jnp.maximum(i - 1, 0)]
-
-    @pl.when(jnp.logical_or(i == 0, prev != b2t[i]))
+    @pl.when(tl.first_visit(b2t, i))
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        out_ref[...] = acc_ref[...]
 
-    e = vals_ref[...].astype(jnp.float32)[:, None]
-    for w in range(nin):
-        e = e * row_buf[slot, w]
+    # Elementwise products in ref's order, (val * A[i0]) * B[i1] ..., staged
+    # in VMEM so the segment adds below read finished rows (a product formed
+    # inside the add loop could be contracted into a fused multiply-add and
+    # round differently from ref).
+    def product(p, _):
+        q = off + p
+        e = vals_smem[q // tl.LANES, jax.lax.rem(q, tl.LANES)] \
+            * gather.row_buf[slot, 0, pl.ds(p, 1), :]
+        for w in range(1, nin):
+            e = e * gather.row_buf[slot, w, pl.ds(p, 1), :]
+        e_buf[pl.ds(p, 1), :] = e
+        return 0
+
+    jax.lax.fori_loop(0, gather.block_p, product, 0)
 
     # Segmented reduction: each run of equal output row accumulates in a
     # (1, R) accumulator, added in slot order (== segment_sum's order), and
     # its row is read-modify-written exactly once per segment.
     for s in range(nseg):
-        start = seg_starts[i, s]
-        end = seg_starts[i, s + 1]
-        row = seg_rows[i, s]
+        start = seg_smem[0, s]
+        end = seg_smem[0, s + 1]
+        row = seg_smem[0, nseg + 1 + s]
 
         @pl.when(end > start)
         def _segment(start=start, end=end, row=row):
-            acc = out_ref[pl.ds(row, 1), :]
-
-            def body(p, acc):
-                return acc + jax.lax.dynamic_slice_in_dim(e, p, 1, axis=0)
-
             out_ref[pl.ds(row, 1), :] = jax.lax.fori_loop(
-                start, end, body, acc)
+                start, end, lambda p, acc: acc + e_buf[pl.ds(p, 1), :],
+                out_ref[pl.ds(row, 1), :])
 
 
 def ec_sorted(
@@ -135,7 +120,7 @@ def ec_sorted(
     seg_starts: jax.Array,             # (nblocks, S+1) int32, S = tile+1
     seg_rows: jax.Array,               # (nblocks, S) int32 in [0, tile)
     block_to_tile: jax.Array,          # (nblocks,) int32, scalar-prefetched
-    input_indices: jax.Array,          # (nnz, nin) int32 rows into factors[w]
+    input_indices: jax.Array,          # (nin, nnz) int32 rows into factors[w]
     factors: Sequence[jax.Array],      # nin arrays (padded_w, R), HBM-resident
     *,
     num_rows: int,                     # rows_max (multiple of tile)
@@ -147,54 +132,54 @@ def ec_sorted(
     """Segmented-reduction EC on the row-sorted block layout.
 
     Returns (num_rows, R) f32, bit-identical to the ``ref`` oracle.
-    ``input_indices[:, j]`` indexes ``factors[j]`` (the output mode is
+    ``input_indices[j]`` indexes ``factors[j]`` (the output mode is
     compacted away by the caller, see ops.py); descriptors come from
     ``core.partition.block_segment_descriptors``.
     """
     nnz = values.shape[0]
     assert nnz % block_p == 0, (nnz, block_p)
     assert num_rows % tile == 0, (num_rows, tile)
-    if not (2 <= num_buffers <= MAX_NUM_BUFFERS):
-        raise ValueError(
-            f"num_buffers must be in [2, {MAX_NUM_BUFFERS}], got {num_buffers}")
+    check_num_buffers(num_buffers)
+    tl.check_block_p(block_p)
     nblocks = nnz // block_p
     nin = len(factors)
-    assert input_indices.shape == (nnz, nin), (input_indices.shape, nnz, nin)
+    assert input_indices.shape == (nin, nnz), (input_indices.shape, nnz, nin)
     nseg = seg_rows.shape[-1]
     assert seg_starts.shape == (nblocks, nseg + 1), (seg_starts.shape, nseg)
     assert seg_rows.shape == (nblocks, nseg), (seg_rows.shape, nblocks)
     r = factors[0].shape[-1]
-    lookahead = num_buffers - 1
+    facs = [tl.pad_lanes(f.astype(jnp.float32)) for f in factors]
+    rp = facs[0].shape[-1]
+    vals = tl.lane_slab(values.astype(jnp.float32))
+    # one lane-aligned record per block: [starts (S+1) | rows (S) | pad]
+    seg = tl.pad_lanes(jnp.concatenate(
+        [seg_starts.astype(jnp.int32), seg_rows.astype(jnp.int32)], axis=1))
+    idx = tl.lane_slab(input_indices.astype(jnp.int32))
+    srows = tl.slab_rows(block_p)
 
-    def idx_map(k):
-        return lambda i, b2t, ss, sr: (jnp.minimum(i + k, nblocks - 1), 0)
+    def launch(n, base, b2t, out):
+        tile_spec = pl.BlockSpec((tile, rp), lambda i, base, b2t: (b2t[i], 0))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (3 + nin)
+            + [tile_spec],
+            out_specs=tile_spec,
+            scratch_shapes=[
+                pltpu.SMEM((srows, tl.LANES), jnp.float32),
+                pltpu.SMEM((1, seg.shape[1]), jnp.int32),
+                pltpu.VMEM((block_p, rp), jnp.float32),
+            ] + gather_scratch(nin, block_p, rp, num_buffers),
+        )
+        return pl.pallas_call(
+            functools.partial(_sorted_kernel, nin, n, nseg),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((num_rows, rp), jnp.float32),
+            input_output_aliases={5 + nin: 0},
+            interpret=interpret,
+            name=f"amped_ec_sorted_nin{nin}_nb{num_buffers}",
+        )(base, b2t, vals, seg, idx, *facs, out)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((block_p,), lambda i, b2t, ss, sr: (i,)),
-        ] + [
-            pl.BlockSpec((block_p, nin), idx_map(k))
-            for k in range(lookahead + 1)
-        ] + [
-            pl.BlockSpec(memory_space=pltpu.ANY) for _ in range(nin)
-        ],
-        out_specs=pl.BlockSpec((tile, r), lambda i, b2t, ss, sr: (b2t[i], 0)),
-        scratch_shapes=[
-            pltpu.SMEM((block_p, nin), jnp.int32),
-            pltpu.VMEM((num_buffers, nin, block_p, r), jnp.float32),
-            pltpu.SemaphoreType.DMA((num_buffers,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    facs32 = [f.astype(jnp.float32) for f in factors]
-    return pl.pallas_call(
-        functools.partial(_sorted_kernel, nin, num_buffers, nblocks, nseg),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_rows, r), jnp.float32),
-        interpret=interpret,
-        name=f"amped_ec_sorted_nin{nin}_nb{num_buffers}",
-    )(block_to_tile, seg_starts.astype(jnp.int32),
-      seg_rows.astype(jnp.int32), values,
-      *([input_indices] * (lookahead + 1)), *facs32)
+    out = tl.chunked(launch, nblocks=nblocks, block_to_tile=block_to_tile,
+                     out=jnp.zeros((num_rows, rp), jnp.float32))
+    return out[:, :r]

@@ -116,8 +116,9 @@ def _mask_unvisited(out: jax.Array, tile_mask: jax.Array | None,
                     tile: int) -> jax.Array:
     if tile_mask is None:
         return out
-    # Tiles never visited by a block are uninitialised VMEM (possibly
-    # NaN) — select, don't multiply (NaN * 0 == NaN).
+    # The kernels accumulate into a zeroed output, so unvisited tiles are
+    # already 0; the select keeps that a stated contract (select, don't
+    # multiply: NaN * 0 == NaN).
     mask = jnp.repeat(tile_mask > 0, tile)[:, None]
     return jnp.where(mask, out, 0.0)
 
@@ -148,10 +149,10 @@ def _run_fused(indices, values, local_rows, block_to_tile, factors, *,
                mode, num_rows, tile, block_p, interpret, tile_mask,
                num_buffers, seg_starts, seg_rows, rows_sorted):
     del seg_starts, seg_rows, rows_sorted
-    # Compact the input-mode index columns into one (nnz, nin) array; the
+    # Compact the input-mode index columns into one (nin, nnz) array; the
     # factor matrices themselves stay in HBM (no (nnz, R) intermediate).
     in_modes = [w for w in range(len(factors)) if w != mode]
-    input_indices = jnp.stack([indices[:, w] for w in in_modes], axis=1)
+    input_indices = jnp.stack([indices[:, w] for w in in_modes])
     row_in_tile = (local_rows % tile).astype(jnp.int32)
     out = ec_fused(
         values, row_in_tile, block_to_tile, input_indices,
@@ -171,7 +172,7 @@ def _run_sorted(indices, values, local_rows, block_to_tile, factors, *,
             "them with core.partition.block_segment_descriptors(local_rows, "
             "tile=..., block_p=...) and pass seg_starts=/seg_rows=")
     in_modes = [w for w in range(len(factors)) if w != mode]
-    input_indices = jnp.stack([indices[:, w] for w in in_modes], axis=1)
+    input_indices = jnp.stack([indices[:, w] for w in in_modes])
     out = ec_sorted(
         values, seg_starts, seg_rows, block_to_tile, input_indices,
         [factors[w] for w in in_modes],

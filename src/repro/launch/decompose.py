@@ -106,6 +106,8 @@ def main():
                          "live")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from repro.obs import clock
     from repro.obs import trace as obs_trace
     if args.trace_out:

@@ -243,3 +243,45 @@ def test_reconstruct_at_matches_dense():
     coords = np.stack([ii.ravel(), jj.ravel(), kk.ravel()], axis=1)
     got = res.reconstruct_at(coords).reshape(6, 5, 4)
     np.testing.assert_allclose(got, dense, rtol=1e-5)
+
+
+# -- compile cache (entry points) ---------------------------------------------
+
+_CACHE_SCRIPT = r"""
+import json, sys
+import jax, jax.numpy as jnp
+from repro.launch import compile_cache
+path = compile_cache.enable()
+if sys.argv[1:] == ["compile"]:
+    jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+print(json.dumps({"path": path,
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(from_env, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and receives the entries; unset,
+    the cache is the fixed ``<repo>/.jax_cache``."""
+    import json
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    # unset, nothing is compiled: the test writes nothing in the checkout
+    argv = ["compile"] if from_env else []
+    out = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT, *argv],
+                         env=env, text=True, capture_output=True,
+                         timeout=300, check=True)
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    if from_env:
+        assert got["path"] == got["config"] == str(tmp_path)
+        assert os.listdir(tmp_path)
+    else:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got["path"] == got["config"] == os.path.join(repo,
+                                                            ".jax_cache")

@@ -61,11 +61,12 @@ results["overlap_bitwise"] = bool(
 # --- merge variants over the sub axis (r=2) ------------------------------
 y = jnp.asarray(rng.normal(size=(2, 8, 3)).astype(np.float32))
 
+# each device returns its (4, 3) row slice of the merged (8, 3) partial
 def merge(**kw):
     fn = lambda v: comm.merge_partials(v.reshape(8, 3), "sub", **kw)
     return np.asarray(jax.jit(shard_map(
         fn, mesh=mesh, in_specs=P("group", None, None),
-        out_specs=P("group", "sub", None)))(y))
+        out_specs=P(("group", "sub"), None)))(y))
 
 ps = merge(merge="psum_scatter")
 results["ring_rs_matches_psum_scatter"] = bool(
@@ -79,7 +80,7 @@ try:
     merge_bad = lambda v: comm.merge_partials(v.reshape(8, 3)[:7], "sub")
     jax.jit(shard_map(merge_bad, mesh=mesh,
                       in_specs=P("group", None, None),
-                      out_specs=P("group", "sub", None)))(y)
+                      out_specs=P(("group", "sub"), None)))(y)
     results["nondivisible_raises"] = False
 except ValueError as e:
     results["nondivisible_raises"] = "not divisible" in str(e)

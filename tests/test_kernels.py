@@ -343,3 +343,43 @@ def test_autotune_cache_v1_migration(tmp_path, monkeypatch):
     at._MEMO.clear()
     miss = at.autotune_ec(3, 8, dtype=jnp.bfloat16, **kw)
     assert dict(miss.timings) != {"t8_p64_b2": 1.0}  # re-tuned, no replay
+
+
+@pytest.mark.parametrize("variant", ["blocked", "fused", "sorted"])
+def test_chunked_launch_matches_single_launch(variant, monkeypatch):
+    """Splitting the grid into launches of a few blocks (a fori_loop over
+    full chunks plus a tail) gives the single launch's output bit for bit:
+    a tile that straddles two launches carries its partial sum through the
+    aliased running output."""
+    from repro.core.coo import random_sparse
+    from repro.core.partition import block_segment_descriptors, partition_mode
+    from repro.kernels.mttkrp_fused import ec_fused
+    from repro.kernels.mttkrp_sorted import ec_sorted
+    from repro.kernels import tpu_layout
+    t = random_sparse((40, 18, 12), 600, seed=4, distribution="zipf")
+    part, _, _ = partition_mode(t, 0, 1, replication=1, tile=8, block_p=16,
+                                layout="sorted")
+    assert part.nblocks > 7
+    rng = np.random.default_rng(4)
+    factors = [jnp.asarray(rng.normal(size=(s, 16)).astype(np.float32))
+               for s in t.shape]
+    ind = part.indices[0]
+    vals, b2t = jnp.asarray(part.values[0]), jnp.asarray(part.block_to_tile[0])
+    rit = jnp.asarray(part.local_rows[0] % part.tile)
+    kw = dict(num_rows=part.rows_max, tile=part.tile, block_p=part.block_p,
+              interpret=True)
+    if variant == "blocked":
+        gathered = [factors[w][ind[:, w]] for w in (1, 2)]
+        run = lambda: ec_blocked(vals, rit, b2t, gathered, **kw)
+    elif variant == "fused":
+        idx = jnp.asarray(ind[:, 1:].T)
+        run = lambda: ec_fused(vals, rit, b2t, idx, factors[1:], **kw)
+    else:
+        ss, sr = block_segment_descriptors(part.local_rows[0], tile=part.tile,
+                                           block_p=part.block_p)
+        idx = jnp.asarray(ind[:, 1:].T)
+        run = lambda: ec_sorted(vals, jnp.asarray(ss), jnp.asarray(sr), b2t,
+                                idx, factors[1:], **kw)
+    single = np.asarray(run())
+    monkeypatch.setattr(tpu_layout, "MAX_CHUNK_BLOCKS", 3)
+    np.testing.assert_array_equal(np.asarray(run()), single)
