@@ -236,10 +236,10 @@ class RuntimeConfig:
     stream_spill: bool = True       # on-disk window cache across sweeps
     stream_spill_dir: str | None = None  # spill location (None = tempdir)
     # ``trace=True`` enables the repro.obs span tracer for this solver's
-    # lifetime: sweeps run a traced path that dispatches EC and exchange
-    # separately (bitwise-identical fits, documented sync points) so each
-    # stage gets its own host span. Off by default — the hot path then
-    # stays fully async and spans cost one dict lookup each.
+    # lifetime: sweeps record sweep → mode_update spans (each also a
+    # jax.profiler annotation) and run the same programs, with no added
+    # sync and bitwise-identical fits. Off by default — spans then cost
+    # one attribute check each.
     trace: bool = False
 
     def __post_init__(self):

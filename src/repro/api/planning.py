@@ -305,7 +305,7 @@ def plan(tensor: SparseTensor | TensorStore, config: DecomposeConfig, *,
     ``"strict"`` raises on any error finding before the plan escapes,
     ``"warn"`` reports findings to stderr, ``"off"`` (default) skips.
     """
-    with obs_trace.span("plan", annotate=True):
+    with obs_trace.span("plan"):
         nd = _resolve_num_devices(config, num_devices)
         tile, block_p = _resolve_geometry(tensor.nmodes, config)
 

@@ -118,7 +118,6 @@ class CPSolver:
             obs_trace.enable()
         kernel_kw = config.kernel.mttkrp_kwargs(nmodes=plan.nmodes,
                                                 rank=config.rank)
-        self._kernel_kw = kernel_kw
         self.exchange_spec = comm.resolve_exchange_spec(
             config.exchange, plan=plan, rank=config.rank, mesh=mesh)
         if self.streaming:
@@ -187,9 +186,6 @@ class CPSolver:
         if config.runtime.checkpoint_dir is not None:
             from repro.training.checkpoint import CheckpointManager
             self._ckpt_mgr = CheckpointManager(config.runtime.checkpoint_dir)
-        # traced resident sweeps need split EC/exchange dispatches — built
-        # lazily on the first traced sweep (see _traced_updates)
-        self._traced_updates_cache = None
         self.metrics.register_provider("overlap", self.overlap_report)
         self.metrics.register_provider("imbalance", self.imbalance_report)
         self.metrics.register_provider(
@@ -316,19 +312,6 @@ class CPSolver:
         })
 
     # -- execution ---------------------------------------------------------
-    def _traced_updates(self):
-        """Split EC/exchange jitted triples for the RESIDENT plan — the
-        traced sweep path. Accumulating the fused MTTKRP's partial into a
-        zero accumulator then finishing (merge/exchange/solve) is bitwise
-        identical to the fused update; splitting the dispatch is what lets
-        each stage carry its own host span. Two extra compiles per mode,
-        paid once on the first traced sweep."""
-        if self._traced_updates_cache is None:
-            self._traced_updates_cache = als_mod.make_streaming_sweep_updates(
-                self.plan, self.mesh, rank=self.config.rank,
-                exchange_spec=self.exchange_spec, **self._kernel_kw)
-        return self._traced_updates_cache
-
     def sweep(self) -> als_mod.ALSState:
         """One full ALS sweep (all modes). Enqueues device work only; the
         appended fit is a device scalar (reading it blocks the host).
@@ -338,13 +321,11 @@ class CPSolver:
         sweep's transfer/exposed timings are emitted as ``stream_sweep``
         events (see :attr:`stream_events` / :meth:`overlap_report`).
 
-        With the span tracer enabled (``runtime.trace=True`` or
-        ``obs.trace.enable()``) a resident sweep runs
-        :func:`~repro.core.als.als_traced_sweep` instead — EC and exchange
-        as separate dispatches with their own spans, fits still bitwise
-        identical, at the documented cost of per-stage sync points."""
-        tracer = obs_trace.get_tracer()
-        with tracer.span("sweep", sweep=self.state.sweep + 1, annotate=True):
+        The span tracer (``runtime.trace=True`` or ``obs.trace.enable()``)
+        only records: a traced sweep runs the same compiled programs, with
+        no added sync, and gives bitwise-identical fits. Its ``sweep`` span
+        holds one ``mode_update`` span per mode."""
+        with obs_trace.span("sweep", sweep=self.state.sweep + 1):
             if self.streaming:
                 before = self.streamer.stats_snapshot()
                 self.state = als_mod.als_streaming_sweep(
@@ -364,10 +345,6 @@ class CPSolver:
                         hidden / transfer if transfer > 0 else None),
                     shards_streamed=after["builds"] - before["builds"],
                 )
-            elif tracer.enabled:
-                self.state = als_mod.als_traced_sweep(
-                    self.plan, self.mesh, self.dev_arrays, self.state,
-                    self._traced_updates())
             else:
                 self.state = als_mod.als_sweep(self.plan, self.mesh,
                                                self.dev_arrays, self.state,
@@ -418,7 +395,7 @@ class CPSolver:
         if tol is None:
             tol = self.config.runtime.tol
         cadence = self.config.schedule.cadence
-        with obs_trace.span("run", iters=iters, annotate=True):
+        with obs_trace.span("run", iters=iters):
             for _ in range(self.state.sweep, iters):
                 state = self.sweep()
                 if verbose:
@@ -572,8 +549,12 @@ class CPSolver:
     def dump_trace(self, path: str) -> dict:
         """Export every span the process tracer recorded as Chrome-trace
         JSON (load in ``chrome://tracing`` or https://ui.perfetto.dev);
-        returns the trace dict. Spans nest run → sweep → mode_update →
-        {ec, exchange, h2d_window} (+ plan/compile/checkpoint/rebalance)."""
+        returns the trace dict. Spans nest run → sweep → mode_update
+        (streamed sweeps add {h2d_window, ec, exchange} under each
+        mode_update), beside plan → {plan.sort, plan.block,
+        plan.translate}, compile, checkpoint and rebalance. A traced run
+        runs the same programs as an untraced one; each span is also a
+        ``jax.profiler`` annotation of its name."""
         return obs_export.dump_chrome_trace(
             path, obs_trace.get_tracer().records())
 
@@ -628,7 +609,7 @@ def compile(plan: CPPlan, config: DecomposeConfig, *,
     cheap relative to ``plan()`` at scale."""
     if config.runtime.trace:
         obs_trace.enable()  # before the span below so it is recorded
-    with obs_trace.span("compile", annotate=True):
+    with obs_trace.span("compile"):
         from repro.core.partition import validate_plan
         validate_plan(plan)  # fail loudly before any device placement
         if mesh is None:

@@ -30,14 +30,15 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.core import mttkrp as dmttkrp
-from repro.obs import trace as obs_trace
 from repro.core.partition import CPPlan
+from repro.obs import profiler as obs_profiler
+from repro.obs import trace as obs_trace
 
 __all__ = ["ALSState", "init_factors", "matmul", "gram", "make_mode_update",
            "make_sweep_updates", "als_sweep", "fit_from_stats",
            "unpad_factors", "StreamingModeUpdate",
            "make_streaming_mode_update", "make_streaming_sweep_updates",
-           "als_streaming_sweep", "als_traced_sweep"]
+           "als_streaming_sweep"]
 
 
 @dataclasses.dataclass
@@ -83,6 +84,23 @@ def _pinv_psd(v: jax.Array, rcond: float = 1e-8) -> jax.Array:
     return matmul(u * w_inv[None, :], u.T)
 
 
+def _solve(m: jax.Array, grams: Sequence[jax.Array], mode: int):
+    """The ALS algebra of one mode update, under the ``als_solve`` device
+    scope: ``V`` (the Hadamard of the other modes' Grams), ``F = M V⁺``,
+    λ = colnorms(F), ``F /= λ`` and ``F``'s new Gram. Returns
+    ``(F, G, λ)``."""
+    with obs_profiler.device_scope("als_solve"):
+        v = functools.reduce(
+            lambda a, b: a * b,
+            [g for w, g in enumerate(grams) if w != mode])    # (R, R)
+        f_new = matmul(m, _pinv_psd(v))
+        lam = jnp.linalg.norm(f_new, axis=0)
+        lam = jnp.where(lam > 0, lam, 1.0)
+        f_new = f_new / lam[None, :]
+        g_new = gram(f_new)
+    return f_new, g_new, lam
+
+
 def make_mode_update(plan: CPPlan, mode: int, mesh: Mesh, **mttkrp_kw) -> Callable:
     """Jitted ``(F_d_old, dev_arrays, other_factors, grams) ->
     (F_d, G_d, M_d, lam)``.
@@ -94,21 +112,13 @@ def make_mode_update(plan: CPPlan, mode: int, mesh: Mesh, **mttkrp_kw) -> Callab
     does not implement it.
     """
     mfn = dmttkrp.make_mttkrp_fn(plan.modes[mode], mesh, **mttkrp_kw)
-    n = plan.nmodes
 
     def update(f_old: jax.Array, dev, other_factors: Sequence[jax.Array],
                grams: Sequence[jax.Array]):
         factors = list(other_factors[:mode]) + [f_old] + \
             list(other_factors[mode:])
         m = mfn(dev, factors)                             # (padded_d, R)
-        v = functools.reduce(
-            lambda a, b: a * b,
-            [grams[w] for w in range(n) if w != mode])     # (R, R)
-        f_new = matmul(m, _pinv_psd(v))
-        lam = jnp.linalg.norm(f_new, axis=0)
-        lam = jnp.where(lam > 0, lam, 1.0)
-        f_new = f_new / lam[None, :]
-        g_new = gram(f_new)
+        f_new, g_new, lam = _solve(m, grams, mode)
         return f_new, g_new, m, lam
 
     donate = (0,) if jax.default_backend() != "cpu" else ()
@@ -171,7 +181,6 @@ def make_streaming_mode_update(plan: CPPlan, mode: int, mesh: Mesh, *,
     finish_kw = {k: v for k, v in mttkrp_kw.items()
                  if k in _STREAM_EXCHANGE_KEYS}
     part = plan.modes[mode]
-    n = plan.nmodes
     pfn = dmttkrp.make_partial_mttkrp_fn(part, mesh, **axis_kw, **kernel_kw)
     ffn = dmttkrp.make_streaming_finish_fn(part, mesh, **axis_kw,
                                            **finish_kw)
@@ -185,14 +194,7 @@ def make_streaming_mode_update(plan: CPPlan, mode: int, mesh: Mesh, *,
     def finish(f_old: jax.Array, acc, other_factors: Sequence[jax.Array],
                grams: Sequence[jax.Array]):
         m = ffn(acc)                                       # (padded_d, R)
-        v = functools.reduce(
-            lambda a, b: a * b,
-            [grams[w] for w in range(n) if w != mode])     # (R, R)
-        f_new = matmul(m, _pinv_psd(v))
-        lam = jnp.linalg.norm(f_new, axis=0)
-        lam = jnp.where(lam > 0, lam, 1.0)
-        f_new = f_new / lam[None, :]
-        g_new = gram(f_new)
+        f_new, g_new, lam = _solve(m, grams, mode)
         return f_new, g_new, m, lam
 
     donate = jax.default_backend() != "cpu"
@@ -230,13 +232,13 @@ def als_streaming_sweep(plan: CPPlan, mesh: Mesh, streamer, stream_plans,
     factors, grams = list(state.factors), list(state.grams)
     m_last = f_last = lam = None
     for d in range(n):
-        with tracer.span("mode_update", mode=d, annotate=True):
+        with tracer.span("mode_update", mode=d):
             upd = updates[d]
             acc = upd.init_acc()
             for k in range(stream_plans[d].num_shards):
                 with tracer.span("h2d_window", mode=d, shard=k):
                     dev = streamer.get(d, k)
-                with tracer.span("ec", mode=d, shard=k, annotate=True):
+                with tracer.span("ec", mode=d, shard=k):
                     acc = upd.accumulate(acc, dev, factors)
                     # double-buffer barrier: shard k+1's compute
                     # data-depends on this accumulator, so waiting costs
@@ -246,49 +248,11 @@ def als_streaming_sweep(plan: CPPlan, mesh: Mesh, streamer, stream_plans,
                     # host queue-ahead racing the async dispatch)
                     jax.block_until_ready(acc)
             others = [factors[w] for w in range(n) if w != d]
-            with tracer.span("exchange", mode=d, annotate=True):
+            with tracer.span("exchange", mode=d):
                 f_d, g_d, m_d, lam = upd.finish(factors[d], acc, others,
                                                 grams)
-                if tracer.enabled:
-                    # only when traced: close the span at the true end of
-                    # merge/exchange/solve instead of at dispatch
-                    jax.block_until_ready(f_d)
             factors[d], grams[d] = f_d, g_d
             m_last, f_last = m_d, f_d
-    fit = fit_from_stats(plan.norm, m_last, f_last, lam, grams)
-    return ALSState(factors=factors, lam=lam, grams=grams,
-                    sweep=state.sweep + 1, fits=state.fits + [fit])
-
-
-def als_traced_sweep(plan: CPPlan, mesh: Mesh, dev_arrays: Sequence,
-                     state: ALSState,
-                     updates: Sequence[StreamingModeUpdate]) -> ALSState:
-    """Traced twin of :func:`als_sweep` for resident shards: runs each mode
-    through a :class:`StreamingModeUpdate` triple built for the *resident*
-    plan, so the EC partial (``accumulate`` on a zero accumulator — bitwise
-    equal to the fused MTTKRP partial) and the merge/exchange/solve
-    (``finish``) are separate jitted dispatches, each wrapped in its own
-    host span and synced at its end. Fits are bitwise identical to
-    :func:`als_sweep`; the added ``block_until_ready`` calls are the
-    documented cost of stage-attributed timing (the untraced path stays
-    fully async — :class:`repro.api.CPSolver` picks per sweep)."""
-    n = plan.nmodes
-    tracer = obs_trace.get_tracer()
-    factors, grams = list(state.factors), list(state.grams)
-    m_last = f_last = lam = None
-    for d in range(n):
-        upd = updates[d]
-        with tracer.span("mode_update", mode=d, annotate=True):
-            with tracer.span("ec", mode=d, annotate=True):
-                acc = upd.accumulate(upd.init_acc(), dev_arrays[d], factors)
-                jax.block_until_ready(acc)
-            others = [factors[w] for w in range(n) if w != d]
-            with tracer.span("exchange", mode=d, annotate=True):
-                f_d, g_d, m_d, lam = upd.finish(factors[d], acc, others,
-                                                grams)
-                jax.block_until_ready(f_d)
-        factors[d], grams[d] = f_d, g_d
-        m_last, f_last = m_d, f_d
     fit = fit_from_stats(plan.norm, m_last, f_last, lam, grams)
     return ALSState(factors=factors, lam=lam, grams=grams,
                     sweep=state.sweep + 1, fits=state.fits + [fit])
@@ -297,12 +261,14 @@ def als_traced_sweep(plan: CPPlan, mesh: Mesh, dev_arrays: Sequence,
 @jax.jit
 def fit_from_stats(norm_x: float, m_last, f_last, lam, grams) -> jax.Array:
     """fit = 1 - ||X - X̂||_F / ||X||_F via the norm identity (one small
-    jitted program per sweep, not a dozen eager dispatches)."""
-    inner = jnp.sum(jnp.sum(m_last * f_last, axis=0) * lam)
-    gall = functools.reduce(lambda a, b: a * b, grams)
-    model_sq = matmul(lam, matmul(gall, lam))
-    resid_sq = jnp.maximum(norm_x ** 2 - 2.0 * inner + model_sq, 0.0)
-    return 1.0 - jnp.sqrt(resid_sq) / norm_x
+    jitted program per sweep, not a dozen eager dispatches), under the
+    ``als_solve`` device scope."""
+    with obs_profiler.device_scope("als_solve"):
+        inner = jnp.sum(jnp.sum(m_last * f_last, axis=0) * lam)
+        gall = functools.reduce(lambda a, b: a * b, grams)
+        model_sq = matmul(lam, matmul(gall, lam))
+        resid_sq = jnp.maximum(norm_x ** 2 - 2.0 * inner + model_sq, 0.0)
+        return 1.0 - jnp.sqrt(resid_sq) / norm_x
 
 
 def als_sweep(plan: CPPlan, mesh: Mesh, dev_arrays: Sequence, state: ALSState,
@@ -316,16 +282,20 @@ def als_sweep(plan: CPPlan, mesh: Mesh, dev_arrays: Sequence, state: ALSState,
     Fully async: the sweep only enqueues device work; the fit is appended as
     a device scalar and forces a host sync only when read (off CPU the
     updated factor overwrites the donated old buffer, so do not read factors
-    of a pre-sweep ALSState afterwards)."""
+    of a pre-sweep ALSState afterwards). Each mode's dispatch is a
+    ``mode_update`` span (attribute ``mode``); the span waits for nothing,
+    so with the tracer on the sweep runs exactly as with it off."""
     n = plan.nmodes
     if updates is None:
         updates = [make_mode_update(plan, d, mesh, **mttkrp_kw) for d in range(n)]
+    tracer = obs_trace.get_tracer()
     factors, grams = list(state.factors), list(state.grams)
     m_last = f_last = lam = None
     for d in range(n):
-        others = [factors[w] for w in range(n) if w != d]
-        f_d, g_d, m_d, lam = updates[d](factors[d], dev_arrays[d], others,
-                                        grams)
+        with tracer.span("mode_update", mode=d):
+            others = [factors[w] for w in range(n) if w != d]
+            f_d, g_d, m_d, lam = updates[d](factors[d], dev_arrays[d],
+                                            others, grams)
         factors[d], grams[d] = f_d, g_d
         m_last, f_last = m_d, f_d
     fit = fit_from_stats(plan.norm, m_last, f_last, lam, grams)

@@ -43,6 +43,7 @@ from typing import Literal, Sequence
 import numpy as np
 
 from repro.core.coo import SparseTensor
+from repro.obs import trace as obs_trace
 from repro.schedule import static as static_policies
 from repro.schedule.static import auto_replication  # noqa: F401  (re-export)
 
@@ -420,87 +421,107 @@ def partition_mode(
     owner, g2p, p2g, rows_owned = (lay.owner, lay.global_to_padded,
                                    lay.padded_to_global, lay.rows_owned)
 
-    # --- per-nonzero placement -------------------------------------------
-    out_idx = t.indices[:, mode]
-    nz_group = owner[out_idx] if owner.size else np.zeros(t.nnz, np.int32)
-    nz_padded_row = g2p[out_idx] if owner.size else np.zeros(t.nnz, np.int64)
-    # sort nonzeros by (group, padded row) → contiguous group runs, row-sorted
-    order = np.lexsort((nz_padded_row, nz_group))
-    nz_group, nz_padded_row = nz_group[order], nz_padded_row[order]
-    ind_sorted, val_sorted = t.indices[order], t.values[order]
+    # Host phases, each a span: sort the nonzeros by owner, block them
+    # per device, translate the input-mode indices.
+    span_kw = dict(mode=mode, nnz=int(t.nnz))
+    with obs_trace.span("plan.sort", **span_kw):
+        # --- per-nonzero placement ---------------------------------------
+        out_idx = t.indices[:, mode]
+        nz_group = owner[out_idx] if owner.size \
+            else np.zeros(t.nnz, np.int32)
+        nz_padded_row = g2p[out_idx] if owner.size \
+            else np.zeros(t.nnz, np.int64)
+        # sort nonzeros by (group, padded row) → contiguous group runs,
+        # row-sorted
+        order = np.lexsort((nz_padded_row, nz_group))
+        nz_group, nz_padded_row = nz_group[order], nz_padded_row[order]
+        ind_sorted, val_sorted = t.indices[order], t.values[order]
 
-    group_counts = np.bincount(nz_group, minlength=n_groups)
-    group_start = np.zeros(n_groups, np.int64)
-    group_start[1:] = np.cumsum(group_counts)[:-1]
+    with obs_trace.span("plan.block", **span_kw):
+        group_counts = np.bincount(nz_group, minlength=n_groups)
+        group_start = np.zeros(n_groups, np.int64)
+        group_start[1:] = np.cumsum(group_counts)[:-1]
 
-    # split each group's run into r near-equal contiguous chunks (row-sorted)
-    dev_lists_idx: list[np.ndarray] = []
-    for g in range(n_groups):
-        s, c = int(group_start[g]), int(group_counts[g])
-        bounds = np.linspace(0, c, r + 1).astype(np.int64)
-        for sub in range(r):
-            dev_lists_idx.append(np.arange(s + bounds[sub], s + bounds[sub + 1]))
+        # split each group's run into r near-equal contiguous chunks
+        # (row-sorted)
+        dev_lists_idx: list[np.ndarray] = []
+        for g in range(n_groups):
+            s, c = int(group_start[g]), int(group_counts[g])
+            bounds = np.linspace(0, c, r + 1).astype(np.int64)
+            for sub in range(r):
+                dev_lists_idx.append(
+                    np.arange(s + bounds[sub], s + bounds[sub + 1]))
 
-    nnz_true = np.array([len(x) for x in dev_lists_idx], np.int64)
+        nnz_true = np.array([len(x) for x in dev_lists_idx], np.int64)
 
-    # --- kernel blocking: per device, pad each row-tile's nnz to a multiple
-    # of block_p so no block straddles a tile; then pad devices to the global
-    # max block count.
-    n_tiles = rows_max // tile
-    nmodes = t.nmodes
-    dev_rows, dev_vals, dev_inds, dev_b2t = [], [], [], []
-    for dev, sel in enumerate(dev_lists_idx):
-        g = dev // r
-        lrow = (nz_padded_row[sel] - g * rows_max).astype(np.int64)
-        rows_b, vals_b, inds_b, b2t_b = block_device_rows(
-            lrow, val_sorted[sel], ind_sorted[sel],
-            n_tiles=n_tiles, tile=tile, block_p=block_p, layout=layout)
-        dev_rows.append(rows_b)
-        dev_vals.append(vals_b)
-        dev_inds.append(inds_b)
-        dev_b2t.append(b2t_b)
+        # --- kernel blocking: per device, pad each row-tile's nnz to a
+        # multiple of block_p so no block straddles a tile; then pad devices
+        # to the global max block count.
+        n_tiles = rows_max // tile
+        nmodes = t.nmodes
+        dev_rows, dev_vals, dev_inds, dev_b2t = [], [], [], []
+        for dev, sel in enumerate(dev_lists_idx):
+            g = dev // r
+            lrow = (nz_padded_row[sel] - g * rows_max).astype(np.int64)
+            rows_b, vals_b, inds_b, b2t_b = block_device_rows(
+                lrow, val_sorted[sel], ind_sorted[sel],
+                n_tiles=n_tiles, tile=tile, block_p=block_p, layout=layout)
+            dev_rows.append(rows_b)
+            dev_vals.append(vals_b)
+            dev_inds.append(inds_b)
+            dev_b2t.append(b2t_b)
 
-    nnz_cap = max(max((x.size for x in dev_rows), default=0), block_p)
-    nnz_cap = -(-nnz_cap // block_p) * block_p
-    nblocks = nnz_cap // block_p
-    rows_arr = np.zeros((m, nnz_cap), np.int64)
-    vals_arr = np.zeros((m, nnz_cap), np.float32)
-    inds_arr = np.zeros((m, nnz_cap, nmodes), np.int64)
-    b2t_arr = np.zeros((m, nblocks), np.int64)
-    visited = np.zeros((m, n_tiles), np.float32)
-    for dev in range(m):
-        k = dev_rows[dev].size
-        rows_arr[dev, :k] = dev_rows[dev]
-        vals_arr[dev, :k] = dev_vals[dev]
-        inds_arr[dev, :k] = dev_inds[dev]
-        kb = dev_b2t[dev].size
-        b2t_arr[dev, :kb] = dev_b2t[dev]
-        # trailing pad blocks revisit the last used tile (no extra switches)
-        b2t_arr[dev, kb:] = dev_b2t[dev][-1] if kb else 0
-        # pad rows must be in the pad blocks' tile; the sorted layout keeps
-        # them at the device's last real row so local_rows stays monotone
-        if layout == "sorted":
-            rows_arr[dev, k:] = dev_rows[dev][-1] if k else 0
-        else:
-            pad_tile = int(b2t_arr[dev, -1])
-            rows_arr[dev, k:] = pad_tile * tile
-        visited[dev, b2t_arr[dev]] = 1.0
-
-    # translate input-mode indices into padded layouts
-    if all_g2p is not None:
-        for w in range(nmodes):
-            if w == mode:
-                inds_arr[:, :, w] = np.where(
-                    vals_arr != 0, g2p[np.minimum(inds_arr[:, :, w], max(hist.size - 1, 0))], 0
-                ) if hist.size else 0
+        nnz_cap = max(max((x.size for x in dev_rows), default=0), block_p)
+        nnz_cap = -(-nnz_cap // block_p) * block_p
+        nblocks = nnz_cap // block_p
+        rows_arr = np.zeros((m, nnz_cap), np.int64)
+        vals_arr = np.zeros((m, nnz_cap), np.float32)
+        inds_arr = np.zeros((m, nnz_cap, nmodes), np.int64)
+        b2t_arr = np.zeros((m, nblocks), np.int64)
+        visited = np.zeros((m, n_tiles), np.float32)
+        for dev in range(m):
+            k = dev_rows[dev].size
+            rows_arr[dev, :k] = dev_rows[dev]
+            vals_arr[dev, :k] = dev_vals[dev]
+            inds_arr[dev, :k] = dev_inds[dev]
+            kb = dev_b2t[dev].size
+            b2t_arr[dev, :kb] = dev_b2t[dev]
+            # trailing pad blocks revisit the last used tile (no extra
+            # switches)
+            b2t_arr[dev, kb:] = dev_b2t[dev][-1] if kb else 0
+            # pad rows must be in the pad blocks' tile; the sorted layout
+            # keeps them at the device's last real row so local_rows stays
+            # monotone
+            if layout == "sorted":
+                rows_arr[dev, k:] = dev_rows[dev][-1] if k else 0
             else:
-                t_g2p = all_g2p[w]
-                if t_g2p is not None and t_g2p.size:
+                pad_tile = int(b2t_arr[dev, -1])
+                rows_arr[dev, k:] = pad_tile * tile
+            visited[dev, b2t_arr[dev]] = 1.0
+
+    with obs_trace.span("plan.translate", **span_kw):
+        # translate input-mode indices into padded layouts
+        if all_g2p is not None:
+            for w in range(nmodes):
+                if w == mode:
                     inds_arr[:, :, w] = np.where(
                         vals_arr != 0,
-                        t_g2p[np.minimum(inds_arr[:, :, w], t_g2p.size - 1)],
+                        g2p[np.minimum(inds_arr[:, :, w],
+                                       max(hist.size - 1, 0))],
                         0,
-                    )
+                    ) if hist.size else 0
+                else:
+                    t_g2p = all_g2p[w]
+                    if t_g2p is not None and t_g2p.size:
+                        inds_arr[:, :, w] = np.where(
+                            vals_arr != 0,
+                            t_g2p[np.minimum(inds_arr[:, :, w],
+                                             t_g2p.size - 1)],
+                            0,
+                        )
+        indices = inds_arr.astype(np.int32)
+        local_rows = rows_arr.astype(np.int32)
+        block_to_tile = b2t_arr.astype(np.int32)
 
     part = ModePartition(
         mode=mode,
@@ -510,10 +531,10 @@ def partition_mode(
         rows_max=rows_max,
         tile=tile,
         block_p=block_p,
-        indices=inds_arr.astype(np.int32),
+        indices=indices,
         values=vals_arr,
-        local_rows=rows_arr.astype(np.int32),
-        block_to_tile=b2t_arr.astype(np.int32),
+        local_rows=local_rows,
+        block_to_tile=block_to_tile,
         tile_visited=visited,
         nnz_true=nnz_true,
         rows_owned=rows_owned,

@@ -30,8 +30,10 @@ against bytes measured from the compiled HLO's collectives, e.g.::
         --exchange-report
 
 --trace-out enables the repro.obs span tracer for the whole invocation
-(plan → compile → execute, nested down to per-mode EC/exchange/H2D spans)
-and writes a Chrome-trace JSON loadable in chrome://tracing or Perfetto;
+(plan and its sort/block/translate phases → compile → execute, nested
+down to per-mode updates, and per-window EC/exchange/H2D spans when
+streamed) and writes a Chrome-trace JSON loadable in chrome://tracing or
+Perfetto; a traced run runs the same programs as an untraced one;
 --events-out mirrors every structured event (sweeps, rebalance points,
 per-window transfer timings) as greppable JSON lines, live.
 """
@@ -99,7 +101,8 @@ def main():
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="enable span tracing and write a Chrome-trace "
                          "JSON (chrome://tracing / ui.perfetto.dev) "
-                         "covering plan/compile/execute")
+                         "covering plan/compile/execute; the traced run "
+                         "runs the same programs as an untraced one")
     ap.add_argument("--events-out", default=None, metavar="PATH",
                     help="mirror structured events (sweeps, rebalance "
                          "points, H2D windows) as JSON lines, flushed "

@@ -7,8 +7,8 @@ span stack, so spans opened on the streamer's prefetch thread nest under
 that thread's own roots instead of corrupting the main thread's tree.
 
     from repro.obs import trace
-    with trace.span("mode", mode=d):
-        with trace.span("ec", mode=d, annotate=True):
+    with trace.span("sweep", sweep=k):
+        with trace.span("mode_update", mode=d):
             ...
 
 Disabled (the default) a ``span()`` call returns a shared no-op context
@@ -16,9 +16,12 @@ manager — one attribute check, no allocation beyond the kwargs dict — so
 instrumented hot paths cost nothing measurable (the bench records the
 per-call price; see BENCH_mttkrp.json ``obs.disabled_span``). Enabled, each
 span records ``{id, parent, name, tid, t0, t1, attrs}`` on the shared
-:func:`repro.obs.clock.now` clock; ``annotate=True`` additionally enters a
-``jax.profiler.TraceAnnotation`` so device profiles line up with host
-spans (see :mod:`repro.obs.profiler`).
+:func:`repro.obs.clock.now` clock and enters a
+``jax.profiler.TraceAnnotation`` of its name, so a profiler trace taken
+meanwhile holds every span on the device trace's clock (see
+:mod:`repro.obs.profiler`). Spans only observe: they dispatch nothing and
+wait for nothing, so a traced run runs the same programs as an untraced
+one.
 
 Export to Chrome-trace/Perfetto JSON lives in :mod:`repro.obs.export`
 (``CPSolver.dump_trace`` / ``launch.decompose --trace-out``).
@@ -29,7 +32,7 @@ import itertools
 import threading
 from typing import Optional
 
-from repro.obs import clock
+from repro.obs import clock, profiler
 
 __all__ = ["Tracer", "get_tracer", "span", "timed", "enable", "disable",
            "reset"]
@@ -54,32 +57,26 @@ class _Span:
     __slots__ = ("_tracer", "name", "attrs", "id", "parent", "t0", "t1",
                  "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, annotate: bool,
-                 attrs: dict):
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self.id = self.parent = None
         self.t0 = self.t1 = None
-        self._annotation = None
-        if annotate:
-            from repro.obs import profiler
-            self._annotation = profiler.annotation(name)
+        self._annotation = profiler.annotation(name)
 
     def __enter__(self):
         stack = self._tracer._stack()
         self.parent = stack[-1].id if stack else None
         self.id = next(self._tracer._ids)
         stack.append(self)
-        if self._annotation is not None:
-            self._annotation.__enter__()
+        self._annotation.__enter__()
         self.t0 = clock.now()
         return self
 
     def __exit__(self, *exc):
         self.t1 = clock.now()
-        if self._annotation is not None:
-            self._annotation.__exit__(*exc)
+        self._annotation.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -136,15 +133,16 @@ class Tracer:
     def enabled(self) -> bool:
         return self._enabled
 
-    def span(self, name: str, *, annotate: bool = False, **attrs):
-        """Context manager for one span. A shared no-op while disabled."""
+    def span(self, name: str, **attrs):
+        """Context manager for one span, also a profiler annotation of its
+        name. A shared no-op while disabled."""
         if not self._enabled:
             return _NULL_SPAN
-        return _Span(self, name, annotate, attrs)
+        return _Span(self, name, attrs)
 
-    def timed(self, name: str, *, annotate: bool = False, **attrs) -> _Timed:
+    def timed(self, name: str, **attrs) -> _Timed:
         """A span that always measures ``.duration`` (even disabled)."""
-        return _Timed(self.span(name, annotate=annotate, **attrs))
+        return _Timed(self.span(name, **attrs))
 
     def _stack(self) -> list:
         stack = getattr(self._tls, "stack", None)
@@ -191,14 +189,14 @@ def get_tracer() -> Tracer:
     return _TRACER
 
 
-def span(name: str, *, annotate: bool = False, **attrs):
+def span(name: str, **attrs):
     """``with trace.span("mode_update", mode=k): ...`` on the global
     tracer."""
-    return _TRACER.span(name, annotate=annotate, **attrs)
+    return _TRACER.span(name, **attrs)
 
 
-def timed(name: str, *, annotate: bool = False, **attrs) -> _Timed:
-    return _TRACER.timed(name, annotate=annotate, **attrs)
+def timed(name: str, **attrs) -> _Timed:
+    return _TRACER.timed(name, **attrs)
 
 
 def enable() -> None:

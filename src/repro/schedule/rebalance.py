@@ -147,8 +147,7 @@ def measure_mode_device_times(part, factors: Sequence[jax.Array],
             cache[key] = fn
         fn(idx, vals, rows, b2t, factors, tile_mask=mask).block_until_ready()
         best = float("inf")
-        with obs_trace.span("rebalance_probe", mode=part.mode, device=dev,
-                            annotate=True):
+        with obs_trace.span("rebalance_probe", mode=part.mode, device=dev):
             for _ in range(max(1, repeats)):
                 t0 = clock.now()
                 fn(idx, vals, rows, b2t, factors,

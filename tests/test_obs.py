@@ -7,11 +7,16 @@ B/E, non-overlapping siblings, coverage), the metrics registry
 (counters/gauges/histograms/providers), the LogHistogram torn-snapshot
 concurrency regression, the structured event log and its JSON-lines sink,
 StreamMonitor window attribution, overlap_report steady-state fractions
-under injected slow/fast transfers, and a traced CPSolver run whose span
-tree nests sweep -> mode_update -> {ec, exchange} at >= 95% coverage with
-fits bitwise identical to the untraced path.
+under injected slow/fast transfers, a traced CPSolver run whose span
+tree nests run -> sweep -> mode_update at >= 95% coverage, running the
+untraced run's programs with bitwise-identical fits, enabled spans as
+jax.profiler host events, and the plan's sort/block/translate spans.
 """
+import collections
+import dataclasses
+import glob
 import json
+import os
 import threading
 import time
 
@@ -70,6 +75,47 @@ def test_disabled_span_is_shared_noop():
     with s1:
         pass
     assert tracer.records() == []
+
+
+def _profiler_host_events(log_dir):
+    import jax
+    files = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1, files
+    pd = jax.profiler.ProfileData.from_file(files[0])
+    return [(ev.name, ev.start_ns, ev.duration_ns)
+            for plane in pd.planes if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_enabled_span_is_a_profiler_host_event(tmp_path):
+    """An enabled span enters a jax.profiler annotation of its name, so a
+    profiler trace holds it on the device trace's clock; a disabled span
+    is the shared no-op and leaves nothing in the trace."""
+    import jax
+    import jax.numpy as jnp
+    off = obs_trace.span("obs_probe_off")
+    assert off is obs_trace.span("obs_probe_other")
+    on_dir, off_dir = str(tmp_path / "on"), str(tmp_path / "off")
+    with jax.profiler.trace(off_dir):
+        with off:
+            jax.block_until_ready(jnp.arange(4) + 1)
+    obs_trace.enable()
+    with jax.profiler.trace(on_dir):
+        with obs_trace.span("obs_probe_outer", sweep=1):
+            with obs_trace.span("obs_probe_inner", mode=2) as inner:
+                assert inner is not off
+                jax.block_until_ready(jnp.arange(4) + 1)
+    events = {name: (t0, dur) for name, t0, dur in
+              _profiler_host_events(on_dir)}
+    assert {"obs_probe_outer", "obs_probe_inner"} <= set(events)
+    (o0, odur), (i0, idur) = (events["obs_probe_outer"],
+                              events["obs_probe_inner"])
+    assert o0 <= i0 and i0 + idur <= o0 + odur
+    assert not [e for e in _profiler_host_events(off_dir)
+                if e[0].startswith("obs_probe")]
+    assert [r["name"] for r in obs_trace.get_tracer().records()] == [
+        "obs_probe_inner", "obs_probe_outer"]
 
 
 def test_timed_measures_even_when_disabled():
@@ -445,29 +491,63 @@ def _solver_cfg(trace):
         num_devices=1, tol=0.0, seed=0, trace=trace))
 
 
+def _compiled_programs(fn):
+    """Run ``fn``; return the names of the programs XLA compiled meanwhile
+    (jax.monitoring's backend-compile events), with their counts."""
+    import jax
+    names = collections.Counter()
+
+    def listener(event, _secs, **kw):
+        if event.endswith("backend_compile_duration"):
+            names[kw.get("fun_name")] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        out = fn()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    return out, names
+
+
 def test_traced_run_nests_and_matches_untraced(small_tensor, tmp_path):
     """Acceptance: a traced run's Chrome trace nests run -> sweep ->
-    mode_update -> {ec, exchange} at >= 95% top-level coverage, and its
-    fit trajectory is bitwise identical to the untraced path."""
-    cfg = _solver_cfg(False)
-    with api.compile(api.plan(small_tensor, cfg), cfg) as s:
-        r_plain = s.run(2)
+    mode_update at >= 95% top-level coverage; tracing changes nothing that
+    runs: the fit trajectory and factors are bitwise identical to the
+    untraced run's, and the traced run compiles no program the untraced
+    run did not."""
+    def solve(trace):
+        cfg = _solver_cfg(trace)
+        with api.compile(api.plan(small_tensor, cfg), cfg) as s:
+            assert obs_trace.get_tracer().enabled == trace
+            result = s.run(2)
+            if not trace:
+                return result, None
+            path = str(tmp_path / "trace.json")
+            trace_doc = s.dump_trace(path)
+            assert json.load(open(path)) == trace_doc
+            rep = s.report()
+            assert s._obs_name in obs.report()["sections"]
+        # close() deregistered the solver's section from the global report
+        assert s._obs_name not in obs.report()["sections"]
+        # the solver report is the registry view over the existing
+        # reporters, value-identical to calling them directly
+        # (measure=False: a report snapshot must never force an HLO
+        # re-lower)
+        assert rep["sections"]["overlap"] == {"enabled": False}
+        assert rep["sections"]["exchange"] == s.exchange_report(
+            measure=False)
+        assert "measured" not in rep["sections"]["exchange"]
+        assert rep["sections"]["imbalance"] == s.imbalance_report()
+        return result, trace_doc
 
-    cfg = _solver_cfg(True)
-    with api.compile(api.plan(small_tensor, cfg), cfg) as s:
-        assert obs_trace.get_tracer().enabled
-        r_traced = s.run(2)
-        path = str(tmp_path / "trace.json")
-        trace = s.dump_trace(path)
-        rep = s.report()
-        glob = obs.report()
-        assert s._obs_name in glob["sections"]
-    # close() deregistered the solver's section from the global report
-    assert s._obs_name not in obs.report()["sections"]
+    (r_plain, _), plain_progs = _compiled_programs(lambda: solve(False))
+    (r_traced, trace), traced_progs = _compiled_programs(lambda: solve(True))
 
     assert r_traced.fits == r_plain.fits
     for a, b in zip(r_plain.factors, r_traced.factors):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert plain_progs["jit(update)"] == len(small_tensor.shape)
+    assert not traced_progs - plain_progs, (traced_progs, plain_progs)
 
     res = validate_trace(trace, min_coverage=0.95)
     assert res["ok"], res["problems"]
@@ -476,27 +556,48 @@ def test_traced_run_nests_and_matches_untraced(small_tensor, tmp_path):
     assert res["span_counts"]["run"] == 1
     assert res["span_counts"]["sweep"] == 2
     assert res["span_counts"]["mode_update"] == 2 * nmodes
-    assert res["span_counts"]["ec"] == 2 * nmodes
-    assert res["span_counts"]["exchange"] == 2 * nmodes
-    assert json.load(open(path)) == trace
 
-    # parent links: ec/exchange under mode_update, mode_update under sweep,
-    # sweep under run
     by_id = {r["id"]: r for r in obs_trace.get_tracer().records()}
-    parent_names = {"ec": "mode_update", "exchange": "mode_update",
-                    "mode_update": "sweep", "sweep": "run"}
+    parent_names = {"mode_update": "sweep", "sweep": "run"}
     for r in by_id.values():
         want = parent_names.get(r["name"])
         if want is not None:
             assert by_id[r["parent"]]["name"] == want, r
+    modes = [r["attrs"]["mode"] for r in by_id.values()
+             if r["name"] == "mode_update"]
+    assert sorted(modes) == sorted(list(range(nmodes)) * 2)
 
-    # the solver report is the registry view over the existing reporters,
-    # value-identical to calling them directly (measure=False: a report
-    # snapshot must never force an HLO re-lower)
-    assert rep["sections"]["overlap"] == {"enabled": False}
-    assert rep["sections"]["exchange"] == s.exchange_report(measure=False)
-    assert "measured" not in rep["sections"]["exchange"]
-    assert rep["sections"]["imbalance"] == s.imbalance_report()
+
+def test_plan_spans_per_mode_and_identical_plan(small_tensor):
+    """The plan's host phases are spans (plan.sort, plan.block,
+    plan.translate) once per mode under ``plan``, with the mode and the
+    nonzero count; the plan built with the tracer on is byte-identical to
+    the one built with it off."""
+    cfg = _solver_cfg(False)
+    plain = api.plan(small_tensor, cfg)
+    obs_trace.enable()
+    traced = api.plan(small_tensor, cfg)
+    recs = obs_trace.get_tracer().records()
+    by_id = {r["id"]: r for r in recs}
+    nmodes = len(small_tensor.shape)
+    for phase in ("plan.sort", "plan.block", "plan.translate"):
+        spans = [r for r in recs if r["name"] == phase]
+        assert sorted(r["attrs"]["mode"] for r in spans) == \
+            list(range(nmodes))
+        assert {r["attrs"]["nnz"] for r in spans} == {small_tensor.nnz}
+        assert {by_id[r["parent"]]["name"] for r in spans} == {"plan"}
+    assert obs_trace.get_tracer().summary()["plan"]["count"] == 1
+    for a, b in zip(plain.modes, traced.modes):
+        for f in dataclasses.fields(a):
+            x, y = getattr(a, f.name), getattr(b, f.name)
+            if isinstance(x, np.ndarray):
+                assert x.dtype == y.dtype and x.tobytes() == y.tobytes(), \
+                    f.name
+            else:
+                assert x == y, f.name
+    for a, b in zip(plain.global_to_padded + plain.padded_to_global,
+                    traced.global_to_padded + traced.padded_to_global):
+        assert a.tobytes() == b.tobytes()
 
 
 def test_solver_events_and_dumps(small_tensor, tmp_path):
