@@ -25,10 +25,11 @@ tensor and geometry); the report carries, per variant:
   * an HLO check: ``gather_free`` is True iff the lowered computation
     contains no XLA gather op (no materialized intermediate exists).
 
-Each point also times the ``ref`` XLA path on the sorted shard with and
-without the ``segment_sum(indices_are_sorted=True)`` hint — bit-identical
-by construction (asserted), and real XLA CPU wall time, so hint parity or
-better is the one wall-clock claim this container can honestly make
+Each point also times the slot-order oracle (``kernels/ref.py``) on the
+sorted shard with and without the ``segment_sum(indices_are_sorted=True)``
+hint — bit-identical by construction (asserted), and real XLA CPU wall
+time, so hint parity or better is the one wall-clock claim this container
+can honestly make
 (``ref_sorted_hint.parity``); the Pallas variants run in interpret mode
 off-TPU, where absolute times are meaningless.
 
@@ -742,6 +743,7 @@ def bench_point(nmodes: int, rank: int, nnz: int, *, repeats: int = 3,
     from repro.core.partition import block_segment_descriptors
     from repro.kernels import ops as kops
     from repro.kernels.autotune import representative_shard
+    from repro.kernels.ref import mttkrp_local_ref
 
     t, part = representative_shard(nmodes, nnz, seed=seed)
     # same tensor, same blocking geometry, row-sorted pad placement
@@ -761,8 +763,7 @@ def bench_point(nmodes: int, rank: int, nnz: int, *, repeats: int = 3,
     mask = jnp.asarray(part.tile_visited[0])
     ss, sr = block_segment_descriptors(part_s.local_rows[0], tile=part.tile,
                                        block_p=part.block_p)
-    seg_kw = dict(seg_starts=jnp.asarray(ss), seg_rows=jnp.asarray(sr),
-                  rows_sorted=True)
+    seg_kw = dict(seg_starts=jnp.asarray(ss), seg_rows=jnp.asarray(sr))
     nin = nmodes - 1
     nnz_pad = part.nnz_max  # post-padding nonzeros actually streamed
     flops = _flops(nnz_pad, rank, nin)
@@ -803,15 +804,14 @@ def bench_point(nmodes: int, rank: int, nnz: int, *, repeats: int = 3,
             "gather_free": _gather_free(run, vargs),
         }
 
-    # ref on the sorted shard, with vs without the segment_sum hint: real
-    # XLA CPU wall time (no interpret mode), bit-identical by construction
+    # the slot-order oracle on the sorted shard, with vs without the
+    # segment_sum hint: real XLA CPU wall time (no interpret mode),
+    # bit-identical by construction
     def run_ref(indices, values, local_rows, block_to_tile, facs, *,
                 hint):
-        return kops.mttkrp_local(
-            indices, values, local_rows, block_to_tile, facs,
-            mode=0, num_rows=part.rows_max, tile=part.tile,
-            block_p=part.block_p, tile_mask=mask, use_kernel=False,
-            variant="ref", rows_sorted=hint)
+        del block_to_tile
+        return mttkrp_local_ref(indices, values, local_rows, facs, 0,
+                                part.rows_max, sorted_rows=hint)
 
     j_plain = jax.jit(lambda *a: run_ref(*a, hint=False))
     j_hint = jax.jit(lambda *a: run_ref(*a, hint=True))

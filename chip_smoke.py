@@ -201,7 +201,7 @@ def _ec_checks(jax, solver) -> dict:
                 variant=variant, num_buffers=NUM_BUFFERS,
                 tile_mask=dev.tile_visited[0, 0] if nnz is None else None,
                 seg_starts=cut(dev.seg_starts, True),
-                seg_rows=cut(dev.seg_rows, True), rows_sorted=True)
+                seg_rows=cut(dev.seg_rows, True))
         compiled = jax.jit(fn).lower(dev, factors).compile()
         if variant != "ref":
             check("tpu_custom_call" in compiled.as_text(),

@@ -97,8 +97,7 @@ def ec_lowered_text(variant: str, *, nmodes: int, rank: int,
         ss, sr = block_segment_descriptors(part.local_rows[0],
                                            tile=part.tile,
                                            block_p=part.block_p)
-        seg_kw = dict(seg_starts=jnp.asarray(ss), seg_rows=jnp.asarray(sr),
-                      rows_sorted=True)
+        seg_kw = dict(seg_starts=jnp.asarray(ss), seg_rows=jnp.asarray(sr))
 
     def run(indices, values, local_rows, block_to_tile, facs):
         return ops.mttkrp_local(

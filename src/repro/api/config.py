@@ -129,7 +129,7 @@ class ScheduleConfig:
 class KernelConfig:
     """EC kernel selection and launch parameters (see repro.kernels.ops)."""
 
-    use_kernel: bool = False        # False + variant=None → "ref" (jnp oracle)
+    use_kernel: bool = False        # False + variant=None → "ref" (pure XLA)
     variant: str | None = None      # "ref"|"blocked"|"fused"|"sorted"|None=env
     num_buffers: int | None = None  # fused DMA ring depth (None = 2/autotuned)
     autotune: bool = False          # sweep (tile, block_p, num_buffers)

@@ -86,7 +86,7 @@ def _resolve_geometry(tensor_nmodes: int, config: DecomposeConfig
     tile, block_p = config.partition.tile, config.partition.block_p
     if config.kernel.autotune:
         variant = config.kernel.resolved_variant()
-        if variant != "ref":  # ref ignores the blocking geometry
+        if variant != "ref":  # the grid times the Pallas variants only
             from repro.kernels.autotune import autotune_ec
             tuned = autotune_ec(tensor_nmodes, config.rank, variant=variant)
             if tile is None:

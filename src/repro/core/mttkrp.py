@@ -1,7 +1,7 @@
 """Distributed MTTKRP (paper Algorithms 1–2) via shard_map.
 
 Per output mode ``d``:
-  1. every device runs the EC on its shard (Pallas kernel or jnp segments) —
+  1. every device runs the EC on its shard (Pallas kernel or the XLA ref) —
      no cross-device write conflicts by the partitioning invariant,
   2. replication groups (r>1) merge partials with an intra-group
      reduce-scatter (``psum_scatter`` or the explicit ``ring_rs`` schedule;
@@ -164,8 +164,7 @@ def _local_ec(part_meta: dict, indices, values, local_rows, block_to_tile,
         tile=part_meta["tile"], block_p=part_meta["block_p"],
         use_kernel=use_kernel, variant=variant, num_buffers=num_buffers,
         interpret=interpret, tile_mask=tile_visited,
-        seg_starts=seg_starts, seg_rows=seg_rows,
-        rows_sorted=part_meta.get("rows_sorted", False))
+        seg_starts=seg_starts, seg_rows=seg_rows)
 
 
 def make_mttkrp_fn(
@@ -195,9 +194,7 @@ def make_mttkrp_fn(
     variant, honoured only when no spec is given.
     """
     meta = dict(mode=part.mode, rows_max=part.rows_max, tile=part.tile,
-                block_p=part.block_p,
-                rows_sorted=getattr(part, "block_layout",
-                                    "blocked") == "sorted")
+                block_p=part.block_p)
     all_axes = tuple(group_axes) + (sub_axis,)
     if exchange_spec is None:
         exchange_spec = comm.ExchangeSpec(
@@ -352,9 +349,7 @@ def make_partial_mttkrp_fn(
     the resident path's.
     """
     meta = dict(mode=part.mode, rows_max=part.rows_max, tile=part.tile,
-                block_p=part.block_p,
-                rows_sorted=getattr(part, "block_layout",
-                                    "blocked") == "sorted")
+                block_p=part.block_p)
 
     def local_fn(acc, indices, values, local_rows, block_to_tile,
                  tile_visited, seg_starts, seg_rows, *factors):

@@ -72,7 +72,8 @@ Strategy = Literal["amped_cdf", "amped_lpt", "uniform_index", "equal_nnz"]
 #               local_rows is globally nondecreasing per device and every
 #               block holds at most `tile + 1` row segments. This is what
 #               lets ec_sorted replace the one-hot scatter with a segmented
-#               reduction, and lets ref pass indices_are_sorted=True.
+#               reduction, and lets the slot-order oracle
+#               (kernels/ref.py) pass indices_are_sorted=True.
 # Pad values are 0 either way, so pads stay exact no-ops for every variant.
 Layout = Literal["blocked", "sorted"]
 DEFAULT_LAYOUT = "blocked"

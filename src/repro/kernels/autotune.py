@@ -206,8 +206,7 @@ def _time_candidate(t, part, rank: int, variant: str, num_buffers: int,
         ss, sr = block_segment_descriptors(part.local_rows[0],
                                            tile=part.tile,
                                            block_p=part.block_p)
-        seg_kw = dict(seg_starts=jnp.asarray(ss), seg_rows=jnp.asarray(sr),
-                      rows_sorted=True)
+        seg_kw = dict(seg_starts=jnp.asarray(ss), seg_rows=jnp.asarray(sr))
 
     @jax.jit
     def run(indices, values, local_rows, block_to_tile, facs):

@@ -26,8 +26,9 @@ copy (arXiv 2405.08470):
     shipped to the kernel at all.
 
 Accumulation order is *slot order*, exactly the order XLA's scatter-add
-(`segment_sum`) uses, and the elementwise product is formed in ``ref``'s
-order, so the result is bit-identical to ``ref`` — on both layouts (on the
+(`segment_sum`) uses, and the elementwise product is formed in the
+oracle's order, so the result is bit-identical to the slot-order oracle
+(``kernels/ref.py:mttkrp_local_ref``) — on both layouts (on the
 legacy blocked layout a pad run may revisit an earlier row, but pads
 contribute exact ``0.0`` adds in the same slot positions).
 
@@ -131,7 +132,8 @@ def ec_sorted(
 ) -> jax.Array:
     """Segmented-reduction EC on the row-sorted block layout.
 
-    Returns (num_rows, R) f32, bit-identical to the ``ref`` oracle.
+    Returns (num_rows, R) f32, bit-identical to the slot-order oracle
+    (``kernels/ref.py``).
     ``input_indices[j]`` indexes ``factors[j]`` (the output mode is
     compacted away by the caller, see ops.py); descriptors come from
     ``core.partition.block_segment_descriptors``.

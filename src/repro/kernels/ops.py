@@ -3,14 +3,17 @@
 ``mttkrp_local`` is the single-device EC used inside shard_map by
 core/mttkrp.py. Four interchangeable variants (see EXPERIMENTS.md §Perf):
 
-  ``ref``      pure-jnp gather + segment_sum (XLA; the semantic oracle)
+  ``ref``      pure XLA, no Pallas: over chunks of blocks, gather +
+               product, each block summed into its tile, then a
+               scatter-add over the chunk's blocks (the slot-order oracle
+               is kernels/ref.py, which no variant runs)
   ``blocked``  XLA pre-gather of (nnz, R) input rows + Pallas one-hot-matmul
                EC kernel (mttkrp_pallas.ec_blocked)
   ``fused``    in-kernel factor gather with double-buffered HBM streaming —
                no gathered intermediate (mttkrp_fused.ec_fused)
   ``sorted``   fused's in-kernel gather + segmented reduction over the
                row-sorted block layout — no one-hot scatter, each output
-               row written once per segment; bit-identical to ``ref``
+               row written once per segment; bit-identical to the oracle
                (mttkrp_sorted.ec_sorted; needs seg_starts/seg_rows
                descriptors, see core.partition.block_segment_descriptors)
 
@@ -27,7 +30,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref as _ref
+from repro.kernels import tpu_layout as tl
 from repro.kernels.mttkrp_fused import ec_fused
 from repro.kernels.mttkrp_pallas import ec_blocked
 from repro.kernels.mttkrp_sorted import ec_sorted
@@ -123,19 +126,84 @@ def _mask_unvisited(out: jax.Array, tile_mask: jax.Array | None,
     return jnp.where(mask, out, 0.0)
 
 
+# The ``ref`` EC works through the shard in chunks of blocks, so the
+# gathered factor rows (lane-padded to 128 floats) never exist for the
+# whole shard at once. Where the input factors fit in VMEM together (128
+# MiB on a v5e, with room left for the chunk's own rows), the chunks run in
+# a loop of REF_LOOP_BLOCKS blocks and the compiler keeps the factors and
+# each chunk's rows there. Otherwise a loop would leave a factor in HBM and
+# gather from it row by row, so the chunks are laid out one after another
+# in the program, each gathering at most REF_CHUNK_BYTES of padded rows,
+# and the compiler stages each factor into VMEM for its gather in turn.
+REF_VMEM_FACTOR_BYTES = 100 << 20
+REF_LOOP_BLOCKS = 128
+REF_CHUNK_BYTES = 512 << 20
+
+
+def _ref_tiles(indices, values, local_rows, factors, mode, tile, block_p):
+    """The slots' products summed within each block into the block's tile:
+    ``(nblocks, tile, R)``."""
+    nblocks = values.shape[0] // block_p
+    e = values.astype(jnp.float32)[:, None]
+    for w in range(len(factors)):
+        if w != mode:
+            e = e * factors[w][indices[:, w]].astype(jnp.float32)
+    rank = e.shape[-1]
+    row_in_tile = (local_rows % tile).reshape(nblocks, block_p, 1)
+    onehot = row_in_tile == jax.lax.broadcasted_iota(
+        local_rows.dtype, (1, 1, tile), 2)
+    return jnp.einsum("bpt,bpr->btr", onehot.astype(jnp.float32),
+                      e.reshape(nblocks, block_p, rank),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 def _run_ref(indices, values, local_rows, block_to_tile, factors, *,
              mode, num_rows, tile, block_p, interpret, tile_mask,
-             num_buffers, seg_starts, seg_rows, rows_sorted):
-    del block_to_tile, tile, block_p, interpret, tile_mask, num_buffers
-    del seg_starts, seg_rows
-    return _ref.mttkrp_local_ref(indices, values, local_rows, factors,
-                                 mode, num_rows, sorted_rows=rows_sorted)
+             num_buffers, seg_starts, seg_rows):
+    """Two-level reduction over the block layout, in XLA.
+
+    The slots' products ``values * prod_{w != mode} F_w[indices[:, w]]``
+    are summed within each ``block_p``-slot block into the block's
+    ``tile``-row tile (no block straddles a tile), and the blocks' tiles
+    are then scatter-added into the output by ``block_to_tile``: one
+    update per block, not per slot. The in-block sum contracts with a
+    one-hot of each slot's row in its tile, built from an iota inside the
+    fusion, at HIGHEST precision: the one-hot is exact in bfloat16 and the
+    float32 products are split into bfloat16 parts that keep every bit.
+    The shard is processed in chunks of blocks (see REF_LOOP_BLOCKS), each
+    scatter-adding into the running output in block order, so the result
+    does not depend on the chunking. Pad slots hold value 0 and add exact
+    zeros; tiles no block visits stay 0. The sums run in another order
+    than the slot-order oracle (kernels/ref.py), so they agree with it to
+    float32 rounding, and bit for bit where every sum is exact."""
+    del interpret, tile_mask, num_buffers, seg_starts, seg_rows
+    rank = factors[0].shape[-1]
+    row_bytes = tl.round_up(rank, tl.LANES) * 4
+    inputs = [w for w in range(len(factors)) if w != mode]
+    if sum(factors[w].shape[0] for w in inputs) * row_bytes \
+            <= REF_VMEM_FACTOR_BYTES:
+        chunk, unroll = REF_LOOP_BLOCKS, False
+    else:
+        chunk = max(1, REF_CHUNK_BYTES // (len(inputs) * block_p * row_bytes))
+        unroll = True
+
+    def launch(n, base, b2t, out):
+        s = base[0] * block_p
+        cut = lambda x: jax.lax.dynamic_slice_in_dim(x, s, n * block_p)
+        tiles = _ref_tiles(cut(indices), cut(values), cut(local_rows),
+                           factors, mode, tile, block_p)
+        return out.at[b2t].add(tiles)
+
+    out = tl.chunked(launch, nblocks=block_to_tile.shape[0],
+                     block_to_tile=block_to_tile, chunk=chunk, unroll=unroll,
+                     out=jnp.zeros((num_rows // tile, tile, rank), jnp.float32))
+    return out.reshape(num_rows, rank)
 
 
 def _run_blocked(indices, values, local_rows, block_to_tile, factors, *,
                  mode, num_rows, tile, block_p, interpret, tile_mask,
-                 num_buffers, seg_starts, seg_rows, rows_sorted):
-    del num_buffers, seg_starts, seg_rows, rows_sorted
+                 num_buffers, seg_starts, seg_rows):
+    del num_buffers, seg_starts, seg_rows
     gathered = [factors[w][indices[:, w]]
                 for w in range(len(factors)) if w != mode]
     row_in_tile = (local_rows % tile).astype(jnp.int32)
@@ -147,8 +215,8 @@ def _run_blocked(indices, values, local_rows, block_to_tile, factors, *,
 
 def _run_fused(indices, values, local_rows, block_to_tile, factors, *,
                mode, num_rows, tile, block_p, interpret, tile_mask,
-               num_buffers, seg_starts, seg_rows, rows_sorted):
-    del seg_starts, seg_rows, rows_sorted
+               num_buffers, seg_starts, seg_rows):
+    del seg_starts, seg_rows
     # Compact the input-mode index columns into one (nin, nnz) array; the
     # factor matrices themselves stay in HBM (no (nnz, R) intermediate).
     in_modes = [w for w in range(len(factors)) if w != mode]
@@ -164,8 +232,8 @@ def _run_fused(indices, values, local_rows, block_to_tile, factors, *,
 
 def _run_sorted(indices, values, local_rows, block_to_tile, factors, *,
                 mode, num_rows, tile, block_p, interpret, tile_mask,
-                num_buffers, seg_starts, seg_rows, rows_sorted):
-    del local_rows, rows_sorted  # descriptors replace the per-slot rows
+                num_buffers, seg_starts, seg_rows):
+    del local_rows  # descriptors replace the per-slot rows
     if seg_starts is None or seg_rows is None:
         raise ValueError(
             "variant='sorted' needs per-block segment descriptors; compute "
@@ -207,7 +275,6 @@ def mttkrp_local(
     tile_mask: jax.Array | None = None,  # (num_rows/tile,) 1=visited
     seg_starts: jax.Array | None = None,  # (nblocks, S+1) int32 ("sorted")
     seg_rows: jax.Array | None = None,    # (nblocks, S) int32 ("sorted")
-    rows_sorted: bool = False,            # local_rows nondecreasing (ref hint)
 ) -> jax.Array:
     """Local (per-device) EC over this device's shard. Returns (num_rows, R) f32."""
     variant = resolve_variant(variant, use_kernel)
@@ -217,4 +284,4 @@ def mttkrp_local(
         indices, values, local_rows, block_to_tile, factors,
         mode=mode, num_rows=num_rows, tile=tile, block_p=block_p,
         interpret=interpret, tile_mask=tile_mask, num_buffers=num_buffers,
-        seg_starts=seg_starts, seg_rows=seg_rows, rows_sorted=rows_sorted)
+        seg_starts=seg_starts, seg_rows=seg_rows)

@@ -98,14 +98,17 @@ def first_visit(b2t, i) -> jax.Array:
 
 
 def chunked(launch: Callable, *, nblocks: int, block_to_tile: jax.Array,
-            out: jax.Array) -> jax.Array:
+            out: jax.Array, chunk: int | None = None,
+            unroll: bool = False) -> jax.Array:
     """Run ``launch(n, base, b2t, out) -> out`` over consecutive chunks of at
-    most :data:`MAX_CHUNK_BLOCKS` blocks.
+    most ``chunk`` blocks (:data:`MAX_CHUNK_BLOCKS` by default).
     ``base`` is a ``(1,)`` int32 array holding the chunk's first block,
-    ``b2t`` the chunk's slice of ``block_to_tile``; ``n`` is static."""
+    ``b2t`` the chunk's slice of ``block_to_tile``; ``n`` is static. The full
+    chunks run in a ``fori_loop``, or, with ``unroll``, one after another
+    in the program with constant bases."""
     if nblocks < 1:
         raise ValueError("an EC launch needs at least one block")
-    c = min(nblocks, MAX_CHUNK_BLOCKS)
+    c = min(nblocks, MAX_CHUNK_BLOCKS if chunk is None else chunk)
     nfull, tail = divmod(nblocks, c)
 
     def body(k, out):
@@ -115,6 +118,10 @@ def chunked(launch: Callable, *, nblocks: int, block_to_tile: jax.Array,
 
     if nfull == 1:
         out = launch(c, jnp.zeros((1,), jnp.int32), block_to_tile[:c], out)
+    elif unroll:
+        for k in range(nfull):
+            out = launch(c, jnp.full((1,), k * c, jnp.int32),
+                         block_to_tile[k * c:(k + 1) * c], out)
     else:
         out = jax.lax.fori_loop(0, nfull, body, out)
     if tail:

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels.mttkrp_pallas import ec_blocked
-from repro.kernels.ref import ec_rows_ref
+from repro.kernels.ref import ec_rows_ref, mttkrp_local_ref
 from repro.kernels import ops as kops
 
 
@@ -383,3 +383,234 @@ def test_chunked_launch_matches_single_launch(variant, monkeypatch):
     single = np.asarray(run())
     monkeypatch.setattr(tpu_layout, "MAX_CHUNK_BLOCKS", 3)
     np.testing.assert_array_equal(np.asarray(run()), single)
+
+
+# ---------------------------------------------------------------------------
+# The ``ref`` variant (each block summed into its tile, one scatter-add over
+# blocks) vs the slot-order oracle kernels/ref.py:mttkrp_local_ref
+# ---------------------------------------------------------------------------
+
+def _ref_and_oracle(indices, values, local_rows, b2t, factors, *, mode,
+                    num_rows, tile=8, block_p=128):
+    """(ops ``ref`` variant, slot-order oracle) on one shard, as numpy."""
+    got = kops.mttkrp_local(
+        jnp.asarray(indices), jnp.asarray(values), jnp.asarray(local_rows),
+        jnp.asarray(b2t), factors, mode=mode, num_rows=num_rows, tile=tile,
+        block_p=block_p, variant="ref")
+    want = mttkrp_local_ref(jnp.asarray(indices), jnp.asarray(values),
+                            jnp.asarray(local_rows), factors, mode, num_rows)
+    return np.asarray(got), np.asarray(want)
+
+
+def _integer_data(values, shapes, rank, seed):
+    """Small integers in place of the values (pads stay 0) and the factors:
+    every float32 partial sum is then exact, whatever the order."""
+    rng = np.random.default_rng(seed)
+    ints = rng.integers(1, 4, size=values.shape) * rng.choice([-1, 1],
+                                                              values.shape)
+    vals = np.where(values != 0, ints, 0).astype(np.float32)
+    factors = [jnp.asarray(rng.integers(-3, 4, size=(s, rank))
+                           .astype(np.float32)) for s in shapes]
+    return vals, factors
+
+
+def _normal_factors(shapes, rank, seed):
+    rng = np.random.default_rng(seed)
+    return [jnp.asarray(rng.normal(size=(s, rank)).astype(np.float32))
+            for s in shapes]
+
+
+def _assert_close_to_oracle(got, want, indices, values, local_rows, factors,
+                            mode, num_rows):
+    """|ref − oracle| ≤ 1e-5 of the sum of the terms' magnitudes, row by row
+    (a float32 reordering bound; exact cancellation leaves no relative
+    scale)."""
+    mag = np.asarray(mttkrp_local_ref(
+        jnp.asarray(indices), jnp.abs(jnp.asarray(values)),
+        jnp.asarray(local_rows), [jnp.abs(f) for f in factors], mode,
+        num_rows))
+    np.testing.assert_array_less(np.abs(got - want), 1e-5 * mag + 1e-30)
+
+
+REF_LAYOUTS = ["blocked", "sorted"]
+
+
+@pytest.mark.parametrize("layout", REF_LAYOUTS)
+@pytest.mark.parametrize("nmodes", [3, 4, 5])
+@pytest.mark.parametrize("rank", [8, 32])
+def test_ref_ec_bitwise_on_integers(layout, nmodes, rank):
+    """Integer values and factors: every sum is exact, so the two-level
+    reduction equals the slot-order oracle bit for bit."""
+    from repro.core.coo import random_sparse
+    from repro.core.partition import partition_mode
+    shape = tuple([24, 18, 12, 10, 8][:nmodes])
+    t = random_sparse(shape, 900, seed=nmodes + rank, distribution="zipf")
+    part, _, _ = partition_mode(t, 1, 1, replication=1, tile=8, block_p=128,
+                                layout=layout)
+    vals, factors = _integer_data(part.values[0], shape, rank, seed=rank)
+    got, want = _ref_and_oracle(part.indices[0], vals, part.local_rows[0],
+                                part.block_to_tile[0], factors, mode=1,
+                                num_rows=part.rows_max)
+    assert np.abs(want).max() > 0
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("layout", REF_LAYOUTS)
+@pytest.mark.parametrize("nmodes", [3, 4, 5])
+@pytest.mark.parametrize("rank", [8, 32])
+def test_ref_ec_matches_oracle(layout, nmodes, rank):
+    """N(0, 1) values and factors: agreement to float32 reordering."""
+    from repro.core.coo import random_sparse
+    from repro.core.partition import partition_mode
+    shape = tuple([24, 18, 12, 10, 8][:nmodes])
+    t = random_sparse(shape, 900, seed=nmodes * 7 + rank,
+                      distribution="zipf")
+    part, _, _ = partition_mode(t, 1, 1, replication=1, tile=8, block_p=128,
+                                layout=layout)
+    factors = _normal_factors(shape, rank, seed=rank + 1)
+    args = (part.indices[0], part.values[0], part.local_rows[0])
+    got, want = _ref_and_oracle(*args, part.block_to_tile[0], factors,
+                                mode=1, num_rows=part.rows_max)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    _assert_close_to_oracle(got, want, *args, factors, 1, part.rows_max)
+
+
+@pytest.mark.parametrize("layout", REF_LAYOUTS)
+def test_ref_ec_pad_only_device(layout):
+    """A device that owns no nonzeros holds only pad blocks: exact zeros."""
+    from repro.core.coo import SparseTensor
+    from repro.core.partition import partition_mode
+    ind = np.zeros((50, 3), np.int32)
+    ind[:, 1] = np.arange(50) % 7
+    ind[:, 2] = np.arange(50) % 5
+    t = SparseTensor(ind, np.ones(50, np.float32), (3, 7, 5))
+    part, _, _ = partition_mode(t, 0, 2, strategy="amped_cdf", replication=1,
+                                layout=layout)
+    empty = int(np.argmin(part.nnz_true))
+    assert part.nnz_true[empty] == 0 and part.nblocks >= 1
+    factors = _normal_factors(t.shape, 8, seed=0)
+    got, want = _ref_and_oracle(
+        part.indices[empty], part.values[empty], part.local_rows[empty],
+        part.block_to_tile[empty], factors, mode=0, num_rows=part.rows_max)
+    np.testing.assert_array_equal(got, 0.0)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("data", ["integer", "normal"])
+def test_ref_ec_heavy_row_spans_blocks(data):
+    """One output row holds most nonzeros: its tile's run spans hundreds of
+    blocks, which the block scatter adds into one tile."""
+    from repro.core.coo import SparseTensor
+    from repro.core.partition import partition_mode
+    rng = np.random.default_rng(3)
+    n, shape = 40_000, (20, 60, 50)
+    ind = np.stack([np.where(rng.random(n) < 0.9, 5, rng.integers(0, 20, n)),
+                    rng.integers(0, 60, n), rng.integers(0, 50, n)], 1)
+    t = SparseTensor(ind.astype(np.int32),
+                     rng.normal(size=n).astype(np.float32), shape)
+    part, _, _ = partition_mode(t, 0, 1, replication=1, tile=8, block_p=128)
+    b2t = part.block_to_tile[0]
+    assert np.bincount(b2t).max() >= 256  # one tile, hundreds of blocks
+    if data == "integer":
+        vals, factors = _integer_data(part.values[0], shape, 16, seed=1)
+    else:
+        vals, factors = part.values[0], _normal_factors(shape, 16, seed=1)
+    args = (part.indices[0], vals, part.local_rows[0])
+    got, want = _ref_and_oracle(*args, b2t, factors, mode=0,
+                                num_rows=part.rows_max)
+    if data == "integer":
+        np.testing.assert_array_equal(got, want)
+    else:
+        _assert_close_to_oracle(got, want, *args, factors, 0, part.rows_max)
+
+
+@pytest.mark.parametrize("path", ["loop", "unrolled"])
+def test_ref_ec_chunks_match_one_chunk(path, monkeypatch):
+    """The shard in chunks of a few blocks, in a loop (factors that fit in
+    VMEM together) or laid out one after another (factors that do not):
+    tiles split across chunks keep their partial sums, so the output equals
+    the one-chunk output bit for bit, and the oracle's on integer data."""
+    from repro.core.coo import random_sparse
+    from repro.core.partition import partition_mode
+    t = random_sparse((40, 18, 12), 3000, seed=7, distribution="zipf")
+    part, _, _ = partition_mode(t, 0, 1, replication=1, tile=8, block_p=16)
+    assert part.nblocks > 20
+    args = (part.indices[0], part.values[0], part.local_rows[0],
+            part.block_to_tile[0])
+    kw = dict(mode=0, num_rows=part.rows_max, block_p=part.block_p)
+    factors = _normal_factors(t.shape, 16, seed=7)
+    int_vals, int_factors = _integer_data(part.values[0], t.shape, 16, seed=7)
+    one_chunk, _ = _ref_and_oracle(*args, factors, **kw)
+    if path == "loop":
+        monkeypatch.setattr(kops, "REF_LOOP_BLOCKS", 3)
+    else:
+        monkeypatch.setattr(kops, "REF_VMEM_FACTOR_BYTES", 0)
+        # 5 blocks of 16 slots, two input modes of 128 padded floats
+        monkeypatch.setattr(kops, "REF_CHUNK_BYTES", 5 * 16 * 2 * 512)
+    got, _ = _ref_and_oracle(*args, factors, **kw)
+    np.testing.assert_array_equal(got, one_chunk)
+    got, want = _ref_and_oracle(args[0], int_vals, *args[2:], int_factors,
+                                **kw)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_ref_ec_trailing_pad_blocks():
+    """A light device of a two-device plan ends in whole pad blocks that
+    revisit its last tile; they add exact zeros there."""
+    from repro.core.coo import random_sparse
+    from repro.core.partition import partition_mode
+    t = random_sparse((64, 18, 12), 1500, seed=5, distribution="zipf")
+    part, _, _ = partition_mode(t, 0, 2, strategy="amped_cdf",
+                                replication=1, tile=8, block_p=128)
+    real_blocks = -(-part.nnz_true // part.block_p)
+    light = int(np.argmin(part.nnz_true))
+    assert real_blocks[light] < part.nblocks  # trailing pad blocks exist
+    b2t = part.block_to_tile[light]
+    tail = b2t[real_blocks[light]:]
+    assert (tail == tail[0]).all()
+    for data in ("integer", "normal"):
+        if data == "integer":
+            vals, factors = _integer_data(part.values[light], t.shape, 8,
+                                          seed=2)
+        else:
+            vals = part.values[light]
+            factors = _normal_factors(t.shape, 8, seed=2)
+        args = (part.indices[light], vals, part.local_rows[light])
+        got, want = _ref_and_oracle(*args, b2t, factors, mode=0,
+                                    num_rows=part.rows_max)
+        if data == "integer":
+            np.testing.assert_array_equal(got, want)
+        else:
+            _assert_close_to_oracle(got, want, *args, factors, 0,
+                                    part.rows_max)
+
+
+def test_ref_ec_super_shard_windows(tmp_path):
+    """Streamed super-shard windows: each window's ``ref`` EC matches the
+    oracle, and the windows' partials add up to the resident shard's
+    ``ref`` EC bit for bit (each tile's blocks meet in one window)."""
+    from repro.core.coo import random_sparse
+    from repro.store import (TensorStore, build_plan_from_store,
+                             split_mode_super_shards, write_store_from_coo)
+    t = random_sparse((96, 18, 12), 3000, seed=6, distribution="zipf")
+    path = str(tmp_path / "s.store")
+    write_store_from_coo(t, path, chunk_nnz=256)
+    part = build_plan_from_store(TensorStore(path), 1, replication=1).modes[0]
+    nnz_cap_full = part.device_arrays(0)[1].shape[0]
+    sp = split_mode_super_shards(
+        part, max(64 * 1024, nnz_cap_full * (4 * 3 + 8 + 4) // 3))
+    assert len([w for w in sp.windows[0] if w != (0, 0)]) > 1
+    factors = _normal_factors(t.shape, 16, seed=3)
+    kw = dict(mode=0, num_rows=part.rows_max)
+    acc = np.zeros((part.rows_max, 16), np.float32)
+    for (t0, t1) in sp.windows[0]:
+        wi, wv, wr, b2t, _ = part.super_shard_arrays(
+            0, t0, t1, nnz_cap=sp.nnz_cap, nblocks=sp.nblocks)
+        got, want = _ref_and_oracle(wi, wv, wr, b2t, factors, **kw)
+        _assert_close_to_oracle(got, want, wi, wv, wr, factors, 0,
+                                part.rows_max)
+        acc = acc + got
+    di, dv, dr = part.device_arrays(0)
+    resident, _ = _ref_and_oracle(di, dv, dr, part.block_to_tile[0],
+                                  factors, **kw)
+    np.testing.assert_array_equal(acc, resident)

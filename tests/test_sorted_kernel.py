@@ -1,5 +1,5 @@
-"""Row-sorted hierarchical-COO EC (``ec_sorted``) vs the jnp reference:
-bit-identity on real partitions, degenerate shapes, the
+"""Row-sorted hierarchical-COO EC (``ec_sorted``) vs the slot-order oracle
+(``kernels/ref.py``): bit-identity on real partitions, degenerate shapes, the
 ``segment_sum(indices_are_sorted=True)`` hint, the out-of-core store and
 super-shard paths, and the autotune cache v2 -> v3 migration."""
 import numpy as np
@@ -44,6 +44,13 @@ def _run(part, factors, variant, dev=0, num_buffers=2, mode=1):
         tile_mask=jnp.asarray(part.tile_visited[dev]), **kw, **extra)
 
 
+def _oracle(part, factors, dev=0, mode=1):
+    """The slot-order oracle on one device's shard."""
+    return mttkrp_local_ref(
+        jnp.asarray(part.indices[dev]), jnp.asarray(part.values[dev]),
+        jnp.asarray(part.local_rows[dev]), factors, mode, part.rows_max)
+
+
 @pytest.mark.parametrize("nmodes", [3, 4, 5])
 @pytest.mark.parametrize("rank", [8, 32])
 def test_sorted_matches_ref_bitwise(nmodes, rank):
@@ -52,7 +59,7 @@ def test_sorted_matches_ref_bitwise(nmodes, rank):
     _, part, factors = _sorted_case(nmodes, rank, seed=nmodes * 10 + rank)
     assert part.block_layout == "sorted"
     got = np.asarray(_run(part, factors, "sorted"))
-    ref = np.asarray(_run(part, factors, "ref"))
+    ref = np.asarray(_oracle(part, factors))
     np.testing.assert_array_equal(got, ref)
 
 
@@ -69,7 +76,7 @@ def test_sorted_multi_device_shards(strategy, num_devices, replication):
                                     strategy=strategy)
     for dev in range(num_devices):
         got = np.asarray(_run(part, factors, "sorted", dev=dev))
-        ref = np.asarray(_run(part, factors, "ref", dev=dev))
+        ref = np.asarray(_oracle(part, factors, dev=dev))
         np.testing.assert_array_equal(got, ref, err_msg=f"dev {dev}")
 
 
@@ -78,14 +85,14 @@ def test_sorted_num_buffers(num_buffers):
     """DMA-ring depth changes only the prefetch schedule, never the sums."""
     _, part, factors = _sorted_case(3, 16, seed=5)
     got = np.asarray(_run(part, factors, "sorted", num_buffers=num_buffers))
-    ref = np.asarray(_run(part, factors, "ref"))
+    ref = np.asarray(_oracle(part, factors))
     np.testing.assert_array_equal(got, ref)
 
 
 def test_ref_hint_bit_identity():
     """``indices_are_sorted=True`` is declarative — on a row-sorted shard the
-    hinted segment_sum returns the exact bits of the unhinted call (both
-    through ec_rows_ref and through the mttkrp_local rows_sorted plumb)."""
+    oracle's hinted segment_sum returns the exact bits of the unhinted
+    call."""
     _, part, factors = _sorted_case(3, 16, seed=7)
     rows = np.asarray(part.local_rows[0])
     assert (np.diff(rows) >= 0).all()  # layout contract
@@ -96,13 +103,6 @@ def test_ref_hint_bit_identity():
                               jnp.asarray(part.values[0]), jnp.asarray(rows),
                               factors, 1, part.rows_max, sorted_rows=True)
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(hinted))
-    kw = dict(mode=1, num_rows=part.rows_max, tile=part.tile,
-              block_p=part.block_p)
-    via_ops = kops.mttkrp_local(
-        jnp.asarray(part.indices[0]), jnp.asarray(part.values[0]),
-        jnp.asarray(rows), jnp.asarray(part.block_to_tile[0]), factors,
-        variant="ref", rows_sorted=True, **kw)
-    np.testing.assert_array_equal(np.asarray(plain), np.asarray(via_ops))
 
 
 # -- degenerate shapes -------------------------------------------------------
@@ -144,7 +144,7 @@ def test_sorted_single_segment_spans_blocks():
     factors = [jnp.asarray(rng.normal(size=(s, 8)).astype(np.float32))
                for s in t.shape]
     got = np.asarray(_run(part, factors, "sorted"))
-    ref = np.asarray(_run(part, factors, "ref"))
+    ref = np.asarray(_oracle(part, factors))
     np.testing.assert_array_equal(got, ref)
     # exactly one written row
     assert (np.abs(got).sum(axis=1) != 0).sum() == 1
@@ -174,7 +174,7 @@ def test_sorted_all_padding_trailing_block():
     blocks = np.asarray(part.values[light]).reshape(-1, part.block_p)
     assert (blocks == 0).all(axis=1).any()  # >= 1 all-padding block
     got = np.asarray(_run(part, factors, "sorted", dev=light))
-    ref = np.asarray(_run(part, factors, "ref", dev=light))
+    ref = np.asarray(_oracle(part, factors, dev=light))
     np.testing.assert_array_equal(got, ref)
 
 
@@ -205,7 +205,7 @@ def test_sorted_segment_boundaries_on_block_edges():
     factors = [jnp.asarray(rng.normal(size=(s, 8)).astype(np.float32))
                for s in t.shape]
     got = np.asarray(_run(part, factors, "sorted"))
-    ref = np.asarray(_run(part, factors, "ref"))
+    ref = np.asarray(_oracle(part, factors))
     np.testing.assert_array_equal(got, ref)
 
 
@@ -260,9 +260,9 @@ def test_sorted_store_plan_bit_identity(tmp_path):
                 jnp.asarray(b2t), factors, variant="sorted",
                 interpret=True, tile_mask=jnp.asarray(vis),
                 seg_starts=jnp.asarray(ss), seg_rows=jnp.asarray(sr), **kw)
-            ref = kops.mttkrp_local(
-                jnp.asarray(wi), jnp.asarray(wv), jnp.asarray(wr),
-                jnp.asarray(b2t), factors, variant="ref", **kw)
+            ref = mttkrp_local_ref(
+                jnp.asarray(wi), jnp.asarray(wv), jnp.asarray(wr), factors,
+                1, part.rows_max)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(ref),
                                           err_msg=f"dev {dev} [{t0},{t1})")
 

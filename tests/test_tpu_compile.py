@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
 imports every test file.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -123,3 +125,144 @@ def test_resident_mode_update_compiles_for_v5e(one_chip):
     grams = [jax.ShapeDtypeStruct((32, 32), jnp.float32, sharding=rep)] * 3
     compiled = update.lower(facs[0], dev, facs[1:], grams).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _hlo_shapes(text):
+    """{instruction name: dims} over every instruction of a compiled HLO
+    module whose result is a single array."""
+    shapes = {}
+    for m in re.finditer(r"%([\w.-]+) = \w+\[([\d,]*)\]", text):
+        shapes[m.group(1)] = [int(d) for d in m.group(2).split(",") if d]
+    return shapes
+
+
+def _operand_dims(text, op):
+    """Dims of each operand of every ``op`` instruction in ``text``."""
+    shapes = _hlo_shapes(text)
+    out = []
+    for m in re.finditer(rf"= [^=]*? {op}\(([^)]*)\)", text):
+        names = re.findall(r"%([\w.-]+)", m.group(1))
+        out.append([shapes.get(n) for n in names])
+    return out
+
+
+AMAZON = (48216, 17744, 18056)          # amazon-r32's padded factor rows
+TWITCH = (186296, 73944, 9408, 80, 80)  # twitch-r32's
+SMOKE = (289272, 106456, 108311)        # chip_smoke.py's amazon at 0.06
+# (factor rows, output mode, tile, layout, blocks in the shard, whether the
+# slot-order oracle's temporaries are compared): a few hundred blocks, the
+# cells' shards where the chunks run in a loop (amazon modes 0 and 1) and
+# where they are laid out one after another (twitch mode 3, whose input
+# factors do not fit in VMEM together), and chip_smoke.py's mode-0 shard
+# (row-sorted layout, tile 16, about 42.9 M slots).
+REF_SHAPES = {
+    "300": (AMAZON, 0, 8, "blocked", 300, True),
+    "89000": (AMAZON, 0, 8, "blocked", 89_000, True),
+    "amazon-mode1": (AMAZON, 1, 8, "blocked", 87_300, True),
+    "twitch-mode3": (TWITCH, 3, 8, "blocked", 24_170, False),
+    "chip_smoke": (SMOKE, 0, 16, "sorted", 335_000, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REF_SHAPES))
+def test_ref_mode_update_scatters_blocks_for_v5e(one_chip, monkeypatch,
+                                                 case):
+    """One resident ALS mode update on the ``ref`` EC (R 32, block_p 128),
+    compiled for the chip at the shapes of ``REF_SHAPES``: no scatter takes
+    one update row per nonzero, no sort runs over the nonzeros, the block
+    scatter-add takes at most one chunk of blocks, the EC's matmuls run at
+    HIGHEST, and the program's temporaries stay within a few chunks'
+    gathered rows (``kops.REF_CHUNK_BYTES``) however large the shard. At
+    the amazon shapes they are also no larger than the slot-order oracle's
+    (kernels/ref.py) in the same update, to within 1 MiB. (Where the output has few rows, as
+    in twitch's mode 3, the compiler fuses the oracle's gathers into its
+    scatter and the oracle holds less; its scatter then runs over every
+    nonzero.)"""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import als, mttkrp
+    from repro.core.coo import random_sparse
+    from repro.core.partition import build_plan
+    from repro.kernels import ops as kops
+    from repro.kernels.ref import mttkrp_local_ref
+    rows, mode, tile, layout, nblocks, vs_oracle = REF_SHAPES[case]
+    nmodes = len(rows)
+    topo, _ = one_chip
+    # the plan sets the factor rows and tile geometry; the shard's shapes
+    # are given below, at the block count under test
+    t = random_sparse(rows, 20000, seed=0, distribution="zipf")
+    plan = build_plan(t, 1, replication=1, tile=tile, block_p=128,
+                      layout=layout)
+    part = plan.modes[mode]
+    nnz = nblocks * part.block_p
+    mesh = mttkrp.cp_mesh(1, 1, devices=np.asarray(topo.devices[:1]))
+    grid = ("group", "sub")
+
+    def sharded(shape, dtype, trailing):
+        return jax.ShapeDtypeStruct(
+            (1, 1) + shape, dtype,
+            sharding=NamedSharding(mesh, P(*grid, *([None] * trailing))))
+
+    dev = mttkrp.DeviceArrays(
+        indices=sharded((nnz, nmodes), jnp.int32, 2),
+        values=sharded((nnz,), jnp.float32, 1),
+        local_rows=sharded((nnz,), jnp.int32, 1),
+        block_to_tile=sharded((nblocks,), jnp.int32, 1),
+        tile_visited=sharded((part.rows_max // part.tile,), jnp.float32, 1),
+        seg_starts=sharded((nblocks, part.tile + 2), jnp.int32, 2),
+        seg_rows=sharded((nblocks, part.tile + 1), jnp.int32, 2))
+    rep = NamedSharding(mesh, P())
+    facs = [jax.ShapeDtypeStruct((m.padded_rows, 32), jnp.float32,
+                                 sharding=rep) for m in plan.modes]
+    grams = [jax.ShapeDtypeStruct((32, 32), jnp.float32, sharding=rep)] \
+        * nmodes
+
+    def compile_update():
+        update = als.make_mode_update(plan, mode, mesh, use_kernel=False,
+                                      variant="ref", interpret=False)
+        others = [facs[w] for w in range(nmodes) if w != mode]
+        return update.lower(facs[mode], dev, others, grams).compile()
+
+    def per_nonzero(text):
+        """(scatters whose updates have nnz rows, sorts over nnz keys)."""
+        scatters = [d for d in _operand_dims(text, "scatter")
+                    if d[-1] and d[-1][0] == nnz]
+        sorts = [d for d in _operand_dims(text, "sort")
+                 if any(x and x[0] == nnz for x in d)]
+        return scatters, sorts
+
+    compiled = compile_update()
+    text = compiled.as_text()
+    block_scatters = [d[-1] for d in _operand_dims(text, "scatter")
+                      if d[-1] and d[-1][1:] == [part.tile, 32]]
+    assert block_scatters, "the block scatter-add is missing"
+    # in-VMEM loop chunks or unrolled chunks of REF_CHUNK_BYTES: a chunk is
+    # far smaller than the shard (except at a few hundred blocks)
+    assert max(d[0] for d in block_scatters) <= max(
+        kops.REF_LOOP_BLOCKS, kops.REF_CHUNK_BYTES // (2 * 128 * 512))
+    assert per_nonzero(text) == ([], [])
+    # the in-block contraction keeps float32: every EC matmul at HIGHEST
+    ec_dots = [ln for ln in text.splitlines()
+               if "convolution(" in ln and "/ec_local/" in ln]
+    assert ec_dots and all("operand_precision={highest,highest}" in ln
+                           for ln in ec_dots), ec_dots
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 4 * kops.REF_CHUNK_BYTES, temp
+    if not vs_oracle:
+        return
+
+    def oracle(indices, values, local_rows, block_to_tile, factors, *,
+               mode, num_rows, **_):
+        return mttkrp_local_ref(indices, values, local_rows, factors, mode,
+                                num_rows)
+
+    monkeypatch.setitem(kops.KERNEL_VARIANTS, "ref", oracle)
+    compiled_oracle = compile_update()
+    scatters, sorts = per_nonzero(compiled_oracle.as_text())
+    # the checks above see them where they are (at mode 1 the compiler
+    # sorts nothing for the oracle's scatter)
+    assert scatters and (sorts or mode != 0)
+    # At mode 1 both programs' temporaries are the shard relayouts (the
+    # indices' and local_rows', 223.5 MB): within 1 MiB, the loop's
+    # bookkeeping and the arena's packing
+    assert temp <= compiled_oracle.memory_analysis().temp_size_in_bytes \
+        + (1 << 20)
