@@ -54,7 +54,8 @@ from repro.sparse.stream import ShardStreamer, SuperShardStreamer
 # obs.report() — names are never reused within a process
 _SOLVER_IDS = itertools.count(1)
 
-__all__ = ["CPSolver", "compile", "validate_factor_payload"]
+__all__ = ["CPSolver", "compile", "partition_report",
+           "validate_factor_payload"]
 
 
 def validate_factor_payload(factors, lam, *, shape, rank,
@@ -89,6 +90,26 @@ def validate_factor_payload(factors, lam, *, shape, rank,
     if ls != (rank,):
         raise ValueError(f"{source} lambda has shape {ls}, expected "
                          f"({rank},)")
+
+
+def partition_report(plan: CPPlan) -> dict:
+    """What a plan's static partition costs across devices, per mode, from
+    the plan alone: true nonzeros and used kernel slots (``blocks_true ·
+    block_p``) per device, max over mean, and the padded ownership layout's
+    rows over the mode's true rows. One device reads 1, 1 and the layout's
+    tile padding. The exchange bytes this layout implies are the
+    ``exchange`` section's ``modelled`` entry of the same report."""
+    per_mode = {}
+    for d, part in enumerate(plan.modes):
+        nnz = np.asarray(part.nnz_true, np.float64)
+        slots = np.asarray(part.blocks_true, np.float64) * part.block_p
+        per_mode[d] = {
+            "nnz_max_over_mean": float(nnz.max() / max(nnz.mean(), 1.0)),
+            "slots_max_over_mean": float(slots.max() / max(slots.mean(), 1.0)),
+            "padded_rows_over_rows":
+                part.padded_rows / max(int(plan.shape[d]), 1),
+        }
+    return {"num_devices": int(plan.num_devices), "per_mode": per_mode}
 
 
 class CPSolver:
@@ -188,6 +209,7 @@ class CPSolver:
             self._ckpt_mgr = CheckpointManager(config.runtime.checkpoint_dir)
         self.metrics.register_provider("overlap", self.overlap_report)
         self.metrics.register_provider("imbalance", self.imbalance_report)
+        self.metrics.register_provider("partition", self.partition_report)
         self.metrics.register_provider(
             "exchange", lambda: self.exchange_report(measure=False))
         self.metrics.register_provider("stream",
@@ -443,6 +465,11 @@ class CPSolver:
             "events": self.schedule_events,
         }
 
+    def partition_report(self) -> dict:
+        """:func:`partition_report` of the live (possibly rebalanced) plan;
+        it needs no rebalancer."""
+        return partition_report(self.plan)
+
     def exchange_report(self, *, measure: bool = True) -> dict:
         """Modelled — and, with ``measure``, HLO-measured — per-device
         exchange bytes for one ALS sweep under the resolved
@@ -534,11 +561,11 @@ class CPSolver:
 
     def report(self) -> dict:
         """This solver's unified metrics report: counters/gauges/latency
-        histograms plus the ``overlap``/``imbalance``/``exchange``/
-        ``stream`` sections — each a registered provider over the
-        pre-existing report method, value-identical to calling it
-        directly. (``exchange`` uses ``measure=False``: a report snapshot
-        must not force an HLO re-lower.)"""
+        histograms plus the ``overlap``/``imbalance``/``partition``/
+        ``exchange``/``stream`` sections — each a registered provider over
+        its report method, value-identical to calling it directly.
+        (``exchange`` uses ``measure=False``: a report snapshot must not
+        force an HLO re-lower.)"""
         return self.metrics.report()
 
     def stream_monitor(self) -> StreamMonitor:

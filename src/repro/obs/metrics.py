@@ -10,7 +10,7 @@ can no longer yield a torn count/bucket view.
 
 :class:`MetricsRegistry` is what every reporter registers into —
 ``ServiceMetrics`` wraps one, ``CPSolver`` owns one whose named *providers*
-(``overlap``/``exchange``/``imbalance``/``stream``) are the pre-existing
+(``overlap``/``exchange``/``imbalance``/``partition``/``stream``) are its
 report methods, and the autotune/plan caches count hits into the process
 registry (:func:`repro.obs.get_registry`). ``report()`` is one
 JSON-serializable snapshot of everything.

@@ -180,3 +180,24 @@ def test_validate_plan_rejects_inconsistent_device_grid(small_tensor):
     bad_plan = dataclasses.replace(plan, modes=(bad_part,) + plan.modes[1:])
     with pytest.raises(ValueError, match="device grid"):
         validate_plan(bad_plan)
+
+
+def test_partition_report_of_a_four_device_plan():
+    """What the static partition costs, read from a 4-device paper-preset
+    plan built in this one-device process (planning needs no devices):
+    CDF ownership pads a Zipf mode's factor to several times its rows, and
+    every ratio of a max to a mean is at least 1."""
+    from repro import api
+    from repro.api.solver import partition_report
+    cfg = api.preset("paper", {"rank": 8, "runtime.num_devices": 4})
+    t = random_sparse((300, 120, 90), 6000, seed=0, distribution="zipf")
+    plan = api.plan(t, cfg)
+    rep = partition_report(plan)
+    assert rep["num_devices"] == 4
+    for d, mode in rep["per_mode"].items():
+        part = plan.modes[d]
+        assert mode["padded_rows_over_rows"] == \
+            part.n_groups * part.rows_max / plan.shape[d] > 1.0
+        assert mode["slots_max_over_mean"] >= 1.0
+        assert mode["nnz_max_over_mean"] == pytest.approx(
+            part.nnz_true.max() / part.nnz_true.mean())

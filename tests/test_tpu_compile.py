@@ -266,3 +266,62 @@ def test_ref_mode_update_scatters_blocks_for_v5e(one_chip, monkeypatch,
     # bookkeeping and the arena's packing
     assert temp <= compiled_oracle.memory_analysis().temp_size_in_bytes \
         + (1 << 20)
+
+
+_COLLECTIVE_RE = re.compile(
+    r"= .*? (collective-permute(?:-start|-done)?|all-gather(?:-start|-done)?"
+    r"|all-reduce(?:-start|-done)?|reduce-scatter|all-to-all)\(")
+
+
+@pytest.mark.parametrize("mode", [0, 1])
+def test_four_chip_exchange_collectives_are_scoped_for_v5e(one_chip, mode):
+    """A paper-preset mode update over the four chips of a v5e 2x2 (CDF
+    ownership across 4 groups, the ``ref`` EC under ``shard_map``, the
+    Algorithm-3 ring): every collective the compiler emits carries the
+    ``factor_exchange`` or ``merge`` scope, so the trace's exchange time
+    (``bench/metrics/exchange_ms.py``) sees all of it and none falls in
+    the solve."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import api, comm
+    from repro.core import als, mttkrp
+    from repro.core.coo import random_sparse
+    topo, _ = one_chip
+    cfg = api.preset("paper", {"rank": 32, "runtime.num_devices": 4})
+    t = random_sparse((3000, 1200, 900), 60000, seed=0, distribution="zipf")
+    plan = api.plan(t, cfg)
+    mesh = mttkrp.cp_mesh(4, 1, devices=np.asarray(topo.devices[:4]))
+    part = plan.modes[mode]
+    grid = ("group", "sub")
+
+    def sharded(shape, dtype, trailing):
+        return jax.ShapeDtypeStruct(
+            (4, 1) + shape, dtype,
+            sharding=NamedSharding(mesh, P(*grid, *([None] * trailing))))
+
+    nblocks = part.nblocks
+    dev = mttkrp.DeviceArrays(
+        indices=sharded((part.nnz_max, 3), jnp.int32, 2),
+        values=sharded((part.nnz_max,), jnp.float32, 1),
+        local_rows=sharded((part.nnz_max,), jnp.int32, 1),
+        block_to_tile=sharded((nblocks,), jnp.int32, 1),
+        tile_visited=sharded((part.rows_max // part.tile,), jnp.float32, 1),
+        seg_starts=sharded((nblocks, part.tile + 2), jnp.int32, 2),
+        seg_rows=sharded((nblocks, part.tile + 1), jnp.int32, 2))
+    rep = NamedSharding(mesh, P())
+    facs = [jax.ShapeDtypeStruct((m.padded_rows, 32), jnp.float32,
+                                 sharding=rep) for m in plan.modes]
+    grams = [jax.ShapeDtypeStruct((32, 32), jnp.float32, sharding=rep)] * 3
+    spec = comm.resolve_exchange_spec(cfg.exchange, plan=plan, rank=32,
+                                      mesh=mesh)
+    update = als.make_mode_update(
+        plan, mode, mesh, **cfg.kernel.mttkrp_kwargs(nmodes=3, rank=32),
+        exchange_spec=spec)
+    others = [facs[w] for w in range(3) if w != mode]
+    text = update.lower(facs[mode], dev, others, grams).compile().as_text()
+    collectives = [ln for ln in text.splitlines()
+                   if _COLLECTIVE_RE.search(ln)]
+    assert collectives, "the exchange emitted no collective"
+    for ln in collectives:
+        m = re.search(r'op_name="([^"]*)"', ln)
+        assert m and re.search(r"(^|/)(factor_exchange|merge)(/|$)",
+                               m.group(1)), ln
