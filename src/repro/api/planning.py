@@ -41,8 +41,10 @@ __all__ = ["plan", "plan_signature", "save_plan", "load_plan",
 
 # v2: ModePartition.blocks_true + rebalance_epoch; v3: lazy (out-of-core)
 # plans — store-backed manifests carry a store path + digest instead of the
-# O(nnz) arrays.
-PLAN_FORMAT_VERSION = 3
+# O(nnz) arrays; v4: the packed ownership layout (core/partition.py) — the
+# row translations address packed factors, and each mode's manifest entry
+# carries its groups' ``rows_owned``.
+PLAN_FORMAT_VERSION = 4
 _SAMPLE_CAP = 65536  # strided digest sample size (cheap at billion scale)
 
 # Observability for tests and ops dashboards: how often plan() rebuilt vs
@@ -162,10 +164,11 @@ def save_plan(p: CPPlan, path: str, *, signature: str | None = None) -> str:
                              "digest": store.digest}
     for d, part in enumerate(p.modes):
         # META_FIELDS are ints except block_layout (a layout-name string)
-        manifest["modes"].append(
-            {k: (v if isinstance(v, str) else int(v))
-             for k in ModePartition.META_FIELDS
-             for v in (getattr(part, k),)})
+        meta = {k: (v if isinstance(v, str) else int(v))
+                for k in ModePartition.META_FIELDS
+                for v in (getattr(part, k),)}
+        meta["rows_owned"] = [int(x) for x in part.rows_owned]
+        manifest["modes"].append(meta)
         if not lazy:
             for k in ModePartition.ARRAY_FIELDS:
                 arrays[f"mode{d}_{k}"] = getattr(part, k)
@@ -203,13 +206,10 @@ def load_plan(path: str, *, expect_signature: str | None = None) -> CPPlan:
         modes, g2ps, p2gs = [], [], []
         for d, meta in enumerate(manifest["modes"]):
             if not manifest.get("lazy"):
-                # block_layout: string, absent in manifests written before
-                # the sorted layout existed (same format version)
-                fields = {k: int(meta[k])
-                          for k in ModePartition.META_FIELDS
-                          if k != "block_layout"}
-                fields["block_layout"] = str(
-                    meta.get("block_layout", "blocked"))
+                # META_FIELDS are ints except block_layout
+                fields = {k: (meta[k] if k == "block_layout"
+                              else int(meta[k]))
+                          for k in ModePartition.META_FIELDS}
                 fields.update({k: npz[f"mode{d}_{k}"]
                                for k in ModePartition.ARRAY_FIELDS})
                 modes.append(ModePartition(**fields))
@@ -232,8 +232,9 @@ def _rebind_lazy_modes(path: str, manifest: dict, g2ps, p2gs):
     """Reattach a persisted lazy plan to its tensor store: reopen the store
     named in the manifest, verify its digest is still the one the plan was
     built from, and rebuild the lazy partitions from the saved layouts
-    (owner groups are recoverable from ``g2p // rows_max``; everything else
-    re-derives from the store's histogram sidecars)."""
+    (owner groups are recoverable from the packed rows and the groups'
+    offsets, :func:`~repro.core.partition.owner_from_packed`; everything
+    else re-derives from the store's histogram sidecars)."""
     ref = manifest.get("store") or {}
     try:
         store = TensorStore(ref.get("path", ""))
@@ -249,18 +250,17 @@ def _rebind_lazy_modes(path: str, manifest: dict, g2ps, p2gs):
     layouts = []
     for d, meta in enumerate(manifest["modes"]):
         g2p = np.asarray(g2ps[d], np.int64)
-        rows_max = int(meta["rows_max"])
-        owner = (g2p // rows_max).astype(np.int32)
+        rows_owned = np.asarray(meta["rows_owned"], np.int64)
         layouts.append(ModeLayout(
             mode=int(meta["mode"]), num_devices=int(meta["num_devices"]),
             r=int(meta["r"]), n_groups=int(meta["n_groups"]),
-            rows_max=rows_max, tile=int(meta["tile"]),
-            block_p=int(meta["block_p"]), owner=owner,
+            rows_max=int(meta["rows_max"]), tile=int(meta["tile"]),
+            block_p=int(meta["block_p"]),
+            owner=partition_mod.owner_from_packed(g2p, rows_owned),
             global_to_padded=g2p,
             padded_to_global=np.asarray(p2gs[d], np.int64),
-            rows_owned=np.bincount(owner, minlength=int(meta["n_groups"])
-                                   ).astype(np.int64),
-            block_layout=str(meta.get("block_layout", "blocked"))))
+            rows_owned=rows_owned,
+            block_layout=meta["block_layout"]))
     return store_plan_mod.lazy_parts_from_layouts(store, layouts)
 
 

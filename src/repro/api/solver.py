@@ -26,7 +26,7 @@ The solver is deliberately *not* serializable — that's the plan's job
 (:mod:`repro.training.checkpoint`). ``checkpoint()``/``restore()`` store
 GLOBAL-layout factors, so a checkpoint taken by a solver compiled for m
 devices restores into one compiled for m' devices (elastic re-pad into the
-new plan's ownership layout).
+new plan's packed ownership layout).
 """
 from __future__ import annotations
 
@@ -44,6 +44,7 @@ from repro.core import als as als_mod
 from repro.core import mttkrp as dmttkrp
 from repro.core.decompose import CPResult
 from repro.core.partition import CPPlan
+from repro.kernels import ops as kops
 from repro.obs import export as obs_export
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import EventLog, MetricsRegistry
@@ -92,22 +93,33 @@ def validate_factor_payload(factors, lam, *, shape, rank,
                          f"({rank},)")
 
 
-def partition_report(plan: CPPlan) -> dict:
+def partition_report(plan: CPPlan, rank: int) -> dict:
     """What a plan's static partition costs across devices, per mode, from
     the plan alone: true nonzeros and used kernel slots (``blocks_true ·
-    block_p``) per device, max over mean, and the padded ownership layout's
-    rows over the mode's true rows. One device reads 1, 1 and the layout's
-    tile padding. The exchange bytes this layout implies are the
-    ``exchange`` section's ``modelled`` entry of the same report."""
+    block_p``) per device, max over mean; the rows the exchange ships
+    (``n_groups · rows_max`` slabs) over the mode's true rows
+    (``padded_rows_over_rows``); the packed factor's rows over them
+    (``factor_rows_over_rows``); and, for the ``ref`` EC of the mode's
+    update at ``rank``, its input factors' lane-padded bytes
+    (``ec_input_bytes``) and whether it runs its chunks in the VMEM loop
+    (``ec_loop``, :func:`repro.kernels.ops.ref_takes_loop`). One device
+    reads 1 and 1, and the layout's tile padding in both row ratios. The
+    exchange bytes this layout implies are the ``exchange`` section's
+    ``modelled`` entry of the same report."""
     per_mode = {}
     for d, part in enumerate(plan.modes):
         nnz = np.asarray(part.nnz_true, np.float64)
         slots = np.asarray(part.blocks_true, np.float64) * part.block_p
+        rows = max(int(plan.shape[d]), 1)
+        input_rows = [p.padded_rows for w, p in enumerate(plan.modes)
+                      if w != d]
         per_mode[d] = {
             "nnz_max_over_mean": float(nnz.max() / max(nnz.mean(), 1.0)),
             "slots_max_over_mean": float(slots.max() / max(slots.mean(), 1.0)),
-            "padded_rows_over_rows":
-                part.padded_rows / max(int(plan.shape[d]), 1),
+            "padded_rows_over_rows": part.n_groups * part.rows_max / rows,
+            "factor_rows_over_rows": part.padded_rows / rows,
+            "ec_input_bytes": kops.ref_input_bytes(input_rows, rank),
+            "ec_loop": kops.ref_takes_loop(input_rows, rank),
         }
     return {"num_devices": int(plan.num_devices), "per_mode": per_mode}
 
@@ -468,7 +480,7 @@ class CPSolver:
     def partition_report(self) -> dict:
         """:func:`partition_report` of the live (possibly rebalanced) plan;
         it needs no rebalancer."""
-        return partition_report(self.plan)
+        return partition_report(self.plan, self.config.rank)
 
     def exchange_report(self, *, measure: bool = True) -> dict:
         """Modelled — and, with ``measure``, HLO-measured — per-device

@@ -14,8 +14,10 @@ Mode updates are jitted with the replaced factor buffer donated (off-CPU),
 and the per-sweep fit stays a device scalar — a sweep enqueues no host sync;
 callers block only when they actually read ``state.fits``.
 
-Factor matrices live in the padded ownership layout of their mode (see
-core/partition.py); padding rows are zero and stay zero through sweeps
+Factor matrices live in the packed ownership layout of their mode (see
+core/partition.py: each group's owned rows back to back, the last group's
+pad rows at the end; the exchange packs the gathered slabs into it, see
+core/mttkrp.py); padding rows are zero and stay zero through sweeps
 (MTTKRP writes zeros there; the solve is row-wise).
 """
 from __future__ import annotations
@@ -43,7 +45,7 @@ __all__ = ["ALSState", "init_factors", "matmul", "gram", "make_mode_update",
 
 @dataclasses.dataclass
 class ALSState:
-    factors: list[jax.Array]       # per mode, padded layout, replicated
+    factors: list[jax.Array]       # per mode, packed layout, replicated
     lam: jax.Array                 # (R,) column scales
     grams: list[jax.Array]         # per mode, (R, R) = F_wᵀ F_w
     sweep: int = 0
@@ -52,7 +54,9 @@ class ALSState:
 
 
 def init_factors(plan: CPPlan, rank: int, seed: int = 0) -> list[jax.Array]:
-    """Random factors in padded layout; padding rows exactly zero."""
+    """Random factors in the packed ownership layout (``padded_rows`` rows,
+    global row ``i`` at ``global_to_padded[i]``); the last group's pad rows
+    exactly zero."""
     rng = np.random.default_rng(seed)
     out = []
     for w in range(plan.nmodes):
@@ -304,6 +308,6 @@ def als_sweep(plan: CPPlan, mesh: Mesh, dev_arrays: Sequence, state: ALSState,
 
 
 def unpad_factors(plan: CPPlan, factors: Sequence[jax.Array]) -> list[np.ndarray]:
-    """Padded ownership layout → global row order (I_w, R)."""
+    """Packed ownership layout → global row order (I_w, R)."""
     return [np.asarray(f)[plan.global_to_padded[w]]
             for w, f in enumerate(factors)]

@@ -9,8 +9,11 @@ Per output mode ``d``:
   3. the output factor partitions are exchanged via the configured
      :class:`repro.comm.ExchangeSpec` — XLA's native all-gather, the
      Algorithm-3 ``ring``, or the chunked double-buffered ``overlap``
-     schedule, optionally on a bf16 wire — yielding the replicated padded
-     factor for the next mode.
+     schedule, optionally on a bf16 wire — and the gathered
+     ``(n_groups * rows_max, R)`` slabs are packed to the owned rows
+     (:func:`pack_slabs`, under the same ``factor_exchange`` scope),
+     yielding the replicated factor, in its mode's packed ownership layout
+     (core/partition.py), for the next mode.
 
 Device axes: the CP mesh is (n_groups, r) named ("group", "sub"); on the
 production LM mesh the same code runs with group=("pod","data") and
@@ -36,7 +39,8 @@ from repro.obs import profiler as obs_profiler
 
 __all__ = ["DeviceArrays", "cp_mesh", "shard_plan_mode", "distributed_mttkrp",
            "make_mttkrp_fn", "shard_super_shard", "zero_partials",
-           "make_partial_mttkrp_fn", "make_streaming_finish_fn"]
+           "make_partial_mttkrp_fn", "make_streaming_finish_fn",
+           "pack_slabs"]
 
 
 @jax.tree_util.register_dataclass
@@ -167,6 +171,29 @@ def _local_ec(part_meta: dict, indices, values, local_rows, block_to_tile,
         seg_starts=seg_starts, seg_rows=seg_rows)
 
 
+def pack_slabs(x, rows_owned, rows_max: int):
+    """The exchange's gathered ``(n_groups * rows_max, ...)`` slabs in the
+    packed ownership layout: ``concat(slab_0[:owned_0], …,
+    slab_{G-2}[:owned_{G-2}], slab_{G-1})``, one static copy. With one group
+    the slab is the packed factor, and no op is emitted."""
+    n_groups = len(rows_owned)
+    if n_groups == 1:
+        return x
+    parts = [x[g * rows_max:g * rows_max + int(rows_owned[g])]
+             for g in range(n_groups - 1)]
+    parts.append(x[(n_groups - 1) * rows_max:])
+    return jnp.concatenate(parts, axis=0)
+
+
+def _exchange(merged, part, all_axes, exchange_spec):
+    """The all-gather of every device's merged slab, then the packing copy:
+    the factor exchange of one mode update, under ``factor_exchange``."""
+    with obs_profiler.device_scope("factor_exchange"):
+        out = comm.all_gather_axes(merged, all_axes,
+                                   **exchange_spec.gather_kwargs())
+        return pack_slabs(out, part.rows_owned, part.rows_max)
+
+
 def make_mttkrp_fn(
     part: ModePartition,
     mesh: Mesh,
@@ -182,9 +209,12 @@ def make_mttkrp_fn(
 ):
     """Build the jit-able distributed MTTKRP for one mode.
 
-    Returns fn(device_arrays, factors) -> replicated padded output factor
-    (padded_rows, R) f32. ``factors`` are replicated padded factor matrices
-    (one per mode; the output mode's entry is ignored).
+    Returns fn(device_arrays, factors) -> replicated output factor
+    (padded_rows, R) f32 in the packed ownership layout: the EC's
+    ``(rows_max, R)`` partial per device, the merge, the all-gather of the
+    slabs and the packing copy (:func:`pack_slabs`). ``factors`` are the
+    replicated packed factor matrices (one per mode; the output mode's
+    entry is ignored).
 
     ``variant`` selects the EC kernel (``"ref" | "blocked" | "fused"``, see
     repro.kernels.ops); ``num_buffers`` is the fused variant's DMA ring
@@ -221,10 +251,7 @@ def make_mttkrp_fn(
             merged = comm.merge_partials(
                 partial, sub_axis if part.r > 1 else None,
                 **exchange_spec.merge_kwargs())
-        with obs_profiler.device_scope("factor_exchange"):
-            out = comm.all_gather_axes(merged, all_axes,
-                                       **exchange_spec.gather_kwargs())
-        return out
+        return _exchange(merged, part, all_axes, exchange_spec)
 
     in_specs = (
         P(group_axes, sub_axis, None, None),
@@ -406,9 +433,10 @@ def make_streaming_finish_fn(
     exchange_spec: comm.ExchangeSpec | None = None,
 ):
     """Jit-able ``fn(acc) -> (padded_rows, R)``: the merge (intra-group
-    reduce-scatter for r>1) + exchange of :func:`make_mttkrp_fn`, run ONCE
-    on the accumulated super-shard partials. Same collectives, same
-    schedule, same wire dtype as the resident path."""
+    reduce-scatter for r>1) + exchange (all-gather and packing copy) of
+    :func:`make_mttkrp_fn`, run ONCE on the accumulated super-shard
+    partials. Same collectives, same schedule, same wire dtype as the
+    resident path."""
     all_axes = tuple(group_axes) + (sub_axis,)
     if exchange_spec is None:
         exchange_spec = comm.ExchangeSpec(
@@ -420,9 +448,7 @@ def make_streaming_finish_fn(
             merged = comm.merge_partials(
                 acc, sub_axis if part.r > 1 else None,
                 **exchange_spec.merge_kwargs())
-        with obs_profiler.device_scope("factor_exchange"):
-            return comm.all_gather_axes(merged, all_axes,
-                                        **exchange_spec.gather_kwargs())
+        return _exchange(merged, part, all_axes, exchange_spec)
 
     acc_spec = P(group_axes, sub_axis, None, None)
 

@@ -18,14 +18,20 @@ the paper's race-freedom invariant. On TPU we add two structural changes:
   (Patents mode 0 has 46 indices) and single hot indices (Twitch skew) that
   the paper's scheme cannot balance.
 
-Factor matrices are stored in **padded ownership layout**: mode ``w``'s factor
-has ``n_groups_w * rows_max_w`` rows, row ``g*rows_max + k`` being the
-``k``-th index owned by group ``g`` (zero rows for padding). Every tensor
-copy stores its indices pre-translated into each mode's padded layout, so EC
-is gather → multiply → segment-reduce, and the post-mode exchange is exactly
-``reduce_scatter(sub) ∘ all_gather(all)`` with no scatter/permutation on
-device. This is the FLYCOO-style "preprocessed per-mode copy" of the paper,
-minus dynamic remapping (which the paper also drops).
+Factor matrices are stored in **packed ownership layout**: group ``g``'s
+owned indices of mode ``w`` sit back to back from ``offset_g = Σ_{h<g}
+rows_owned_h`` (in increasing global order), and the last group keeps its
+whole ``rows_max`` slab, zero pad rows included, at the end. So a factor has
+``Σ_{g<G-1} rows_owned_g + rows_max`` rows, about ``I_w``; with one group it
+is the ``rows_max`` slab itself. Per device everything stays in slabs: the
+EC writes a ``(rows_max, R)`` partial in local rows, and the exchange ships
+``rows_max``-row slabs, ``reduce_scatter(sub) ∘ all_gather(all)`` with no
+scatter/permutation on device, then packs the gathered ``(n_groups *
+rows_max, R)`` slabs with one static copy
+(:func:`repro.core.mttkrp.pack_slabs`). Every tensor copy stores its indices
+pre-translated into each mode's packed layout, so EC is gather → multiply →
+segment-reduce. This is the FLYCOO-style "preprocessed per-mode copy" of the
+paper, minus dynamic remapping (which the paper also drops).
 
 This module is pure **layout construction**: the scheduling decisions — which
 group owns which index (strategy policies) and which replication factor to
@@ -58,6 +64,9 @@ __all__ = [
     "block_segment_descriptors",
     "auto_replication",
     "validate_plan",
+    "packed_offsets",
+    "packed_rows",
+    "owner_from_packed",
     "Strategy",
 ]
 
@@ -92,7 +101,8 @@ def _lcm(a: int, b: int) -> int:
 @dataclasses.dataclass(frozen=True)
 class ModeLayout:
     """The histogram-only half of one mode's partition: which group owns
-    each global index and the padded row layout. Everything here is
+    each global index and the packed row layout (group ``g``'s rows from
+    :attr:`offsets` ``[g]``, see the module docstring). Everything here is
     computable from the mode's nnz histogram alone — no nonzero data — in
     O(index space), which is what lets :mod:`repro.store` plan out-of-core
     tensors from manifest statistics without reading chunk data. The
@@ -107,8 +117,8 @@ class ModeLayout:
     tile: int
     block_p: int
     owner: np.ndarray              # (I,) int32 owner group per global index
-    global_to_padded: np.ndarray   # (I,) int64
-    padded_to_global: np.ndarray   # (n_groups*rows_max,) int64, -1 pad
+    global_to_padded: np.ndarray   # (I,) int64, packed row per index
+    padded_to_global: np.ndarray   # (padded_rows,) int64, -1 pad
     rows_owned: np.ndarray         # (n_groups,) int64
     # pad-row placement, see Layout above ("block_" prefix: on the lazy
     # StoreModePartition the bare name `layout` is the ModeLayout itself)
@@ -119,8 +129,37 @@ class ModeLayout:
         return self.rows_max // self.tile
 
     @property
+    def offsets(self) -> np.ndarray:
+        """Packed start row of each group's owned rows."""
+        return packed_offsets(self.rows_owned)
+
+    @property
     def padded_rows(self) -> int:
-        return self.n_groups * self.rows_max
+        """Rows of the packed factor (see :func:`packed_rows`)."""
+        return packed_rows(self.rows_owned, self.rows_max)
+
+
+def packed_offsets(rows_owned) -> np.ndarray:
+    """``offset_g = Σ_{h<g} rows_owned_h``: where group ``g``'s owned rows
+    start in the packed layout."""
+    rows_owned = np.asarray(rows_owned, np.int64)
+    offsets = np.zeros(rows_owned.size, np.int64)
+    np.cumsum(rows_owned[:-1], out=offsets[1:])
+    return offsets
+
+
+def packed_rows(rows_owned, rows_max: int) -> int:
+    """Rows of a packed factor: every group's owned rows back to back, and
+    the last group's whole ``rows_max`` slab at the end."""
+    return int(np.sum(np.asarray(rows_owned, np.int64)[:-1])) + rows_max
+
+
+def owner_from_packed(g2p: np.ndarray, rows_owned) -> np.ndarray:
+    """Owner group of each global index, from its packed row: the last
+    group whose offset is at or below it (an empty group shares its offset
+    with the next one and owns no row)."""
+    offsets = packed_offsets(rows_owned)
+    return (np.searchsorted(offsets, g2p, side="right") - 1).astype(np.int32)
 
 
 def mode_layout(
@@ -204,8 +243,8 @@ class ModePartition:
     tile: int
     block_p: int
     # (m, nnz_max, N) int32 — input-gather indices, translated into each
-    # mode's padded factor layout (column d holds the *global padded* output
-    # row, for reference/debug; EC uses local_rows).
+    # mode's packed factor layout (column d holds the *packed* output row,
+    # for reference/debug; EC uses local_rows).
     indices: np.ndarray
     values: np.ndarray          # (m, nnz_max) f32, 0 for padding
     local_rows: np.ndarray      # (m, nnz_max) int32 in [0, rows_max)
@@ -232,8 +271,10 @@ class ModePartition:
 
     @property
     def padded_rows(self) -> int:
-        """Rows of the padded output factor = n_groups * rows_max."""
-        return self.n_groups * self.rows_max
+        """Rows of the packed output factor, ``Σ_{g<G-1} rows_owned_g +
+        rows_max`` (the exchange's wire still carries ``n_groups *
+        rows_max``)."""
+        return packed_rows(self.rows_owned, self.rows_max)
 
     def balance_stats(self) -> dict:
         t = self.nnz_true.astype(np.float64)
@@ -249,7 +290,7 @@ class ModePartition:
 @dataclasses.dataclass(frozen=True)
 class CPPlan:
     """Preprocessing output: one partitioned copy per mode (paper §3.1),
-    plus the global↔padded row translations for every mode."""
+    plus the global↔packed row translations for every mode."""
 
     shape: tuple[int, ...]
     num_devices: int
@@ -378,19 +419,19 @@ def block_segment_descriptors(local_rows: np.ndarray, *, tile: int,
 
 
 def _layout_rows(owner: np.ndarray, n_groups: int, rows_max: int):
-    """Padded-layout row ids. Returns (global_to_padded, padded_to_global,
-    rows_owned)."""
+    """Packed-layout row ids. Returns (global_to_padded, padded_to_global,
+    rows_owned). Group-major, index-minor order puts each group's owned
+    indices back to back from its offset, so an index's packed row is its
+    position in that order; the rows past the last owned index are the last
+    group's pad rows."""
     n_idx = owner.size
     order = np.argsort(owner, kind="stable")        # group-major, index-minor
-    rows_owned = np.bincount(owner, minlength=n_groups)
-    start = np.zeros(n_groups, np.int64)
-    start[1:] = np.cumsum(rows_owned)[:-1]
-    rank_in_group = np.arange(n_idx) - start[owner[order]]
+    rows_owned = np.bincount(owner, minlength=n_groups).astype(np.int64)
     g2p = np.empty(n_idx, np.int64)
-    g2p[order] = owner[order].astype(np.int64) * rows_max + rank_in_group
-    p2g = np.full(n_groups * rows_max, -1, np.int64)
-    p2g[g2p] = np.arange(n_idx)
-    return g2p.astype(np.int64), p2g, rows_owned.astype(np.int64)
+    g2p[order] = np.arange(n_idx)
+    p2g = np.full(packed_rows(rows_owned, rows_max), -1, np.int64)
+    p2g[:n_idx] = order
+    return g2p, p2g, rows_owned
 
 
 def partition_mode(
@@ -432,7 +473,7 @@ def partition_mode(
             else np.zeros(t.nnz, np.int32)
         nz_padded_row = g2p[out_idx] if owner.size \
             else np.zeros(t.nnz, np.int64)
-        # sort nonzeros by (group, padded row) → contiguous group runs,
+        # sort nonzeros by (group, packed row) → contiguous group runs,
         # row-sorted
         order = np.lexsort((nz_padded_row, nz_group))
         nz_group, nz_padded_row = nz_group[order], nz_padded_row[order]
@@ -461,9 +502,10 @@ def partition_mode(
         n_tiles = rows_max // tile
         nmodes = t.nmodes
         dev_rows, dev_vals, dev_inds, dev_b2t = [], [], [], []
+        offsets = packed_offsets(rows_owned)
         for dev, sel in enumerate(dev_lists_idx):
             g = dev // r
-            lrow = (nz_padded_row[sel] - g * rows_max).astype(np.int64)
+            lrow = (nz_padded_row[sel] - offsets[g]).astype(np.int64)
             rows_b, vals_b, inds_b, b2t_b = block_device_rows(
                 lrow, val_sorted[sel], ind_sorted[sel],
                 n_tiles=n_tiles, tile=tile, block_p=block_p, layout=layout)
@@ -501,7 +543,7 @@ def partition_mode(
             visited[dev, b2t_arr[dev]] = 1.0
 
     with obs_trace.span("plan.translate", **span_kw):
-        # translate input-mode indices into padded layouts
+        # translate input-mode indices into packed layouts
         if all_g2p is not None:
             for w in range(nmodes):
                 if w == mode:

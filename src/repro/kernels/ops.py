@@ -37,6 +37,7 @@ from repro.kernels.mttkrp_sorted import ec_sorted
 
 __all__ = ["mttkrp_local", "default_interpret", "resolve_variant",
            "kernel_kwargs_from_config", "variant_vmem_bytes",
+           "ref_input_bytes", "ref_takes_loop",
            "KERNEL_VARIANTS", "ENV_VARIANT", "DEFAULT_VARIANT",
            "DEFAULT_NUM_BUFFERS"]
 
@@ -140,6 +141,18 @@ REF_LOOP_BLOCKS = 128
 REF_CHUNK_BYTES = 512 << 20
 
 
+def ref_input_bytes(input_rows: Sequence[int], rank: int) -> int:
+    """Bytes of the ``ref`` EC's input factors as it weighs them against
+    ``REF_VMEM_FACTOR_BYTES``: their rows, each lane-padded to 128 floats."""
+    return sum(int(n) for n in input_rows) * tl.round_up(rank, tl.LANES) * 4
+
+
+def ref_takes_loop(input_rows: Sequence[int], rank: int) -> bool:
+    """Whether the ``ref`` EC runs its chunks in the VMEM loop, for input
+    factors of ``input_rows`` rows at ``rank``."""
+    return ref_input_bytes(input_rows, rank) <= REF_VMEM_FACTOR_BYTES
+
+
 def _ref_tiles(indices, values, local_rows, factors, mode, tile, block_p):
     """The slots' products summed within each block into the block's tile:
     ``(nblocks, tile, R)``."""
@@ -180,8 +193,7 @@ def _run_ref(indices, values, local_rows, block_to_tile, factors, *,
     rank = factors[0].shape[-1]
     row_bytes = tl.round_up(rank, tl.LANES) * 4
     inputs = [w for w in range(len(factors)) if w != mode]
-    if sum(factors[w].shape[0] for w in inputs) * row_bytes \
-            <= REF_VMEM_FACTOR_BYTES:
+    if ref_takes_loop([factors[w].shape[0] for w in inputs], rank):
         chunk, unroll = REF_LOOP_BLOCKS, False
     else:
         chunk = max(1, REF_CHUNK_BYTES // (len(inputs) * block_p * row_bytes))

@@ -158,6 +158,7 @@ def run_cp_cell(*, multi_pod: bool, profile: str = "amazon",
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core import mttkrp as dm
+    from repro.core.partition import packed_rows
     from repro.sparse.io import DATASET_PROFILES
 
     prof = DATASET_PROFILES[profile]
@@ -176,13 +177,21 @@ def run_cp_cell(*, multi_pod: bool, profile: str = "amazon",
     tile, block_p = 8, 128
     # balanced-partition shapes: nnz evenly split (CDF split ⇒ ±1 index)
     nnz_dev = int(np.ceil(prof.nnz / total / block_p) * block_p)
-    rows_max = int(np.ceil(prof.shape[mode] / g / tile) * tile)
-    rows_max = int(np.ceil(rows_max / r) * r)
+
+    def balanced(size):
+        """(rows_max, rows_owned) of ``size`` indices split evenly over
+        the groups, and the packed factor's rows."""
+        rmax = int(np.ceil(size / g / tile) * tile)
+        rmax = int(np.ceil(rmax / r) * r)
+        owned = np.full(g, size // g, np.int64)
+        owned[:size % g] += 1
+        return rmax, owned, packed_rows(owned, rmax)
+
+    rows_max, rows_owned, _ = balanced(prof.shape[mode])
     part = SimpleNamespace(mode=mode, num_devices=total, r=r, n_groups=g,
-                           rows_max=rows_max, tile=tile, block_p=block_p,
-                           nnz_max=nnz_dev)
-    padded = [int(np.ceil(s / g / tile) * tile * g) for s in prof.shape]
-    padded[mode] = rows_max * g
+                           rows_max=rows_max, rows_owned=rows_owned,
+                           tile=tile, block_p=block_p, nnz_max=nnz_dev)
+    padded = [balanced(s)[2] for s in prof.shape]
 
     def st(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt)

@@ -167,12 +167,13 @@ def exchange_bytes(part, rank: int, *, dtype_bytes: int = 4) -> int:
     """Per-device exchange volume one mode update implies (paper Alg. 3):
     the intra-group reduce-scatter of the (rows_max, R) partial for r>1
     (each member sends (r-1)/r of it) plus the all-gather of every other
-    device's owned slice of the padded output factor."""
+    device's slice of the ``n_groups * rows_max`` slabs the wire carries
+    (before they are packed)."""
     rs = 0
     if part.r > 1:
         rs = part.rows_max * rank * dtype_bytes * (part.r - 1) // part.r
     own_rows = part.rows_max // part.r if part.r > 1 else part.rows_max
-    ag = (part.padded_rows - own_rows) * rank * dtype_bytes
+    ag = (part.n_groups * part.rows_max - own_rows) * rank * dtype_bytes
     return int(rs + ag)
 
 

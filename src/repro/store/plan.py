@@ -55,7 +55,7 @@ def _device_tile_counts(cum_g: np.ndarray, b0: int, b1: int, *,
     """Per-row and per-tile true entry counts of one device.
 
     ``cum_g`` is the group's inclusive-prefix row histogram (rows_max+1,)
-    in padded-row order; the device owns ranks ``[b0, b1)`` of the group's
+    in local-row order; the device owns ranks ``[b0, b1)`` of the group's
     row-sorted nonzero run (the ``np.linspace`` split of
     ``partition_mode``)."""
     cnt = np.minimum(cum_g[1:], b1) - np.maximum(cum_g[:-1], b0)
@@ -97,11 +97,12 @@ class StoreModePartition:
         m, r, tile, block_p = (self.num_devices, self.r, self.tile,
                                self.block_p)
         n_tiles = layout.n_tiles
-        # padded-row histogram: each owned global index contributes its nnz
-        # at its padded row; pad rows stay 0
-        rh = np.zeros(layout.padded_rows, np.int64)
-        rh[layout.global_to_padded] = hist
-        runs = rh.reshape(self.n_groups, self.rows_max)
+        # per-group row histogram: each owned global index contributes its
+        # nnz at its local row (its packed row less its group's offset);
+        # pad rows stay 0
+        owner = layout.owner
+        runs = np.zeros((self.n_groups, self.rows_max), np.int64)
+        runs[owner, layout.global_to_padded - layout.offsets[owner]] = hist
         self._cum = np.zeros((self.n_groups, self.rows_max + 1), np.int64)
         np.cumsum(runs, axis=1, out=self._cum[:, 1:])
         # the linspace rank split partition_mode applies within each group
@@ -162,7 +163,7 @@ class StoreModePartition:
 
     @property
     def padded_rows(self) -> int:
-        return self.n_groups * self.rows_max
+        return self.layout.padded_rows
 
     @property
     def nmodes(self) -> int:
@@ -338,8 +339,12 @@ class StoreModePartition:
         # never reads. Unsorted stores can't skip whole chunks, so this
         # per-entry cut is what keeps an S-window sweep from paying S full
         # O(nnz log nnz) ranking passes.
-        base = g * self.rows_max
-        p2g = lay.padded_to_global[base + r_lo:base + r_hi]
+        # the group's rows start at its packed offset; past its owned rows
+        # the packed layout holds the next group's (or the last group's pad
+        # rows)
+        base = int(lay.offsets[g])
+        p2g = lay.padded_to_global[
+            base + r_lo:base + max(r_lo, min(r_hi, int(self.rows_owned[g])))]
         owned = p2g[p2g >= 0]
         if owned.size:
             glo, ghi = int(owned.min()), int(owned.max())

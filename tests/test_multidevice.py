@@ -147,3 +147,165 @@ def test_multidevice_battery():
     assert results["als_monotone"], results["als_fits"]
     assert results["elastic_resumed"], results["elastic_fits"]
     assert results["compressed_psum_ok"], results["compressed_psum_rel_err"]
+
+
+# -- packed ownership layout on 4 devices -------------------------------------
+
+PACKED_SCRIPT = r"""
+import json
+import sys
+import numpy as np
+import jax
+assert jax.device_count() == 4, jax.device_count()
+
+import repro.api as api
+from repro.core import mttkrp as M
+from repro.core.coo import random_sparse, to_dense
+from repro.store import TensorStore, write_store_from_coo
+from repro.store.plan import resident_shard_nbytes
+
+t = random_sparse((160, 60, 40), 4000, seed=5, distribution="zipf")
+dense = np.asarray(to_dense(t), np.float64)
+R = 8
+SUBS = ("ir", "jr", "kr")
+
+
+def mttkrp64(f, d):
+    ins = [w for w in range(3) if w != d]
+    return np.einsum("ijk," + ",".join(SUBS[w] for w in ins) + "->"
+                     + SUBS[d], dense, *[f[w] for w in ins])
+
+
+def sweep64(f):
+    # one ALS sweep in float64 from global-layout factors
+    f = [np.asarray(x, np.float64) for x in f]
+    grams = [x.T @ x for x in f]
+    for d in range(3):
+        v = np.prod([grams[w] for w in range(3) if w != d], axis=0)
+        fd = mttkrp64(f, d) @ np.linalg.pinv(v)
+        lam = np.linalg.norm(fd, axis=0)
+        lam = np.where(lam > 0, lam, 1.0)
+        f[d] = fd / lam
+        grams[d] = f[d].T @ f[d]
+    return f, lam
+
+
+def rel(a, b):
+    return float(np.abs(np.asarray(a) - b).max() / max(np.abs(b).max(), 1e-12))
+
+
+out = {"mttkrp": {}, "sweep": {}}
+resident = None
+for variant, repl in (("ring", 1), ("ring", 2), ("allgather", 1),
+                      ("overlap", 1), ("overlap", 2)):
+    cfg = api.paper({"rank": R, "runtime.num_devices": 4,
+                     "runtime.tol": 0.0, "exchange.variant": variant,
+                     "partition.replication": repl})
+    plan = api.plan(t, cfg)
+    with api.compile(plan, cfg) as s:
+        f0 = s.result().factors
+        errs, packed = [], True
+        for d in range(3):
+            part = plan.modes[d]
+            m = np.asarray(M.distributed_mttkrp(
+                plan, d, s.mesh, s.dev_arrays[d], s.state.factors,
+                use_kernel=False, exchange_spec=s.exchange_spec))
+            g2p = plan.global_to_padded[d]
+            packed &= (m.shape[0] == part.padded_rows
+                       < part.n_groups * part.rows_max)
+            packed &= bool((np.delete(m, g2p, axis=0) == 0).all())
+            errs.append(rel(m[g2p], mttkrp64(f0, d)))
+        s.sweep()
+        res = s.result()
+    f1, lam1 = sweep64(f0)
+    key = f"{variant}-r{repl}"
+    out["mttkrp"][key] = {"err": max(errs), "packed": bool(packed)}
+    out["sweep"][key] = max([rel(a, b) for a, b in zip(res.factors, f1)]
+                            + [rel(res.lam, lam1)])
+    if variant == "ring" and repl == 1:
+        resident = (f0, f1, lam1, res)
+
+# the streamed finish: a store-backed plan whose budget splits every mode
+# into super-shards, against the resident ring update above
+path = sys.argv[1]
+write_store_from_coo(t, path, chunk_nnz=512)
+st = TensorStore(path)
+cfg = api.paper({"rank": R, "runtime.num_devices": 4, "runtime.tol": 0.0,
+                 "exchange.variant": "ring", "partition.replication": 1})
+lazy = api.plan(st, cfg)
+nmodes = 3
+total = sum(resident_shard_nbytes(p, nmodes) for p in lazy.modes)
+floors = [st.chunk_nnz * (8 * nmodes + 4)]
+for p in lazy.modes:
+    dense_tile = int(p._dev_tc_pad.max())
+    floors.append(2 * int(max(dense_tile, p.block_p)
+                          * (4 * nmodes + 8 + 4 / p.block_p)
+                          + p.layout.n_tiles * 4 + 1))
+scfg = cfg.with_overrides({"runtime.streaming": True,
+                           "runtime.memory_budget":
+                               max(int(total * 0.3), *floors)})
+with api.compile(api.plan(st, scfg), scfg) as s:
+    multi = max(sp.num_shards for sp in s.stream_plans) > 1
+    s.sweep()
+    res = s.result()
+f0, f1, lam1, rres = resident
+out["sweep"]["streamed"] = max([rel(a, b) for a, b in zip(res.factors, f1)]
+                               + [rel(res.lam, lam1)])
+out["streamed_bitwise"] = bool(
+    multi and res.fits == rres.fits
+    and all((np.asarray(a) == np.asarray(b)).all()
+            for a, b in zip(res.factors, rres.factors))
+    and (np.asarray(res.lam) == np.asarray(rres.lam)).all())
+print("RESULTS_JSON:" + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def packed_runs(tmp_path_factory):
+    """One child with 4 virtual CPU devices runs every packed-layout path:
+    a distributed MTTKRP per mode and one ALS sweep under each exchange
+    (r = 1: 4 groups; r = 2, for the ring and the overlap: 2 groups), and
+    the streamed finish."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    store = str(tmp_path_factory.mktemp("packed") / "t.store")
+    proc = subprocess.run([sys.executable, "-c", PACKED_SCRIPT, store],
+                          env=env, capture_output=True, text=True,
+                          timeout=900)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = next(l for l in proc.stdout.splitlines()
+                if l.startswith("RESULTS_JSON:"))
+    return json.loads(line[len("RESULTS_JSON:"):])
+
+
+@pytest.mark.parametrize("variant", ["ring", "allgather", "overlap"])
+def test_packed_mttkrp_matches_the_oracle(packed_runs, variant):
+    """Each mode's distributed MTTKRP returns the packed factor (fewer rows
+    than the gathered slabs, zero pad rows) and matches the float64
+    MTTKRP of the dense tensor."""
+    rows = {k: v for k, v in packed_runs["mttkrp"].items()
+            if k.startswith(variant + "-")}
+    assert rows
+    for key, row in rows.items():
+        assert row["packed"], (key, row)
+        assert row["err"] < 1e-5, (key, row)
+
+
+@pytest.mark.parametrize("case", ["ring", "allgather", "overlap",
+                                  "streamed"])
+def test_packed_sweep_matches_the_oracle(packed_runs, case):
+    """One ALS sweep on the packed layout: factors and λ match a float64
+    sweep from the same initial factors."""
+    errs = {k: v for k, v in packed_runs["sweep"].items()
+            if k == case or k.startswith(case + "-")}
+    assert errs
+    for key, err in errs.items():
+        assert err < 1e-4, (key, err)
+
+
+def test_streamed_finish_equals_the_resident_update(packed_runs):
+    """The streamed finish (the exchange run once on the accumulated
+    super-shard partials) packs the same slabs: fits, factors and λ equal
+    the resident update's bit for bit."""
+    assert packed_runs["streamed_bitwise"]

@@ -5,7 +5,7 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core.coo import random_sparse
 from repro.core.partition import (auto_replication, build_plan,
-                                  partition_mode)
+                                  packed_offsets, partition_mode)
 
 STRATEGIES = ["amped_cdf", "amped_lpt", "uniform_index", "equal_nnz"]
 
@@ -50,15 +50,16 @@ def test_output_rows_disjoint_across_groups(small_tensor, strategy):
 
 
 def test_local_rows_consistent(small_tensor):
-    """local_row + group offset == padded row of the output index."""
+    """local_row + group offset == packed row of the output index."""
     t = small_tensor
     part, g2p, _ = partition_mode(t, 1, 8, strategy="amped_cdf")
+    offsets = packed_offsets(part.rows_owned)
     mask = part.values != 0
     for dev in range(8):
         g = dev // part.r
         for k in np.nonzero(mask[dev])[0]:
             oi = int(part.indices[dev, k, 1])
-            assert g2p[oi] == g * part.rows_max + part.local_rows[dev, k]
+            assert g2p[oi] == offsets[g] + part.local_rows[dev, k]
 
 
 def test_blocks_tile_coherent(small_tensor):
@@ -127,13 +128,13 @@ def test_plan_cover_property(seed, strategy, repl):
         part = plan.modes[mode]
         mask = part.values != 0
         assert mask.sum() == np.count_nonzero(t.values)
-        # translated output indices land in the owning group's padded range
-        g2p = plan.global_to_padded[mode]
+        # translated output indices land in the owning group's packed range
+        offsets = packed_offsets(part.rows_owned)
         for dev in range(4):
             g = dev // part.r
             rows = part.indices[dev][mask[dev]][:, mode]
-            assert ((rows >= g * part.rows_max) &
-                    (rows < (g + 1) * part.rows_max)).all()
+            assert ((rows >= offsets[g]) &
+                    (rows < offsets[g] + part.rows_owned[g])).all()
 
 
 def test_padded_to_global_inverse(small_tensor):
@@ -192,7 +193,7 @@ def test_partition_report_of_a_four_device_plan():
     cfg = api.preset("paper", {"rank": 8, "runtime.num_devices": 4})
     t = random_sparse((300, 120, 90), 6000, seed=0, distribution="zipf")
     plan = api.plan(t, cfg)
-    rep = partition_report(plan)
+    rep = partition_report(plan, 8)
     assert rep["num_devices"] == 4
     for d, mode in rep["per_mode"].items():
         part = plan.modes[d]
@@ -201,3 +202,102 @@ def test_partition_report_of_a_four_device_plan():
         assert mode["slots_max_over_mean"] >= 1.0
         assert mode["nnz_max_over_mean"] == pytest.approx(
             part.nnz_true.max() / part.nnz_true.mean())
+
+
+# -- packed ownership layout --------------------------------------------------
+
+def _zipf_hist(size, a=1.1, total=200_000):
+    """An nnz histogram whose heavy rows follow Zipf(a), seeded."""
+    rng = np.random.default_rng(0)
+    draws = rng.zipf(a, total)
+    return np.bincount(draws[draws <= size] - 1,
+                       minlength=size).astype(np.int64)
+
+
+@pytest.mark.parametrize("devices,replication", [(1, 1), (4, 4)])
+def test_packed_layout_of_one_group_is_the_slab(devices, replication):
+    """With one group the packed layout is the slab layout: every index at
+    its own row, and the factor is the group's ``rows_max`` slab."""
+    from repro.core.partition import mode_layout
+    hist = _zipf_hist(1000)
+    lay = mode_layout(hist, 0, devices, replication=replication)
+    assert lay.n_groups == 1
+    np.testing.assert_array_equal(lay.global_to_padded, np.arange(1000))
+    assert lay.padded_rows == lay.rows_max
+    np.testing.assert_array_equal(lay.padded_to_global[:1000],
+                                  np.arange(1000))
+    assert (lay.padded_to_global[1000:] == -1).all()
+
+
+def test_packed_factor_rows_on_a_zipf_cdf_split():
+    """CDF ownership of a Zipf(1.1) mode over 4 groups: the last group owns
+    most rows, so the slabs the wire carries are several times the rows,
+    while the packed factor holds at most the rows plus the last group's
+    pad rows."""
+    from repro.core.partition import mode_layout
+    size = 20_000
+    lay = mode_layout(_zipf_hist(size), 0, 4, strategy="amped_cdf",
+                      replication=1)
+    assert lay.n_groups == 4
+    assert lay.padded_rows <= size + lay.rows_max - lay.rows_owned[-1]
+    assert lay.n_groups * lay.rows_max > 2 * lay.padded_rows
+    np.testing.assert_array_equal(
+        lay.offsets, np.concatenate([[0], np.cumsum(lay.rows_owned)[:-1]]))
+
+
+@pytest.mark.parametrize("strategy", ["amped_cdf", "amped_lpt"])
+def test_packing_the_slabs_puts_every_row_at_its_packed_row(strategy):
+    """Slabs of ``rows_max`` rows per group, each row at its local row (as
+    the exchange gathers them), packed by ``mttkrp.pack_slabs``: every
+    global row lands where ``global_to_padded`` says, the pad rows stay
+    zero, and the owner groups are recovered from the packed rows."""
+    from repro.core.mttkrp import pack_slabs
+    from repro.core.partition import mode_layout, owner_from_packed
+    size = 3000
+    lay = mode_layout(_zipf_hist(size), 0, 4, strategy=strategy,
+                      replication=1)
+    g2p, owner = lay.global_to_padded, lay.owner
+    vals = np.arange(1, size + 1, dtype=np.float32)[:, None] * [1.0, -1.0]
+    slabs = np.zeros((lay.n_groups * lay.rows_max, 2), np.float32)
+    slabs[owner * lay.rows_max + g2p - lay.offsets[owner]] = vals
+    packed = np.asarray(pack_slabs(slabs, lay.rows_owned, lay.rows_max))
+    assert packed.shape == (lay.padded_rows, 2)
+    np.testing.assert_array_equal(packed[g2p], vals)
+    assert (packed[lay.padded_to_global < 0] == 0).all()
+    np.testing.assert_array_equal(owner_from_packed(g2p, lay.rows_owned),
+                                  owner)
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_partition_report_reads_the_packed_factors(devices):
+    """The report's ``factor_rows_over_rows`` and ``ec_loop`` on a 4-device
+    and a 1-device plan at rank 32: packed factors hold about their true
+    rows where the wire carries several times them, and the ``ref`` EC
+    loops exactly where its input factors' lane-padded bytes fit
+    ``REF_VMEM_FACTOR_BYTES`` (mode 0 reads two small factors; modes 1 and
+    2 read mode 0's 250,000 rows, 128 MB)."""
+    from repro import api
+    from repro.api.solver import partition_report
+    from repro.kernels import ops as kops
+    cfg = api.preset("paper", {"rank": 32, "runtime.num_devices": devices})
+    t = random_sparse((250_000, 300, 200), 6000, seed=0, distribution="zipf")
+    plan = api.plan(t, cfg)
+    rep = partition_report(plan, 32)
+    loops = []
+    for d, mode in rep["per_mode"].items():
+        part = plan.modes[d]
+        assert mode["factor_rows_over_rows"] == part.padded_rows / t.shape[d]
+        slack = (part.rows_max - part.rows_owned[-1]) / t.shape[d]
+        assert mode["factor_rows_over_rows"] <= 1.0 + slack
+        if devices == 1:
+            assert mode["factor_rows_over_rows"] == \
+                mode["padded_rows_over_rows"]
+        else:
+            assert mode["factor_rows_over_rows"] < \
+                mode["padded_rows_over_rows"]
+        inputs = [p.padded_rows for w, p in enumerate(plan.modes) if w != d]
+        assert mode["ec_input_bytes"] == sum(inputs) * 128 * 4
+        assert mode["ec_loop"] == (mode["ec_input_bytes"]
+                                   <= kops.REF_VMEM_FACTOR_BYTES)
+        loops.append(mode["ec_loop"])
+    assert loops == [True, False, False]

@@ -1,6 +1,7 @@
 """Plan serialization: save_plan/load_plan round-trip every ModePartition
 array bit-exactly, and stale-signature plans are rejected, never silently
 reused."""
+import dataclasses
 import json
 import os
 
@@ -87,3 +88,60 @@ def test_corrupted_cache_entry_rebuilds(tmp_path, small_tensor=None):
     # and the rewritten entry is valid again
     api.plan(t, cfg, cache_dir=str(tmp_path))
     assert api.CACHE_STATS["hits"] == 1
+
+
+@pytest.fixture(scope="module")
+def packed_store(tmp_path_factory):
+    from repro.store import TensorStore, write_store_from_coo
+    t = random_sparse((400, 120, 90), 6000, seed=3, distribution="zipf")
+    path = str(tmp_path_factory.mktemp("packed") / "t.store")
+    write_store_from_coo(t, path, chunk_nnz=1024)
+    return TensorStore(path)
+
+
+@pytest.mark.parametrize("lazy", [False, True])
+def test_packed_four_device_plan_recovers_owners(packed_store, lazy,
+                                                 tmp_path):
+    """A 4-device plan in the packed layout (CDF over 4 groups) survives
+    save_plan/load_plan: the row translations and rows owned round-trip,
+    and the owner of every index is recovered from its packed row and the
+    groups' offsets (a store-backed plan rebuilds its layouts from them)."""
+    from repro.core.partition import mode_layout, owner_from_packed
+    from repro.store import build_plan_from_store
+    plan = build_plan_from_store(packed_store, 4, replication=1)
+    if not lazy:
+        plan = dataclasses.replace(
+            plan, modes=tuple(p.materialize() for p in plan.modes))
+    back = api.load_plan(api.save_plan(plan, str(tmp_path / "p")))
+    for d in range(3):
+        lay = mode_layout(packed_store.mode_histogram(d), d, 4,
+                          replication=1)
+        part = back.modes[d]
+        assert part.n_groups == 4
+        assert part.padded_rows < part.n_groups * part.rows_max
+        np.testing.assert_array_equal(part.rows_owned, lay.rows_owned)
+        np.testing.assert_array_equal(back.global_to_padded[d],
+                                      lay.global_to_padded)
+        np.testing.assert_array_equal(
+            owner_from_packed(back.global_to_padded[d], part.rows_owned),
+            lay.owner)
+        if lazy:
+            np.testing.assert_array_equal(part.layout.owner, lay.owner)
+            for dev in range(4):
+                for a, b in zip(part.device_arrays(dev),
+                                plan.modes[d].device_arrays(dev)):
+                    np.testing.assert_array_equal(a, b)
+
+
+def test_format_3_plan_rejected(plan3, tmp_path):
+    """A plan written before the packed layout (format 3: translations
+    into ``n_groups * rows_max`` slabs) is refused by its version, never
+    read as packed."""
+    path = api.save_plan(plan3, str(tmp_path / "p"))
+    mpath = os.path.join(path, "manifest.json")
+    man = json.load(open(mpath))
+    assert man["format_version"] == 4
+    man["format_version"] = 3
+    json.dump(man, open(mpath, "w"))
+    with pytest.raises(api.PlanSignatureError, match="format 3, expected 4"):
+        api.load_plan(path)

@@ -7,7 +7,7 @@ import pytest
 
 import repro.api as api
 from repro.core.coo import SparseTensor, random_sparse
-from repro.core.partition import build_plan
+from repro.core.partition import build_plan, packed_offsets
 from repro.schedule import cost as cost_mod
 from repro.schedule import static as static_mod
 from repro.schedule.rebalance import (ReplanDecision, apply_rebalance,
@@ -229,12 +229,14 @@ def test_apply_rebalance_preserves_semantics():
     # exact cover: same nonzero multiset, just redistributed
     assert _nonzero_multiset(new_part) == _nonzero_multiset(part)
     # ownership untouched: every entry still lands in its group's row range
+    # (its owned rows of the packed layout)
     mask = new_part.values != 0
+    offsets = packed_offsets(new_part.rows_owned)
     for dev in range(4):
         g = dev // new_part.r
         rows = new_part.indices[dev][mask[dev]][:, 0]
-        assert ((rows >= g * new_part.rows_max) &
-                (rows < (g + 1) * new_part.rows_max)).all()
+        assert ((rows >= offsets[g]) &
+                (rows < offsets[g] + new_part.rows_owned[g])).all()
     # blocking contract: no block straddles a tile
     p, tile = new_part.block_p, new_part.tile
     for dev in range(4):

@@ -217,6 +217,39 @@ def test_partition_bit_identity(dup_tensor, dup_store, m, strategy, repl):
             np.testing.assert_array_equal(dr, a.local_rows[dev])
 
 
+@pytest.mark.parametrize("strategy,repl", [("amped_cdf", 1),
+                                           ("amped_cdf", 2),
+                                           ("amped_lpt", 1)])
+def test_lazy_plan_has_the_packed_layout(dup_tensor, dup_store, strategy,
+                                         repl):
+    """A store-built lazy plan lays each mode out exactly as the in-memory
+    plan does in the packed ownership layout: same owners, offsets, packed
+    factor rows and translations, fewer rows than the slabs the wire
+    carries, and each group's local rows at its packed rows less its
+    offset."""
+    from repro.core.partition import mode_layout
+    pm = build_plan(dup_tensor, 4, strategy=strategy, replication=repl)
+    ps = build_plan_from_store(dup_store, 4, strategy=strategy,
+                               replication=repl)
+    for d in range(3):
+        lay = ps.modes[d].layout
+        ref = mode_layout(dup_tensor.mode_histogram(d), d, 4,
+                          strategy=strategy, replication=repl)
+        np.testing.assert_array_equal(lay.owner, ref.owner)
+        np.testing.assert_array_equal(lay.offsets, ref.offsets)
+        assert lay.padded_rows == ref.padded_rows == pm.modes[d].padded_rows \
+            == ps.modes[d].padded_rows < lay.n_groups * lay.rows_max
+        np.testing.assert_array_equal(lay.global_to_padded,
+                                      pm.global_to_padded[d])
+        part = pm.modes[d]
+        for dev in range(4):
+            g = dev // part.r
+            real = part.values[dev] != 0
+            np.testing.assert_array_equal(
+                part.indices[dev][real][:, d],
+                lay.offsets[g] + part.local_rows[dev][real])
+
+
 def test_whole_array_access_guarded(dup_store):
     part = build_plan_from_store(dup_store, 4).modes[0]
     for field in ("indices", "values", "local_rows"):

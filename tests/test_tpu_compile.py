@@ -325,3 +325,86 @@ def test_four_chip_exchange_collectives_are_scoped_for_v5e(one_chip, mode):
         m = re.search(r'op_name="([^"]*)"', ln)
         assert m and re.search(r"(^|/)(factor_exchange|merge)(/|$)",
                                m.group(1)), ln
+
+
+AMAZON_4CHIP = (192848, 70971, 72207)   # amazon-4chip.resident's mode sizes
+
+
+@pytest.fixture(scope="module")
+def amazon_4chip_plan():
+    """A paper-preset 4-device plan at amazon-4chip's mode sizes (a Zipf
+    sample of the nonzeros: CDF ownership leaves most rows to the last
+    group, as in the cell) and its partition report at rank 32."""
+    from repro import api
+    from repro.api.solver import partition_report
+    from repro.core.coo import random_sparse
+    cfg = api.preset("paper", {"rank": 32, "runtime.num_devices": 4})
+    t = random_sparse(AMAZON_4CHIP, 200_000, seed=0, distribution="zipf")
+    plan = api.plan(t, cfg)
+    return cfg, plan, partition_report(plan, 32)
+
+
+@pytest.mark.parametrize("mode", [0, 1, 2])
+def test_amazon_4chip_packed_factors_steer_the_ec_for_v5e(
+        one_chip, amazon_4chip_plan, mode):
+    """A mode update of the 4-chip cell compiled for a v5e 2x2 on the
+    packed factors: mode 0's inputs (73 MB) fit the ``ref`` EC's VMEM loop,
+    so its EC is a ``while``; modes 1 and 2 read mode 0's 98.7 MB factor
+    (135 MB together) and run unrolled chunks. Every collective carries
+    ``factor_exchange`` or ``merge``, and the packing copy
+    (``pack_slabs``' concatenate) sits under ``factor_exchange``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import comm
+    from repro.core import als, mttkrp
+    from repro.kernels import ops as kops
+    topo, _ = one_chip
+    cfg, plan, report = amazon_4chip_plan
+    loop = mode == 0
+    assert report["per_mode"][mode]["ec_loop"] == loop
+    assert report["per_mode"][mode]["factor_rows_over_rows"] <= 1.001
+    mesh = mttkrp.cp_mesh(4, 1, devices=np.asarray(topo.devices[:4]))
+    part = plan.modes[mode]
+    # more blocks than one unrolled chunk holds, so modes 1-2 show two
+    nblocks = kops.REF_CHUNK_BYTES // (2 * 128 * 512) + 904
+    nnz = nblocks * part.block_p
+    grid = ("group", "sub")
+
+    def sharded(shape, dtype, trailing):
+        return jax.ShapeDtypeStruct(
+            (4, 1) + shape, dtype,
+            sharding=NamedSharding(mesh, P(*grid, *([None] * trailing))))
+
+    dev = mttkrp.DeviceArrays(
+        indices=sharded((nnz, 3), jnp.int32, 2),
+        values=sharded((nnz,), jnp.float32, 1),
+        local_rows=sharded((nnz,), jnp.int32, 1),
+        block_to_tile=sharded((nblocks,), jnp.int32, 1),
+        tile_visited=sharded((part.rows_max // part.tile,), jnp.float32, 1),
+        seg_starts=sharded((nblocks, part.tile + 2), jnp.int32, 2),
+        seg_rows=sharded((nblocks, part.tile + 1), jnp.int32, 2))
+    rep = NamedSharding(mesh, P())
+    facs = [jax.ShapeDtypeStruct((m.padded_rows, 32), jnp.float32,
+                                 sharding=rep) for m in plan.modes]
+    grams = [jax.ShapeDtypeStruct((32, 32), jnp.float32, sharding=rep)] * 3
+    spec = comm.resolve_exchange_spec(cfg.exchange, plan=plan, rank=32,
+                                      mesh=mesh)
+    update = als.make_mode_update(
+        plan, mode, mesh, **cfg.kernel.mttkrp_kwargs(nmodes=3, rank=32),
+        exchange_spec=spec)
+    others = [facs[w] for w in range(3) if w != mode]
+    text = update.lower(facs[mode], dev, others, grams).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    assert ("jit(update)/shard_map/ec_local/while" in op_names) == loop
+    block_scatters = [d[-1] for d in _operand_dims(text, "scatter")
+                      if d[-1] and d[-1][1:] == [part.tile, 32]]
+    chunk = kops.REF_LOOP_BLOCKS if loop else nblocks - 904
+    assert max(d[0] for d in block_scatters) == chunk, block_scatters
+    assert loop or len(block_scatters) >= 2, block_scatters
+    for ln in text.splitlines():
+        if _COLLECTIVE_RE.search(ln):
+            m = re.search(r'op_name="([^"]*)"', ln)
+            assert m and re.search(r"(^|/)(factor_exchange|merge)(/|$)",
+                                   m.group(1)), ln
+    packing = [n for n in op_names if n.endswith("/concatenate")]
+    assert packing and all(n.endswith("/factor_exchange/concatenate")
+                           for n in packing), packing
