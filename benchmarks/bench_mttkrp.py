@@ -263,7 +263,7 @@ def bench_exchange_overlap(*, nnz: int = 40000, sweeps: int = 6,
     forced host devices in its own subprocess. Derived fields are recorded,
     not asserted (a noisy wall-clock must not lose the artifact): CI gates
     on ``overlap_not_slower`` / ``bf16_*``; the deterministic bit-equality
-    assertions live in tests/test_exchange.py."""
+    assertions live in tests/test_comm_exchange.py."""
     result = run_subprocess_bench(
         EXCHANGE_SCRIPT.format(nnz=nnz, sweeps=sweeps, repeats=repeats),
         devices=4)

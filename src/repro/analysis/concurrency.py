@@ -1,7 +1,7 @@
 """AST-based lock-discipline lint (the ``AC-*`` pass).
 
 Annotation convention enforced over the thread-using runtime modules
-(``sparse/stream.py``, ``serve/batcher.py``, ``training/checkpoint.py``):
+(``sparse/stream.py``, ``serve/batcher.py``, ``api/checkpoint.py``):
 
 - ``self.attr = ...  # guarded-by: _lock`` on the assignment line declares
   ``self.attr`` guarded by ``self._lock``. Every later read or write of
@@ -33,7 +33,7 @@ __all__ = ["DEFAULT_TARGETS", "lint_file", "lint_source",
 
 # repo-relative module files the CI sweep lints by default
 DEFAULT_TARGETS = ("sparse/stream.py", "serve/batcher.py",
-                   "training/checkpoint.py", "obs/metrics.py",
+                   "api/checkpoint.py", "obs/metrics.py",
                    "obs/trace.py")
 
 _GUARDED_RE = re.compile(r"#\s*guarded-by:\s*([A-Za-z_]\w*)")

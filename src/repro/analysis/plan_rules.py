@@ -20,7 +20,7 @@ AP-P007     error     streaming window byte model vs memory_budget
                       (densest-tile floor, coverage, resident bound)
 AP-P008     warning   autotune cache v3 key hygiene
 AP-P009     error     exchange spec resolvable for this plan/config
-AP-C001     error     configs/ module not on the explicit allowlist
+AP-C001     error     configs/ module that is not a decompose config
 ==========  ========  ==============================================
 
 O(nnz) rules (AP-P003/4/5) run eagerly on in-memory plans; on lazy
@@ -40,22 +40,15 @@ import numpy as np
 from repro.analysis.model import Finding
 
 __all__ = ["PLAN_RULES", "RuleContext", "check_plan", "check_autotune_cache",
-           "check_config_modules", "SEED_MODEL_CONFIGS",
-           "DEFAULT_VMEM_BUDGET"]
+           "check_config_modules", "DEFAULT_VMEM_BUDGET"]
 
 # Pallas-kernel scratch budget the VMEM model is checked against (one TPU
 # core's VMEM, the tightest target we lower for).
 DEFAULT_VMEM_BUDGET = 16 * 2 ** 20
 
-# Seed-scaffold LLM architecture modules under repro/configs — exercised by
-# the dry-run shape tests but NOT part of the decomposition analysis sweep.
-# Anything in configs/ that is neither here nor a known decompose config is
-# an AP-C001 error: new modules must be classified, not silently skipped.
-SEED_MODEL_CONFIGS = frozenset({
-    "gemma2_9b", "nemotron4_340b", "granite_8b", "gemma3_1b",
-    "jamba15_large", "rwkv6_7b", "whisper_small", "deepseek_v2_lite",
-    "phi35_moe", "llama32_vision_90b",
-})
+# The decompose configs under repro/configs. Any other module there is an
+# AP-C001 error: a new config must be named here, not silently skipped by
+# the analysis sweep.
 _DECOMPOSE_CONFIGS = frozenset({"amped_paper"})
 
 
@@ -349,9 +342,9 @@ def _check_exchange(ctx) -> Iterable[Finding]:
 
 
 def check_config_modules(configs_dir: Optional[str] = None) -> list[Finding]:
-    """AP-C001: every module under ``repro/configs`` must be classified —
-    a decompose config or an allowlisted seed LLM scaffold. New files fail
-    loudly instead of being silently skipped by the sweep."""
+    """AP-C001: every module under ``repro/configs`` must be a decompose
+    config. New files fail loudly instead of being silently skipped by the
+    sweep."""
     if configs_dir is None:
         import repro.configs
         configs_dir = os.path.dirname(repro.configs.__file__)
@@ -360,12 +353,11 @@ def check_config_modules(configs_dir: Optional[str] = None) -> list[Finding]:
         stem, ext = os.path.splitext(name)
         if ext != ".py" or stem == "__init__":
             continue
-        if stem in SEED_MODEL_CONFIGS or stem in _DECOMPOSE_CONFIGS:
+        if stem in _DECOMPOSE_CONFIGS:
             continue
         findings.append(Finding(
             "AP-C001", "error",
-            f"configs/{name} is neither a decompose config nor on the "
-            f"seed-model allowlist; classify it in "
+            f"configs/{name} is not a decompose config; name it in "
             f"repro.analysis.plan_rules", f"configs/{name}"))
     return findings
 

@@ -6,7 +6,7 @@ Design targets (1000+ node deployments):
   * **verified** — SHA-256 per array file recorded in a manifest; restore
     skips checkpoints that fail verification (torn writes, bad disks) and
     falls back to the previous one;
-  * **async** — saves run on a background thread off the training loop
+  * **async** — saves run on a background thread off the solver's sweep loop
     (double-buffered: at most one save in flight, next save waits);
   * **bounded** — keep-latest-k retention;
   * **elastic** — checkpoints store flat numpy arrays keyed by path, so a
@@ -130,7 +130,7 @@ class CheckpointManager:
     def wait(self) -> None:
         """Join any in-flight async save. Re-raises the exception the save
         thread hit, if any — without this a failed async save would be
-        silently dropped and the training loop would believe the
+        silently dropped and the solver would believe the
         checkpoint exists. Idempotent; a raised exception is cleared (the
         next wait() does not re-raise it)."""
         if self._thread is not None:

@@ -23,7 +23,7 @@ rebalance points remain fully asynchronous.
 
 The solver is deliberately *not* serializable — that's the plan's job
 (:mod:`repro.api.planning`) plus the checkpoint manager's
-(:mod:`repro.training.checkpoint`). ``checkpoint()``/``restore()`` store
+(:mod:`repro.api.checkpoint`). ``checkpoint()``/``restore()`` store
 GLOBAL-layout factors, so a checkpoint taken by a solver compiled for m
 devices restores into one compiled for m' devices (elastic re-pad into the
 new plan's packed ownership layout).
@@ -217,7 +217,7 @@ class CPSolver:
                 member_nnz_caps=member_caps)
         self._ckpt_mgr = None
         if config.runtime.checkpoint_dir is not None:
-            from repro.training.checkpoint import CheckpointManager
+            from repro.api.checkpoint import CheckpointManager
             self._ckpt_mgr = CheckpointManager(config.runtime.checkpoint_dir)
         self.metrics.register_provider("overlap", self.overlap_report)
         self.metrics.register_provider("imbalance", self.imbalance_report)

@@ -13,6 +13,8 @@ Everything that moves factor partitions between devices lives here:
 * :mod:`repro.comm.autotune` — chunk-size autotuner for the overlap
   variant, sharing the EC autotuner's JSON cache.
 * :mod:`repro.comm.volume` — modelled vs HLO-measured exchange volume.
+* :mod:`repro.comm.hlo` — the loop-weighted HLO-text parser behind the
+  measured volume and the dry run's roofline.
 
 ``repro.core.exchange`` is a thin backwards-compatibility shim over this
 package.

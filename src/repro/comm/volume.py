@@ -14,14 +14,16 @@ The model follows the canonical ring formulas (the paper's §4.9 cost):
 With a bf16 wire both terms halve — exactly the ratio the launcher and the
 ``exchange_overlap`` benchmark assert between modelled fp32 and bf16 runs.
 
-The *measured* side parses a compiled computation's HLO with the roofline
-collective parser (loop-weighted per-device bytes for all-gather /
+The *measured* side parses a compiled computation's HLO with
+:mod:`repro.comm.hlo` (loop-weighted per-device bytes for all-gather /
 collective-permute / reduce-scatter / all-reduce), so model drift is
 visible machine-readably instead of silently.
 """
 from __future__ import annotations
 
 import numpy as np
+
+from repro.comm.hlo import collective_bytes
 
 __all__ = ["wire_bytes", "mode_exchange_bytes", "modelled_exchange_bytes",
            "measured_exchange_bytes"]
@@ -71,9 +73,8 @@ def modelled_exchange_bytes(plan, rank: int, *,
 
 def measured_exchange_bytes(hlo_text: str) -> dict:
     """Per-device exchange bytes measured from compiled HLO (loop-weighted,
-    via :func:`repro.launch.roofline.collective_bytes`), split by collective
+    via :func:`repro.comm.hlo.collective_bytes`), split by collective
     kind plus the summed total."""
-    from repro.launch.roofline import collective_bytes
     coll = collective_bytes(hlo_text)
     picked = {k: float(v) for k, v in coll.items()
               if k in EXCHANGE_COLLECTIVES}

@@ -1,3 +1,2 @@
-# The paper's primary contribution — implement the SYSTEM here
-# (scheduler, optimizer, data path, serving loop, etc.) in the
-# host framework. Add sibling subpackages for substrates.
+"""The CP core: COO tensors, partitioning, the distributed MTTKRP and the
+ALS sweep (paper Algorithms 1–3)."""

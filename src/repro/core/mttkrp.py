@@ -15,9 +15,9 @@ Per output mode ``d``:
      yielding the replicated factor, in its mode's packed ownership layout
      (core/partition.py), for the next mode.
 
-Device axes: the CP mesh is (n_groups, r) named ("group", "sub"); on the
-production LM mesh the same code runs with group=("pod","data") and
-sub="model".
+Device axes: the CP mesh is (n_groups, r) named ("group", "sub"); the
+production dry run (launch/dryrun.py) builds the same mesh over 256 or 512
+devices.
 """
 from __future__ import annotations
 

@@ -2,18 +2,22 @@ import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
-"""Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
+"""Production-mesh dry run of the CP workload: lower + compile one
+distributed MTTKRP mode step (EC + exchange) at a dataset profile's full
+billion-scale shape on 256 (one pod) or 512 (two pods) placeholder devices.
 
 MUST be run as a module (``python -m repro.launch.dryrun``) — the XLA_FLAGS
 line above executes before any other import so the host platform exposes 512
 placeholder devices for ``jax.make_mesh``. Nothing here allocates real
-arrays: parameters, optimizer state, batches and KV caches are
-ShapeDtypeStructs.
+arrays: the shards and factors are ShapeDtypeStructs.
 
 Per cell it records: compile success, ``memory_analysis()`` (fits/doesn't),
 ``cost_analysis()`` FLOPs/bytes, per-device collective bytes parsed from the
 post-SPMD HLO, and the derived three-term roofline → JSON under
-``experiments/dryrun/``.
+``experiments/dryrun/``::
+
+    python -m repro.launch.dryrun --mesh pod1 --cp-profile amazon
+    python -m repro.launch.dryrun --mesh pod1 --cp-exchange-ab
 """
 import argparse       # noqa: E402
 import dataclasses    # noqa: E402
@@ -24,80 +28,13 @@ import traceback      # noqa: E402
 import jax            # noqa: E402
 import numpy as np    # noqa: E402
 
-from repro import compat
-from repro.configs import ARCH_IDS                      # noqa: E402
+from repro import compat                                # noqa: E402
+from repro.comm.hlo import parse_hlo                    # noqa: E402
 from repro.launch import roofline as rf                 # noqa: E402
-from repro.launch.mesh import (make_cp_production_mesh,  # noqa: E402
-                               make_production_mesh)
-from repro.launch.shapes import (SHAPE_CELLS, input_specs,  # noqa: E402
-                                 supports_cell)
+from repro.launch.mesh import make_cp_production_mesh   # noqa: E402
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
-
-
-def run_cell(arch: str, cell: str, *, multi_pod: bool, remat: str | None = None,
-             microbatches: int = 1, save: bool = True,
-             keep_hlo: bool = False, kv_layout: str = "auto",
-             moe_dispatch: str | None = None,
-             tag_extra: str = "") -> dict:
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    t0 = time.time()
-    spec = input_specs(arch, cell, mesh, remat=remat,
-                       microbatches=microbatches, kv_layout=kv_layout,
-                       moe_dispatch=moe_dispatch)
-    rec: dict = {"arch": arch, "cell": cell,
-                 "mesh": list(mesh.devices.shape),
-                 "multi_pod": multi_pod, "meta": spec.meta,
-                 "remat": remat, "microbatches": microbatches,
-                 "kv_layout": kv_layout, "moe_dispatch": moe_dispatch}
-    try:
-        with mesh:
-            jitted = jax.jit(spec.fn, in_shardings=spec.in_shardings,
-                             out_shardings=spec.out_shardings)
-            lowered = jitted.lower(*spec.args)
-            t_lower = time.time() - t0
-            compiled = lowered.compile()
-            t_compile = time.time() - t0 - t_lower
-        mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)
-        try:
-            hlo = compiled.as_text()
-        except Exception:
-            hlo = lowered.as_text()
-        parsed = rf.parse_hlo(hlo)
-        coll = parsed["collectives"]
-        abytes = rf.analytic_memory_bytes(spec.meta)
-        terms = rf.roofline_terms(cost or {}, coll,
-                                  dot_flops=parsed["dot_flops"],
-                                  analytic_bytes=abytes)
-        rec.update(
-            ok=True,
-            t_lower_s=round(t_lower, 2), t_compile_s=round(t_compile, 2),
-            memory_analysis=_mem_dict(mem),
-            cost={k: cost.get(k) for k in
-                  ("flops", "bytes accessed", "optimal_seconds")
-                  if cost and k in cost},
-            collectives={k: v for k, v in sorted(coll.items())},
-            roofline=terms,
-            hlo_bytes=len(hlo),
-        )
-        if keep_hlo:
-            rec["hlo_head"] = hlo[:20000]
-        del hlo
-    except Exception as e:  # noqa: BLE001 — a failed cell is a bug, record it
-        rec.update(ok=False, error=f"{type(e).__name__}: {e}",
-                   traceback=traceback.format_exc()[-4000:])
-    if save:
-        os.makedirs(OUT_DIR, exist_ok=True)
-        tag = "pod2" if multi_pod else "pod1"
-        extra = f"_{remat}" if remat else ""
-        extra += f"_mb{microbatches}" if microbatches > 1 else ""
-        extra += tag_extra
-        path = os.path.join(OUT_DIR, f"{arch}__{cell}__{tag}{extra}.json")
-        with open(path, "w") as f:
-            json.dump(rec, f, indent=1, default=str)
-    return rec
 
 
 def _mem_dict(mem) -> dict:
@@ -238,7 +175,7 @@ def run_cp_cell(*, multi_pod: bool, profile: str = "amazon",
             compiled = lowered.compile()
         cost = compat.cost_analysis(compiled)
         hlo = compiled.as_text()
-        parsed = rf.parse_hlo(hlo)
+        parsed = parse_hlo(hlo)
         coll = parsed["collectives"]
         terms = rf.roofline_terms(cost or {}, coll,
                                   dot_flops=parsed["dot_flops"] or None)
@@ -302,13 +239,11 @@ def run_cp_exchange_ab(*, multi_pod: bool, profile: str = "amazon",
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="all",
-                    help="arch id, 'all', or 'cp' (paper workload)")
-    ap.add_argument("--shape", default="all")
+    ap = argparse.ArgumentParser(
+        description="Compile one CP mode step on the production mesh "
+                    "(256 or 512 placeholder devices) and record its "
+                    "memory, collectives and roofline.")
     ap.add_argument("--mesh", default="both", choices=["pod1", "pod2", "both"])
-    ap.add_argument("--remat", default=None)
-    ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--cp-profile", default="amazon")
     ap.add_argument("--cp-replication", type=int, default=1)
     ap.add_argument("--cp-kernel", action="store_true")
@@ -325,50 +260,27 @@ def main():
                     help="compile the CP cell under both the blocking ring "
                          "and the overlap schedule; record the HLO "
                          "comparison (collective bytes/mix per variant)")
-    ap.add_argument("--kv-layout", default="auto")
-    ap.add_argument("--moe-dispatch", default=None)
-    ap.add_argument("--tag-extra", default="")
     args = ap.parse_args()
 
     meshes = {"pod1": [False], "pod2": [True], "both": [False, True]}[args.mesh]
-    if args.arch == "cp":
-        cfg = None
-        if args.cp_preset:
-            from repro.api import preset
-            cfg = preset(args.cp_preset)
-        for mp in meshes:
-            if args.cp_exchange_ab:
-                rec = run_cp_exchange_ab(
-                    multi_pod=mp, profile=args.cp_profile,
-                    replication=args.cp_replication,
-                    use_kernel=args.cp_kernel, wire_dtype=args.cp_wire)
-                _report_ab(rec)
-                continue
-            rec = run_cp_cell(multi_pod=mp, profile=args.cp_profile,
-                              replication=args.cp_replication,
-                              use_kernel=args.cp_kernel,
-                              exchange_variant=args.cp_exchange,
-                              wire_dtype=args.cp_wire, config=cfg)
-            _report(rec)
-        return
-
-    archs = ARCH_IDS if args.arch == "all" else [args.arch]
-    cells = list(SHAPE_CELLS) if args.shape == "all" else [args.shape]
-    failures = 0
+    cfg = None
+    if args.cp_preset:
+        from repro.api import preset
+        cfg = preset(args.cp_preset)
     for mp in meshes:
-        for arch in archs:
-            for cell in cells:
-                if not supports_cell(arch, cell):
-                    continue
-                rec = run_cell(arch, cell, multi_pod=mp, remat=args.remat,
-                               microbatches=args.microbatches,
-                               kv_layout=args.kv_layout,
-                               moe_dispatch=args.moe_dispatch,
-                               tag_extra=args.tag_extra)
-                failures += 0 if rec["ok"] else 1
-                _report(rec)
-    if failures:
-        raise SystemExit(f"{failures} cells failed")
+        if args.cp_exchange_ab:
+            rec = run_cp_exchange_ab(
+                multi_pod=mp, profile=args.cp_profile,
+                replication=args.cp_replication,
+                use_kernel=args.cp_kernel, wire_dtype=args.cp_wire)
+            _report_ab(rec)
+            continue
+        rec = run_cp_cell(multi_pod=mp, profile=args.cp_profile,
+                          replication=args.cp_replication,
+                          use_kernel=args.cp_kernel,
+                          exchange_variant=args.cp_exchange,
+                          wire_dtype=args.cp_wire, config=cfg)
+        _report(rec)
 
 
 def _report_ab(rec: dict):

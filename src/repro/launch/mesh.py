@@ -1,7 +1,7 @@
-"""Production meshes.
+"""The production CP mesh of the dry run.
 
-Defined as functions (never module-level constants) so importing this module
-never touches jax device state — the dry-run sets
+Defined as a function (never a module-level constant) so importing this
+module never touches jax device state — the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first jax
 init; tests and benches see the real (single) device.
 """
@@ -9,21 +9,13 @@ from __future__ import annotations
 
 from repro import compat
 
-__all__ = ["make_production_mesh", "make_cp_production_mesh"]
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """(16,16)=("data","model") single pod; (2,16,16)=("pod","data","model")
-    for 2 pods = 512 chips."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+__all__ = ["make_cp_production_mesh"]
 
 
 def make_cp_production_mesh(*, multi_pod: bool = False, replication: int = 16):
-    """CP-ALS view of the same chips: ("group","sub") with |sub| =
-    ``replication`` (the intra-group merge axis; 1 → pure paper scheme).
-    Total devices match the production mesh (256 / 512)."""
+    """The CP-ALS mesh ("group","sub") over one pod (256 chips) or two
+    (512), with |sub| = ``replication`` (the intra-group merge axis; 1 →
+    pure paper scheme)."""
     total = 512 if multi_pod else 256
     assert total % replication == 0
     return compat.make_mesh(

@@ -1,38 +1,22 @@
-"""Three-term roofline from a compiled dry-run artifact (no hardware).
+"""Three-term roofline of a compiled dry-run artifact (no hardware).
 
     compute    = FLOPs_per_chip / peak_FLOPs          (197 TF/s bf16, v5e)
     memory     = bytes_per_chip / HBM_bw              (819 GB/s)
     collective = coll_bytes_per_chip / link_bw        (~50 GB/s/link ICI)
 
-Sources, and why each one:
-
-* **collective bytes** — parsed from the post-SPMD HLO, *weighted by while-
-  loop trip counts*: scan-over-layers lowers to `while` ops whose bodies
-  appear once in the text but execute `known_trip_count` times; a naive sum
-  (and `cost_analysis()`) undercounts in-loop collectives by ~n_layers.
-  The parser builds the computation call graph (fusion `calls=`, `to_apply=`,
-  while `body=`/`condition=` with `backend_config known_trip_count`) and
-  multiplies through nested loops. Ring-traffic factors: all-reduce 2×,
-  others 1×.
-
-* **compute FLOPs** — `dot`/`convolution` ops parsed from the same graph
-  (2·result_elems·K_contracted), loop-weighted. `cost_analysis()["flops"]`
-  is also reported (raw) but has the same once-per-loop defect.
-
-* **memory bytes** — analytic (see `analytic_memory_bytes`): parameter,
-  optimizer-state, activation and KV-cache traffic per step from the model
-  config. `cost_analysis()["bytes accessed"]` both undercounts loops and
-  overcounts fusion-boundary traffic (and the CPU backend upcasts bf16
-  dots to f32), so it is reported as auxiliary only.
+* **collective bytes** and **dot FLOPs** — loop-weighted, parsed from the
+  post-SPMD HLO by :mod:`repro.comm.hlo`; ``cost_analysis()["flops"]`` is
+  also reported (raw) but counts a loop body once.
+* **memory bytes** — ``cost_analysis()["bytes accessed"]``, which both
+  undercounts loops and overcounts fusion-boundary traffic, so the memory
+  term is a rough bound.
 """
 from __future__ import annotations
 
 import dataclasses
-import re
 from typing import Any
 
-__all__ = ["HW", "parse_hlo", "collective_bytes", "roofline_terms",
-           "analytic_memory_bytes"]
+__all__ = ["HW", "roofline_terms"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,221 +26,21 @@ class HW:
     link_bw: float = 50e9           # bytes/s per ICI link
 
 
-_DTYPE_BYTES = {
-    "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3": 1, "f8e5m2": 1,
-    "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,
-    "s8": 1, "u8": 1, "pred": 1, "c64": 8, "c128": 16,
-}
-
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-
-_COLL_KINDS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-               "ragged-all-to-all", "collective-permute")
-
-_TRAFFIC_FACTOR = {
-    "all-gather": 1.0, "all-reduce": 2.0, "reduce-scatter": 1.0,
-    "all-to-all": 1.0, "ragged-all-to-all": 1.0, "collective-permute": 1.0,
-}
-
-
-def _shape_bytes(shape_str: str) -> int:
-    total = 0
-    for dt, dims in _SHAPE_RE.findall(shape_str):
-        nb = _DTYPE_BYTES.get(dt)
-        if nb is None:
-            continue
-        n = 1
-        if dims:
-            for d in dims.split(","):
-                if d:
-                    n *= int(d)
-        total += n * nb
-    return total
-
-
-def _shape_elems(shape_str: str) -> tuple[int, list[int]]:
-    m = _SHAPE_RE.search(shape_str)
-    if not m:
-        return 0, []
-    dims = [int(d) for d in m.group(2).split(",") if d]
-    n = 1
-    for d in dims:
-        n *= d
-    return n, dims
-
-
-_OP_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w\.\-]+) = (\S+(?:\([^)]*\))?) "
-                    r"([\w\-]+)\(")
-_COMP_HDR = re.compile(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s*\((.*)\)\s*->")
-_CALLS_RE = re.compile(r"(?:calls|to_apply)=%?([\w\.\-]+)")
-_BODY_RE = re.compile(r"body=%?([\w\.\-]+)")
-_COND_RE = re.compile(r"condition=%?([\w\.\-]+)")
-_TRIP_RE = re.compile(r'known_trip_count[":{]+n[":]+(\d+)')
-_BRANCH_RE = re.compile(r"branch_computations=\{([^}]*)\}")
-_PARAM_RE = re.compile(r"%?([\w\.\-]+):\s*([^,)]+)")
-_OPERAND_RE = re.compile(r"%([\w\.\-]+)")
-_CDIMS_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
-
-
-def parse_hlo(hlo_text: str) -> dict:
-    """Loop-weighted collective bytes and dot FLOPs (see module docstring)."""
-    # --- split into computations ------------------------------------------
-    comps: dict[str, list[str]] = {}
-    entry = None
-    cur = None
-    for line in hlo_text.splitlines():
-        hdr = _COMP_HDR.match(line)
-        if hdr and line.rstrip().endswith("{"):
-            cur = hdr.group(1)
-            comps[cur] = [line]
-            if line.startswith("ENTRY"):
-                entry = cur
-            continue
-        if cur is not None:
-            comps[cur].append(line)
-            if line.strip() == "}":
-                cur = None
-    if not comps:
-        comps = {"main": hlo_text.splitlines()}
-        comps["main"].insert(0, "")  # no header line
-        entry = "main"
-    if entry is None and comps:
-        entry = next(iter(comps))
-
-    # --- per computation: direct costs + call edges ------------------------
-    info: dict[str, dict] = {}
-    for name, lines in comps.items():
-        shapes: dict[str, str] = {}
-        hdr = _COMP_HDR.match(lines[0]) if lines else None
-        if hdr:
-            for pname, ptype in _PARAM_RE.findall(hdr.group(2)):
-                shapes[pname] = ptype
-        coll: dict[str, float] = {}
-        flops = 0.0
-        edges: list[tuple[str, float]] = []
-        for line in lines[1:]:
-            m = _OP_RE.match(line)
-            if m:
-                op_name, result_shape, op = m.groups()
-                shapes[op_name] = result_shape
-                if op in _COLL_KINDS and "-done" not in line:
-                    b = _shape_bytes(result_shape) * _TRAFFIC_FACTOR[op]
-                    coll[op] = coll.get(op, 0.0) + b
-                elif op == "dot":
-                    flops += _dot_flops(line, result_shape, shapes)
-                elif op == "convolution":
-                    n, _ = _shape_elems(result_shape)
-                    flops += 2.0 * n  # lower bound; convs are stubs here
-            body = _BODY_RE.search(line)
-            if "while(" in line and body:
-                trip = 1
-                tm = _TRIP_RE.search(line)
-                if tm:
-                    trip = int(tm.group(1))
-                edges.append((body.group(1), float(trip)))
-                cm = _COND_RE.search(line)
-                if cm:
-                    edges.append((cm.group(1), float(trip)))
-            else:
-                for callee in _CALLS_RE.findall(line):
-                    edges.append((callee, 1.0))
-                br = _BRANCH_RE.search(line)
-                if br:
-                    for c in br.group(1).split(","):
-                        c = c.strip().lstrip("%")
-                        if c:
-                            edges.append((c, 1.0))
-        info[name] = {"coll": coll, "flops": flops, "edges": edges}
-
-    # --- weighted transitive totals ----------------------------------------
-    memo: dict[str, tuple[dict, float]] = {}
-
-    def total(name: str, stack=()) -> tuple[dict, float]:
-        if name in memo:
-            return memo[name]
-        if name not in info or name in stack:
-            return {}, 0.0
-        node = info[name]
-        coll = dict(node["coll"])
-        flops = node["flops"]
-        for callee, mult in node["edges"]:
-            c_coll, c_flops = total(callee, stack + (name,))
-            for k, v in c_coll.items():
-                coll[k] = coll.get(k, 0.0) + v * mult
-            flops += c_flops * mult
-        memo[name] = (coll, flops)
-        return memo[name]
-
-    coll, flops = total(entry) if entry else ({}, 0.0)
-    coll["total"] = sum(coll.values())
-    return {"collectives": coll, "dot_flops": flops}
-
-
-def _dot_flops(line: str, result_shape: str, shapes: dict[str, str]) -> float:
-    n, _ = _shape_elems(result_shape)
-    k = 1
-    ops = _OPERAND_RE.findall(line.split("dot(", 1)[1])
-    cd = _CDIMS_RE.search(line)
-    if ops and cd and ops[0] in shapes:
-        _, dims = _shape_elems(shapes[ops[0]])
-        for di in cd.group(1).split(","):
-            if di and int(di) < len(dims):
-                k *= dims[int(di)]
-    return 2.0 * n * k
-
-
-def collective_bytes(hlo_text: str) -> dict[str, float]:
-    """Back-compat wrapper: loop-weighted totals by kind."""
-    return parse_hlo(hlo_text)["collectives"]
-
-
-def analytic_memory_bytes(meta: dict) -> float:
-    """Per-chip HBM traffic model for one step.
-
-    train:   params (read fwd + read bwd + write) ×2B + grads rw ×2B +
-             adam m,v rw f32 (16B/param) + activations (residual stream,
-             ~12 floats/token/layer without remat, ~4 with)
-    prefill: params read + activations write/read (~6/token/layer) + KV write
-    decode:  params read + full KV cache read
-    All divided by chip count (tensors are sharded).
-    """
-    chips = meta.get("chips", 1)
-    p = meta.get("params", 0)
-    dt = 2.0  # bf16
-    kind = meta.get("kind")
-    seq, batch = meta.get("seq", 0), meta.get("batch", 0)
-    d = meta.get("d_model", 0)
-    layers = meta.get("n_layers", 1)
-    kv_bytes = meta.get("kv_bytes", 0.0)
-    act_scale = 4.0 if meta.get("remat") else 12.0
-    if kind == "train":
-        par = p * (3 * dt + 2 * dt + 16.0)
-        act = act_scale * batch * seq * d * layers * dt
-        return (par + act) / chips
-    if kind == "prefill":
-        par = p * dt
-        act = 6.0 * batch * seq * d * layers * dt
-        return (par + act + kv_bytes) / chips
-    # decode
-    return (p * dt + kv_bytes) / chips
-
-
 def roofline_terms(cost: dict[str, Any], coll: dict[str, float],
                    hw: HW = HW(), *, dot_flops: float | None = None,
-                   analytic_bytes: float | None = None) -> dict[str, float]:
+                   ) -> dict[str, float]:
     raw_flops = float(cost.get("flops", 0.0) or 0.0)
     raw_bytes = float(cost.get("bytes accessed", 0.0) or 0.0)
     flops = dot_flops if dot_flops else raw_flops
-    byts = analytic_bytes if analytic_bytes else raw_bytes
     cb = float(coll.get("total", 0.0))
     terms = {
         "flops_per_chip": flops,
         "raw_hlo_flops": raw_flops,
-        "bytes_per_chip": byts,
+        "bytes_per_chip": raw_bytes,
         "raw_hlo_bytes": raw_bytes,
         "coll_bytes_per_chip": cb,
         "t_compute": flops / hw.peak_flops,
-        "t_memory": byts / hw.hbm_bw,
+        "t_memory": raw_bytes / hw.hbm_bw,
         "t_collective": cb / hw.link_bw,
     }
     dom = max(("t_compute", "t_memory", "t_collective"),
@@ -266,12 +50,3 @@ def roofline_terms(cost: dict[str, Any], coll: dict[str, float],
     terms["step_time_bound"] = t_max
     terms["roofline_fraction"] = (terms["t_compute"] / t_max) if t_max > 0 else 0.0
     return terms
-
-
-def format_row(meta: dict, terms: dict) -> str:
-    return (f"{meta['arch']:<22} {meta['cell']:<12} "
-            f"C={terms['t_compute']*1e3:9.3f}ms "
-            f"M={terms['t_memory']*1e3:9.3f}ms "
-            f"X={terms['t_collective']*1e3:9.3f}ms "
-            f"dom={terms['bottleneck'][2:]:<10} "
-            f"frac={terms['roofline_fraction']:.3f}")

@@ -89,7 +89,7 @@ def main(argv=None) -> int:
     if store is not None:
         # refresh needs a solver config; rank comes from the checkpoint
         # unless pinned on the CLI
-        from repro.training.checkpoint import CheckpointManager
+        from repro.api.checkpoint import CheckpointManager
         restored = CheckpointManager(args.ckpt).restore_latest()
         if restored is None:
             print(f"error: no verified checkpoint under {args.ckpt!r}",

@@ -25,6 +25,7 @@ import time
 
 import numpy as np
 
+from repro.api.checkpoint import CheckpointManager
 from repro.api.config import DecomposeConfig
 from repro.api.solver import validate_factor_payload
 from repro.serve.batcher import MicroBatcher
@@ -33,7 +34,6 @@ from repro.serve.metrics import ServiceMetrics
 from repro.serve.refresh import (affected_row_masks, incremental_refit,
                                  sample_fit)
 from repro.store.store import TensorStore
-from repro.training.checkpoint import CheckpointManager
 
 __all__ = ["CPService"]
 

@@ -140,12 +140,12 @@ def test_ap_p009_degenerate_chunk_rows(sorted_plan, sorted_cfg):
 
 
 def test_ap_c001_config_allowlist(tmp_path):
-    (tmp_path / "gemma2_9b.py").write_text("")
     (tmp_path / "amped_paper.py").write_text("")
     assert check_config_modules(str(tmp_path)) == []
-    (tmp_path / "rogue_model.py").write_text("")
+    (tmp_path / "gemma2_9b.py").write_text("")
     found = check_config_modules(str(tmp_path))
-    assert [f.rule for f in found] == ["AP-C001"]
+    assert [(f.rule, f.location) for f in found] == [
+        ("AP-C001", "configs/gemma2_9b.py")]
     # the clean repo's own configs/ is fully classified
     assert check_config_modules() == []
 
@@ -491,7 +491,7 @@ def test_batcher_close_rejects_queued():
 
 def test_checkpoint_async_exception_surfaced(tmp_path, monkeypatch):
     # regression for the unguarded _save_exc hand-off (now _exc_lock)
-    from repro.training.checkpoint import CheckpointManager
+    from repro.api.checkpoint import CheckpointManager
     mgr = CheckpointManager(str(tmp_path), async_save=True)
     monkeypatch.setattr(mgr, "_save_sync_flat",
                         lambda *a: (_ for _ in ()).throw(IOError("disk")))
@@ -578,15 +578,3 @@ def test_cli_seeded_defect_and_baseline(tmp_path, capsys, monkeypatch):
     rc = analysis_main(args + ["--baseline", base])
     out = capsys.readouterr().out
     assert rc == 0 and "baselined" in out
-
-
-# -- serving retirement shim -------------------------------------------------
-
-def test_serving_serve_shim_warns():
-    import importlib
-    sys.modules.pop("repro.serving.serve", None)
-    with pytest.warns(DeprecationWarning, match="repro.models.lm_serve"):
-        mod = importlib.import_module("repro.serving.serve")
-    import repro.models.lm_serve as lm_serve
-    assert mod.generate is lm_serve.generate
-    assert mod.cache_specs is lm_serve.cache_specs

@@ -3,7 +3,7 @@ import os
 import numpy as np
 import pytest
 
-from repro.training.checkpoint import CheckpointManager
+from repro.api.checkpoint import CheckpointManager
 
 
 def _payload(seed=0):

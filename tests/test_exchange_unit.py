@@ -1,4 +1,4 @@
-"""Unit tests for exchange collectives + roofline analytics + profile-level
+"""Unit tests for exchange collectives + the HLO traffic parser + profile-level
 partitioner behaviour (single device; multi-device in test_multidevice)."""
 import numpy as np
 import pytest
@@ -6,10 +6,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.comm import hlo as hlo_parser
 from repro.compat import shard_map
 from repro.core import exchange
 from repro.core.partition import auto_replication
-from repro.launch import roofline as rf
 from repro.sparse.io import DATASET_PROFILES
 
 
@@ -64,26 +64,9 @@ def test_traffic_factors():
   %x1 = f32[1024]{0} all-reduce(f32[1024] %a)
   %x2 = f32[1024]{0} reduce-scatter(f32[4096] %b), dimensions={0}
 """
-    coll = rf.collective_bytes(hlo)
+    coll = hlo_parser.collective_bytes(hlo)
     assert coll["all-reduce"] == 1024 * 4 * 2.0     # ring: 2× bytes
     assert coll["reduce-scatter"] == 1024 * 4 * 1.0
-
-
-def test_analytic_memory_decode_dominated_by_kv():
-    meta = dict(chips=256, params=9_000_000_000, kind="decode",
-                seq=32768, batch=128, d_model=4096, n_layers=32,
-                kv_bytes=1.0e12, remat=False)
-    b = rf.analytic_memory_bytes(meta)
-    # params 18 GB + kv 1 TB over 256 chips ≈ 4 GB/chip
-    assert 3.5e9 < b < 4.5e9
-
-
-def test_analytic_memory_train_remat_reduces():
-    meta = dict(chips=256, params=9e9, kind="train", seq=4096, batch=256,
-                d_model=4096, n_layers=32, kv_bytes=0.0)
-    full = rf.analytic_memory_bytes({**meta, "remat": False})
-    re = rf.analytic_memory_bytes({**meta, "remat": True})
-    assert re < full
 
 
 def test_parse_hlo_nested_loops():
@@ -102,6 +85,6 @@ def test_parse_hlo_nested_loops():
     compiled = jax.jit(f).lower(
         jax.ShapeDtypeStruct((3, 5, 2, 6), jnp.float32),
         jax.ShapeDtypeStruct((6, 4), jnp.float32)).compile()
-    r = rf.parse_hlo(compiled.as_text())
+    r = hlo_parser.parse_hlo(compiled.as_text())
     # 2·2·4·6 = 96 flops per inner step × 5 inner × 3 outer = 1440
     assert r["dot_flops"] == 3 * 5 * 96.0, r

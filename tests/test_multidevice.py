@@ -1,6 +1,6 @@
 """Multi-device integration: one subprocess with 8 virtual CPU devices runs
 the full distributed battery (ring == all_gather, AMPED vs equal-nnz vs
-oracle, r>1 merges, ALS convergence, gradient-compression psum). Subprocess
+oracle, r>1 merges, ALS convergence, elastic restart). Subprocess
 keeps the main test env at 1 device per the dry-run isolation rule."""
 import json
 import os
@@ -106,24 +106,6 @@ results["elastic_fits"] = r4.fits + r8.fits[len(r4.fits):]
 results["elastic_resumed"] = bool(len(r8.fits) == 6 and
                                   r8.fits[3] >= r4.fits[-1] - 1e-3)
 
-# --- compressed psum across 8 devices ------------------------------------
-from repro.training.compression import compressed_psum_tree
-dmesh = Mesh(np.asarray(jax.devices()), ("data",))
-gs = jnp.asarray(np.random.default_rng(3).normal(size=(8, 128)).astype(np.float32))
-
-def comp(g, r):
-    out, res = compressed_psum_tree({"w": g.reshape(128)},
-                                    {"w": r.reshape(128)}, "data")
-    return out["w"], res["w"]
-
-out, _ = jax.jit(shard_map(comp, mesh=dmesh,
-                               in_specs=(P("data"), P("data")),
-                               out_specs=(P(), P("data"))))(gs, jnp.zeros_like(gs))
-true_mean = np.asarray(gs).mean(0)
-rel = np.abs(np.asarray(out) - true_mean).max() / np.abs(true_mean).max()
-results["compressed_psum_rel_err"] = float(rel)
-results["compressed_psum_ok"] = bool(rel < 0.08)
-
 print("RESULTS_JSON:" + json.dumps(results))
 """
 
@@ -146,7 +128,6 @@ def test_multidevice_battery():
     assert results["fused_matches_jnp_8dev"]
     assert results["als_monotone"], results["als_fits"]
     assert results["elastic_resumed"], results["elastic_fits"]
-    assert results["compressed_psum_ok"], results["compressed_psum_rel_err"]
 
 
 # -- packed ownership layout on 4 devices -------------------------------------

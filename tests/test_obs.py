@@ -54,12 +54,12 @@ def test_clock_monotonic_and_wall():
 
 def test_threaded_runtime_modules_share_the_obs_clock():
     """Satellite: sparse/stream, serve/batcher, schedule/rebalance and
-    training/checkpoint all time against repro.obs.clock — not their own
+    api/checkpoint all time against repro.obs.clock — not their own
     perf_counter bindings."""
+    from repro.api import checkpoint
     from repro.schedule import rebalance
     from repro.serve import batcher
     from repro.sparse import stream
-    from repro.training import checkpoint
     for mod in (stream, batcher, rebalance, checkpoint):
         assert mod.clock is clock, mod.__name__
 

@@ -26,7 +26,7 @@ from repro.serve.metrics import LatencyHistogram
 from repro.sparse.io import make_lowrank_tensor
 from repro.store import TensorStore, append_to_store, write_store_from_coo
 from repro.store.format import StoreFormatError
-from repro.training.checkpoint import CheckpointManager
+from repro.api.checkpoint import CheckpointManager
 
 RANK = 4
 SHAPE = (48, 40, 32)
