@@ -100,7 +100,9 @@ def partition_report(plan: CPPlan, rank: int) -> dict:
     (``n_groups · rows_max`` slabs) over the mode's true rows
     (``padded_rows_over_rows``); the packed factor's rows over them
     (``factor_rows_over_rows``); and, for the ``ref`` EC of the mode's
-    update at ``rank``, its input factors' lane-padded bytes
+    update at ``rank``, the factor rows in each 128-lane row of its gather
+    sources (``ec_rows_per_lane_row``: ``128 / rank`` where it gathers from
+    lane-packed copies of its input factors, else 1), their bytes
     (``ec_input_bytes``) and whether it runs its chunks in the VMEM loop
     (``ec_loop``, :func:`repro.kernels.ops.ref_takes_loop`). One device
     reads 1 and 1, and the layout's tile padding in both row ratios. The
@@ -118,6 +120,8 @@ def partition_report(plan: CPPlan, rank: int) -> dict:
             "slots_max_over_mean": float(slots.max() / max(slots.mean(), 1.0)),
             "padded_rows_over_rows": part.n_groups * part.rows_max / rows,
             "factor_rows_over_rows": part.padded_rows / rows,
+            "ec_rows_per_lane_row": kops.ref_rows_per_lane_row(input_rows,
+                                                              rank),
             "ec_input_bytes": kops.ref_input_bytes(input_rows, rank),
             "ec_loop": kops.ref_takes_loop(input_rows, rank),
         }

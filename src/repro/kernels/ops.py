@@ -37,7 +37,7 @@ from repro.kernels.mttkrp_sorted import ec_sorted
 
 __all__ = ["mttkrp_local", "default_interpret", "resolve_variant",
            "kernel_kwargs_from_config", "variant_vmem_bytes",
-           "ref_input_bytes", "ref_takes_loop",
+           "ref_rows_per_lane_row", "ref_input_bytes", "ref_takes_loop",
            "KERNEL_VARIANTS", "ENV_VARIANT", "DEFAULT_VARIANT",
            "DEFAULT_NUM_BUFFERS"]
 
@@ -132,19 +132,50 @@ def _mask_unvisited(out: jax.Array, tile_mask: jax.Array | None,
 # whole shard at once. Where the input factors fit in VMEM together (128
 # MiB on a v5e, with room left for the chunk's own rows), the chunks run in
 # a loop of REF_LOOP_BLOCKS blocks and the compiler keeps the factors and
-# each chunk's rows there. Otherwise a loop would leave a factor in HBM and
-# gather from it row by row, so the chunks are laid out one after another
-# in the program, each gathering at most REF_CHUNK_BYTES of padded rows,
-# and the compiler stages each factor into VMEM for its gather in turn.
+# each chunk's rows there. Where they fit only lane-packed, 128 / R rows to
+# a 128-lane row (R = 32: a quarter of the lane-padded bytes), the loop
+# gathers from packed copies and picks each row from its lane group; that
+# costs about a fifth more than gathering from the factors as they are
+# (amazon-r32's modes, TPU v5e), so factors that fit as they are stay so.
+# Otherwise a loop would leave a factor in HBM and gather from it row by
+# row, so the chunks are laid out one after another in the program, each
+# gathering at most REF_CHUNK_BYTES of padded rows, and the compiler stages
+# each factor into VMEM for its gather in turn.
 REF_VMEM_FACTOR_BYTES = 100 << 20
 REF_LOOP_BLOCKS = 128
 REF_CHUNK_BYTES = 512 << 20
 
 
+def _source_bytes(input_rows: Sequence[int], rank: int, k: int) -> int:
+    """Bytes of the input factors, lane-packed ``k`` rows to a 128-lane row
+    in whole 8-row sublane tiles, or (``k`` 1) each row lane-padded."""
+    if k == 1:
+        return sum(int(n) for n in input_rows) * tl.round_up(rank, tl.LANES) * 4
+    return sum(tl.round_up(int(n), 8 * k) // k
+               for n in input_rows) * tl.LANES * 4
+
+
+def ref_rows_per_lane_row(input_rows: Sequence[int], rank: int) -> int:
+    """Factor rows the ``ref`` EC packs into one 128-lane row of its gather
+    sources, for input factors of ``input_rows`` rows at ``rank``: ``128 /
+    rank`` where ``rank`` divides 128 and the factors fit in VMEM together
+    lane-packed but not lane-padded, else 1 (it gathers from the factors as
+    they are)."""
+    if rank >= tl.LANES or tl.LANES % rank:
+        return 1
+    k = tl.LANES // rank
+    fits = [_source_bytes(input_rows, rank, n) <= REF_VMEM_FACTOR_BYTES
+            for n in (1, k)]
+    return k if fits == [False, True] else 1
+
+
 def ref_input_bytes(input_rows: Sequence[int], rank: int) -> int:
-    """Bytes of the ``ref`` EC's input factors as it weighs them against
-    ``REF_VMEM_FACTOR_BYTES``: their rows, each lane-padded to 128 floats."""
-    return sum(int(n) for n in input_rows) * tl.round_up(rank, tl.LANES) * 4
+    """Bytes of the ``ref`` EC's gather sources as it weighs them against
+    ``REF_VMEM_FACTOR_BYTES``: the input factors lane-packed
+    (:func:`ref_rows_per_lane_row`), or else each row lane-padded to 128
+    floats."""
+    return _source_bytes(input_rows, rank,
+                         ref_rows_per_lane_row(input_rows, rank))
 
 
 def ref_takes_loop(input_rows: Sequence[int], rank: int) -> bool:
@@ -153,14 +184,39 @@ def ref_takes_loop(input_rows: Sequence[int], rank: int) -> bool:
     return ref_input_bytes(input_rows, rank) <= REF_VMEM_FACTOR_BYTES
 
 
-def _ref_tiles(indices, values, local_rows, factors, mode, tile, block_p):
+def _lane_pack(factor: jax.Array, k: int) -> jax.Array:
+    """``(rows, R) -> (rows', k·R)``: row ``i`` in packed row ``i // k``,
+    lanes ``R·(i % k)`` to ``R·(i % k) + R``; ``rows'`` is a multiple of 8,
+    the pad rows zero."""
+    rows = factor.shape[0]
+    factor = jnp.pad(factor, ((0, tl.round_up(rows, 8 * k) - rows), (0, 0)))
+    return factor.reshape(-1, k * factor.shape[1])
+
+
+def _lane_gather(packed: jax.Array, idx: jax.Array, k: int) -> jax.Array:
+    """Rows ``idx`` of a :func:`_lane_pack`-ed factor: ``(n, R)``, each
+    picked from packed row ``idx // k`` viewed as ``(k, R)``: its lane
+    group ``idx % k`` summed with ``k - 1`` zeros, so the row's own values
+    (a -0.0 reads +0.0)."""
+    rank = packed.shape[-1] // k
+    rows = packed[jnp.right_shift(idx, k.bit_length() - 1)]
+    group = jnp.bitwise_and(idx, k - 1)[:, None] == jnp.arange(k)[None]
+    return jnp.where(group[:, :, None], rows.reshape(-1, k, rank), 0).sum(1)
+
+
+def _ref_tiles(indices, values, local_rows, factors, mode, tile, block_p,
+               k):
     """The slots' products summed within each block into the block's tile:
-    ``(nblocks, tile, R)``."""
+    ``(nblocks, tile, R)``. With ``k > 1`` the input factors are lane-packed
+    (:func:`_lane_pack`)."""
     nblocks = values.shape[0] // block_p
     e = values.astype(jnp.float32)[:, None]
     for w in range(len(factors)):
-        if w != mode:
-            e = e * factors[w][indices[:, w]].astype(jnp.float32)
+        if w == mode:
+            continue
+        rows = factors[w][indices[:, w]] if k == 1 else \
+            _lane_gather(factors[w], indices[:, w], k)
+        e = e * rows.astype(jnp.float32)
     rank = e.shape[-1]
     row_in_tile = (local_rows % tile).reshape(nblocks, block_p, 1)
     onehot = row_in_tile == jax.lax.broadcasted_iota(
@@ -183,27 +239,35 @@ def _run_ref(indices, values, local_rows, block_to_tile, factors, *,
     one-hot of each slot's row in its tile, built from an iota inside the
     fusion, at HIGHEST precision: the one-hot is exact in bfloat16 and the
     float32 products are split into bfloat16 parts that keep every bit.
-    The shard is processed in chunks of blocks (see REF_LOOP_BLOCKS), each
-    scatter-adding into the running output in block order, so the result
-    does not depend on the chunking. Pad slots hold value 0 and add exact
-    zeros; tiles no block visits stay 0. The sums run in another order
-    than the slot-order oracle (kernels/ref.py), so they agree with it to
-    float32 rounding, and bit for bit where every sum is exact."""
+    Where the input factors fit in VMEM only lane-packed, the rows are
+    gathered from a lane-packed copy of each, made once per call
+    (:func:`ref_rows_per_lane_row`); the values gathered are the factor's
+    own. The shard is processed in chunks of blocks (see REF_LOOP_BLOCKS),
+    each scatter-adding into the running output in block order, so the
+    result does not depend on the chunking. Pad slots hold value 0 and add
+    exact zeros; tiles no block visits stay 0. The sums run in another
+    order than the slot-order oracle (kernels/ref.py), so they agree with
+    it to float32 rounding, and bit for bit where every sum is exact."""
     del interpret, tile_mask, num_buffers, seg_starts, seg_rows
     rank = factors[0].shape[-1]
     row_bytes = tl.round_up(rank, tl.LANES) * 4
     inputs = [w for w in range(len(factors)) if w != mode]
-    if ref_takes_loop([factors[w].shape[0] for w in inputs], rank):
+    input_rows = [factors[w].shape[0] for w in inputs]
+    k = ref_rows_per_lane_row(input_rows, rank)
+    if ref_takes_loop(input_rows, rank):
         chunk, unroll = REF_LOOP_BLOCKS, False
     else:
         chunk = max(1, REF_CHUNK_BYTES // (len(inputs) * block_p * row_bytes))
         unroll = True
+    if k > 1:
+        factors = [_lane_pack(f, k) if w in inputs else f
+                   for w, f in enumerate(factors)]
 
     def launch(n, base, b2t, out):
         s = base[0] * block_p
         cut = lambda x: jax.lax.dynamic_slice_in_dim(x, s, n * block_p)
         tiles = _ref_tiles(cut(indices), cut(values), cut(local_rows),
-                           factors, mode, tile, block_p)
+                           factors, mode, tile, block_p, k)
         return out.at[b2t].add(tiles)
 
     out = tl.chunked(launch, nblocks=block_to_tile.shape[0],

@@ -554,6 +554,58 @@ def test_ref_ec_chunks_match_one_chunk(path, monkeypatch):
     np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("nmodes", [3, 4, 5])
+@pytest.mark.parametrize("rank", [8, 16, 32, 64, 128])
+def test_ref_ec_lane_packed_gather(nmodes, rank, monkeypatch):
+    """Input factors that fit in VMEM only lane-packed (here: a VMEM budget
+    of exactly their packed bytes): where R divides 128 the ``ref`` EC
+    gathers their rows from lane-packed copies, 128 / R rows to a 128-lane
+    row; no factor here has a multiple of 8 · (128 / R) rows, so every copy
+    ends in pad rows. On integer data it equals the unpacked form (rows
+    gathered from the factors as they are) and the slot-order oracle bit
+    for bit; on N(0, 1) data the oracle to float32 rounding. At R = 128
+    nothing is packed: the program is the unpacked form's."""
+    from repro.core.coo import random_sparse
+    from repro.core.partition import partition_mode
+    shape = (45, 37, 29, 23, 19)[:nmodes]
+    inputs = shape[:1] + shape[2:]
+    k = 128 // rank
+    assert all(s % (8 * k) for s in shape)
+    monkeypatch.setattr(kops, "REF_VMEM_FACTOR_BYTES",
+                        sum(-(-n // (8 * k)) * 8 for n in inputs) * 512)
+    assert kops.ref_rows_per_lane_row(inputs, rank) == k
+    assert kops.ref_takes_loop(inputs, rank)
+    t = random_sparse(shape, 1200, seed=nmodes * 3 + rank,
+                      distribution="zipf")
+    part, _, _ = partition_mode(t, 1, 1, replication=1, tile=8, block_p=128)
+    rows, b2t = part.local_rows[0], part.block_to_tile[0]
+    kw = dict(mode=1, num_rows=part.rows_max)
+    vals, int_factors = _integer_data(part.values[0], shape, rank, seed=rank)
+    factors = _normal_factors(shape, rank, seed=rank + 2)
+
+    def run(vals, factors):
+        return _ref_and_oracle(part.indices[0], vals, rows, b2t, factors,
+                               **kw)
+
+    def program():
+        return jax.jit(lambda *f: kops.mttkrp_local(
+            jnp.asarray(part.indices[0]), jnp.asarray(vals),
+            jnp.asarray(rows), jnp.asarray(b2t), list(f), tile=8,
+            block_p=128, variant="ref", **kw)).lower(*factors).as_text()
+
+    packed_ints, want = run(vals, int_factors)
+    packed, oracle = run(part.values[0], factors)
+    packed_text = program()
+    monkeypatch.setattr(kops, "ref_rows_per_lane_row", lambda rows, r: 1)
+    unpacked_ints, _ = run(vals, int_factors)
+    assert np.abs(want).max() > 0
+    np.testing.assert_array_equal(packed_ints, unpacked_ints)
+    np.testing.assert_array_equal(packed_ints, want)
+    _assert_close_to_oracle(packed, oracle, part.indices[0], part.values[0],
+                            rows, factors, 1, part.rows_max)
+    assert (packed_text == program()) == (k == 1)
+
+
 def test_ref_ec_trailing_pad_blocks():
     """A light device of a two-device plan ends in whole pad blocks that
     revisit its last tile; they add exact zeros there."""

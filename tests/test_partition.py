@@ -268,19 +268,24 @@ def test_packing_the_slabs_puts_every_row_at_its_packed_row(strategy):
                                   owner)
 
 
-@pytest.mark.parametrize("devices", [1, 4])
-def test_partition_report_reads_the_packed_factors(devices):
-    """The report's ``factor_rows_over_rows`` and ``ec_loop`` on a 4-device
-    and a 1-device plan at rank 32: packed factors hold about their true
-    rows where the wire carries several times them, and the ``ref`` EC
-    loops exactly where its input factors' lane-padded bytes fit
-    ``REF_VMEM_FACTOR_BYTES`` (mode 0 reads two small factors; modes 1 and
-    2 read mode 0's 250,000 rows, 128 MB)."""
+@pytest.mark.parametrize("devices,rows0", [
+    pytest.param(1, 250_000, id="1"), pytest.param(4, 250_000, id="4"),
+    pytest.param(4, 1_000_000, id="4-unrolled")])
+def test_partition_report_reads_the_packed_factors(devices, rows0):
+    """The report's ``factor_rows_over_rows``, ``ec_rows_per_lane_row``
+    and ``ec_loop`` on a 4-device and a 1-device plan at rank 32: packed
+    factors hold about their true rows where the wire carries several times
+    them; the ``ref`` EC loops exactly where its gather sources fit
+    ``REF_VMEM_FACTOR_BYTES``, and gathers from lane-packed copies of its
+    input factors, four rows to a 128-lane row, where they fit only so.
+    Mode 0 reads two small factors (lane-padded: it loops); modes 1 and 2
+    read mode 0's, 250,000 rows (128 MB lane-padded, 32 MB packed: they loop
+    packed) or 1,000,000 (128 MB packed: unrolled, lane-padded)."""
     from repro import api
     from repro.api.solver import partition_report
     from repro.kernels import ops as kops
     cfg = api.preset("paper", {"rank": 32, "runtime.num_devices": devices})
-    t = random_sparse((250_000, 300, 200), 6000, seed=0, distribution="zipf")
+    t = random_sparse((rows0, 300, 200), 6000, seed=0, distribution="zipf")
     plan = api.plan(t, cfg)
     rep = partition_report(plan, 32)
     loops = []
@@ -296,8 +301,14 @@ def test_partition_report_reads_the_packed_factors(devices):
             assert mode["factor_rows_over_rows"] < \
                 mode["padded_rows_over_rows"]
         inputs = [p.padded_rows for w, p in enumerate(plan.modes) if w != d]
-        assert mode["ec_input_bytes"] == sum(inputs) * 128 * 4
+        padded = sum(inputs) * 128 * 4
+        # whole 8-row sublane tiles of 128-lane rows, four factor rows each
+        packed = sum(-(-n // 32) * 8 for n in inputs) * 128 * 4
+        k = 4 if packed <= kops.REF_VMEM_FACTOR_BYTES < padded else 1
+        assert mode["ec_rows_per_lane_row"] == k
+        assert mode["ec_input_bytes"] == (packed if k == 4 else padded)
         assert mode["ec_loop"] == (mode["ec_input_bytes"]
                                    <= kops.REF_VMEM_FACTOR_BYTES)
-        loops.append(mode["ec_loop"])
-    assert loops == [True, False, False]
+        loops.append((k, mode["ec_loop"]))
+    big = (4, True) if rows0 < 1_000_000 else (1, False)
+    assert loops == [(1, True), big, big]

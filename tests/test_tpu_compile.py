@@ -146,21 +146,36 @@ def _operand_dims(text, op):
     return out
 
 
+def _without_metadata(text):
+    """A compiled HLO module's text without its source locations: each
+    instruction's metadata and the tables it points into."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    return "\n".join(
+        ln for ln in text.splitlines()
+        if not re.match(r'\d+ [{"]|(FileNames|FunctionNames|FileLocations'
+                        r'|StackFrames)$', ln))
+
+
 AMAZON = (48216, 17744, 18056)          # amazon-r32's padded factor rows
 TWITCH = (186296, 73944, 9408, 80, 80)  # twitch-r32's
 SMOKE = (289272, 106456, 108311)        # chip_smoke.py's amazon at 0.06
+LARGE = (964241, 354854, 361037)        # amazon at scale 0.2
 # (factor rows, output mode, tile, layout, blocks in the shard, whether the
-# slot-order oracle's temporaries are compared): a few hundred blocks, the
-# cells' shards where the chunks run in a loop (amazon modes 0 and 1) and
-# where they are laid out one after another (twitch mode 3, whose input
-# factors do not fit in VMEM together), and chip_smoke.py's mode-0 shard
-# (row-sorted layout, tile 16, about 42.9 M slots).
+# slot-order oracle's temporaries are compared, how the EC runs its
+# chunks): a few hundred blocks and amazon-r32's shards, whose input
+# factors fit in VMEM together as they are (a "loop"); twitch-r32's mode 3
+# (137 MB lane-padded, 34 MB lane-packed) and chip_smoke.py's mode-0 shard
+# (row-sorted layout, tile 16, about 42.9 M slots; 110 MB lane-padded, 27
+# MB lane-packed), whose factors fit only lane-packed (a "packed" loop);
+# and an amazon mode-1 update at scale 0.2, whose factors do not fit even
+# lane-packed (170 MB), so its chunks are "unrolled".
 REF_SHAPES = {
-    "300": (AMAZON, 0, 8, "blocked", 300, True),
-    "89000": (AMAZON, 0, 8, "blocked", 89_000, True),
-    "amazon-mode1": (AMAZON, 1, 8, "blocked", 87_300, True),
-    "twitch-mode3": (TWITCH, 3, 8, "blocked", 24_170, False),
-    "chip_smoke": (SMOKE, 0, 16, "sorted", 335_000, False),
+    "300": (AMAZON, 0, 8, "blocked", 300, True, "loop"),
+    "89000": (AMAZON, 0, 8, "blocked", 89_000, True, "loop"),
+    "amazon-mode1": (AMAZON, 1, 8, "blocked", 87_300, True, "loop"),
+    "twitch-mode3": (TWITCH, 3, 8, "blocked", 24_170, False, "packed"),
+    "chip_smoke": (SMOKE, 0, 16, "sorted", 335_000, False, "packed"),
+    "large-mode1": (LARGE, 1, 8, "blocked", 9_000, False, "unrolled"),
 }
 
 
@@ -170,10 +185,14 @@ def test_ref_mode_update_scatters_blocks_for_v5e(one_chip, monkeypatch,
     """One resident ALS mode update on the ``ref`` EC (R 32, block_p 128),
     compiled for the chip at the shapes of ``REF_SHAPES``: no scatter takes
     one update row per nonzero, no sort runs over the nonzeros, the block
-    scatter-add takes at most one chunk of blocks, the EC's matmuls run at
-    HIGHEST, and the program's temporaries stay within a few chunks'
-    gathered rows (``kops.REF_CHUNK_BYTES``) however large the shard. At
-    the amazon shapes they are also no larger than the slot-order oracle's
+    scatter-add takes at most one chunk of blocks (a ``while`` of
+    ``kops.REF_LOOP_BLOCKS`` where the input factors fit in VMEM, as they
+    are or lane-packed, else unrolled chunks), the EC's matmuls run at
+    HIGHEST, the program is the one that gathers from the factors as they
+    are unless they fit in VMEM only lane-packed, and the program's
+    temporaries stay within a few chunks' gathered rows
+    (``kops.REF_CHUNK_BYTES``) however large the shard. At the amazon
+    shapes they are also no larger than the slot-order oracle's
     (kernels/ref.py) in the same update, to within 1 MiB. (Where the output has few rows, as
     in twitch's mode 3, the compiler fuses the oracle's gathers into its
     scatter and the oracle holds less; its scatter then runs over every
@@ -184,7 +203,8 @@ def test_ref_mode_update_scatters_blocks_for_v5e(one_chip, monkeypatch,
     from repro.core.partition import build_plan
     from repro.kernels import ops as kops
     from repro.kernels.ref import mttkrp_local_ref
-    rows, mode, tile, layout, nblocks, vs_oracle = REF_SHAPES[case]
+    rows, mode, tile, layout, nblocks, vs_oracle, ec = REF_SHAPES[case]
+    loop = ec != "unrolled"
     nmodes = len(rows)
     topo, _ = one_chip
     # the plan sets the factor rows and tile geometry; the shard's shapes
@@ -237,8 +257,11 @@ def test_ref_mode_update_scatters_blocks_for_v5e(one_chip, monkeypatch,
     assert block_scatters, "the block scatter-add is missing"
     # in-VMEM loop chunks or unrolled chunks of REF_CHUNK_BYTES: a chunk is
     # far smaller than the shard (except at a few hundred blocks)
-    assert max(d[0] for d in block_scatters) <= max(
-        kops.REF_LOOP_BLOCKS, kops.REF_CHUNK_BYTES // (2 * 128 * 512))
+    assert max(d[0] for d in block_scatters) == (
+        kops.REF_LOOP_BLOCKS if loop
+        else kops.REF_CHUNK_BYTES // ((nmodes - 1) * 128 * 512))
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    assert any(n.endswith("/ec_local/while") for n in op_names) == loop
     assert per_nonzero(text) == ([], [])
     # the in-block contraction keeps float32: every EC matmul at HIGHEST
     ec_dots = [ln for ln in text.splitlines()
@@ -247,6 +270,15 @@ def test_ref_mode_update_scatters_blocks_for_v5e(one_chip, monkeypatch,
                            for ln in ec_dots), ec_dots
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= 4 * kops.REF_CHUNK_BYTES, temp
+    # the program gathering from the factors as they are: the same one, to
+    # the byte, unless the factors fit in VMEM only lane-packed
+    assert kops.ref_rows_per_lane_row(
+        [m.padded_rows for w, m in enumerate(plan.modes) if w != mode],
+        32) == (4 if ec == "packed" else 1)
+    with monkeypatch.context() as mp:
+        mp.setattr(kops, "ref_rows_per_lane_row", lambda rows, r: 1)
+        assert (_without_metadata(compile_update().as_text())
+                == _without_metadata(text)) == (ec != "packed")
     if not vs_oracle:
         return
 
@@ -348,23 +380,26 @@ def amazon_4chip_plan():
 def test_amazon_4chip_packed_factors_steer_the_ec_for_v5e(
         one_chip, amazon_4chip_plan, mode):
     """A mode update of the 4-chip cell compiled for a v5e 2x2 on the
-    packed factors: mode 0's inputs (73 MB) fit the ``ref`` EC's VMEM loop,
-    so its EC is a ``while``; modes 1 and 2 read mode 0's 98.7 MB factor
-    (135 MB together) and run unrolled chunks. Every collective carries
-    ``factor_exchange`` or ``merge``, and the packing copy
-    (``pack_slabs``' concatenate) sits under ``factor_exchange``."""
+    packed factors: every mode's ``ref`` EC is a ``while`` whose operands
+    hold its gather sources in VMEM (``S(1)``). Mode 0 reads two small
+    factors (73.3 MB lane-padded) as they are; modes 1 and 2 read mode 0's
+    192,848 rows (135 MB lane-padded), so they gather from lane-packed
+    copies, four rows to a 128-lane row (33.9 MB). Every collective carries
+    ``factor_exchange`` or ``merge``, and the packing copy (``pack_slabs``'
+    concatenate) sits under ``factor_exchange``."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro import comm
     from repro.core import als, mttkrp
     from repro.kernels import ops as kops
     topo, _ = one_chip
     cfg, plan, report = amazon_4chip_plan
-    loop = mode == 0
-    assert report["per_mode"][mode]["ec_loop"] == loop
+    k = 1 if mode == 0 else 4
+    assert report["per_mode"][mode]["ec_rows_per_lane_row"] == k
+    assert report["per_mode"][mode]["ec_loop"]
     assert report["per_mode"][mode]["factor_rows_over_rows"] <= 1.001
     mesh = mttkrp.cp_mesh(4, 1, devices=np.asarray(topo.devices[:4]))
     part = plan.modes[mode]
-    # more blocks than one unrolled chunk holds, so modes 1-2 show two
+    # more blocks than one unrolled chunk would hold
     nblocks = kops.REF_CHUNK_BYTES // (2 * 128 * 512) + 904
     nnz = nblocks * part.block_p
     grid = ("group", "sub")
@@ -394,12 +429,20 @@ def test_amazon_4chip_packed_factors_steer_the_ec_for_v5e(
     others = [facs[w] for w in range(3) if w != mode]
     text = update.lower(facs[mode], dev, others, grams).compile().as_text()
     op_names = re.findall(r'op_name="([^"]*)"', text)
-    assert ("jit(update)/shard_map/ec_local/while" in op_names) == loop
+    assert "jit(update)/shard_map/ec_local/while" in op_names
     block_scatters = [d[-1] for d in _operand_dims(text, "scatter")
                       if d[-1] and d[-1][1:] == [part.tile, 32]]
-    chunk = kops.REF_LOOP_BLOCKS if loop else nblocks - 904
-    assert max(d[0] for d in block_scatters) == chunk, block_scatters
-    assert loop or len(block_scatters) >= 2, block_scatters
+    assert max(d[0] for d in block_scatters) == kops.REF_LOOP_BLOCKS, \
+        block_scatters
+    loops = [ln for ln in text.splitlines()
+             if re.search(r"= \(.*\) while\(", ln) and "/ec_local/" in ln]
+    assert len(loops) == 1, loops
+    for w in range(3):
+        if w != mode:
+            rows = plan.modes[w].padded_rows
+            source = f"{-(-rows // 32) * 8},128" if k == 4 else f"{rows},32"
+            assert re.search(rf"f32\[{source}\]\{{[^}}]*S\(1\)", loops[0]), \
+                (w, source, loops[0][:400])
     for ln in text.splitlines():
         if _COLLECTIVE_RE.search(ln):
             m = re.search(r'op_name="([^"]*)"', ln)
